@@ -32,6 +32,13 @@ each task model on a tiny one-cluster topology, then scans the jaxprs:
     two (see :func:`repro.kernels.ws_sim.grid_shape_hazards`): each
     distinct padded grid shape compiles a distinct Mosaic program.
 
+``pallas.mosaic_op``
+    The event core traced as the Pallas kernel body (under
+    ``engine.select_forms``) must hold no ``scatter``, ``scatter-add``,
+    ``dynamic_slice``, ``dynamic_update_slice`` or integer ``argmin`` /
+    ``argmax``: the TPU's kernel compiler has no lowering for them, so the
+    kernel would fail only on the chip.
+
 ``donation.ungated``
     AST rule over ``core/engine.py``: any literal non-empty
     ``donate_argnums=`` must be behind the ``_donate_ok()`` platform gate
@@ -57,6 +64,12 @@ CALLBACK_PRIMITIVES = frozenset({
     "pure_callback", "io_callback", "debug_callback", "debug_print",
     "host_callback", "outside_call",
 })
+
+#: Primitives the TPU's kernel compiler cannot lower in the event core;
+#: ``argmin``/``argmax`` only on integer or bool operands.
+MOSAIC_UNLOWERABLE = frozenset({
+    "scatter", "scatter-add", "dynamic_slice", "dynamic_update_slice"})
+MOSAIC_FLOAT_ONLY = frozenset({"argmin", "argmax"})
 
 #: Batch widths compared by the shape-branch rule. Distinct pow2 widths so
 #: a legitimate pow2-padding branch would not fire it.
@@ -211,6 +224,35 @@ def shape_branch_findings(name: str, model) -> List[Finding]:
         f"shape forces one compile per batch width")]
 
 
+def kernel_op_findings(closed, symbol: str) -> List[Finding]:
+    """Scan the body of every ``pallas_call`` in ``closed`` for primitives
+    the TPU's kernel compiler cannot lower."""
+    out: List[Finding] = []
+    seen = set()
+    for eqn in iter_eqns(closed.jaxpr):
+        if eqn.primitive.name != "pallas_call":
+            continue
+        for op in iter_eqns(eqn.params["jaxpr"]):
+            name = op.primitive.name
+            if name in MOSAIC_FLOAT_ONLY:
+                dt = op.invars[0].aval.dtype
+                if jax.numpy.issubdtype(dt, jax.numpy.floating):
+                    continue
+                name = f"{name}[{dt}]"
+            elif name not in MOSAIC_UNLOWERABLE:
+                continue
+            if name in seen:
+                continue
+            seen.add(name)
+            out.append(Finding(
+                pass_name=PASS, rule="pallas.mosaic_op",
+                where="kernels.ws_sim._kernel", symbol=symbol,
+                message=f"primitive {name!r} in the kernel body has no "
+                f"TPU kernel lowering; route it through the engine's "
+                f"select-form helpers"))
+    return out
+
+
 def pallas_grid_findings() -> List[Finding]:
     from repro.core import backend as be
     from repro.kernels import ws_sim
@@ -296,14 +338,17 @@ def run(root: Optional[Path] = None) -> List[Finding]:
             closed = trace_pallas(model, SIGNATURE_WIDTHS[0])
             findings.extend(scan_jaxpr(
                 closed, where="kernels.ws_sim.ws_sim_pallas", symbol=name))
+            findings.extend(kernel_op_findings(closed, name))
 
     findings.extend(pallas_grid_findings())
     findings.extend(donation_findings(root))
     return findings
 
 
-__all__ = ["PASS", "CALLBACK_PRIMITIVES", "SIGNATURE_WIDTHS", "tiny_models",
+__all__ = ["PASS", "CALLBACK_PRIMITIVES", "MOSAIC_UNLOWERABLE",
+           "MOSAIC_FLOAT_ONLY", "SIGNATURE_WIDTHS", "tiny_models",
            "trace_model", "trace_pallas", "iter_eqns",
            "structural_signature", "scan_jaxpr", "static_arg_findings",
-           "shape_branch_findings", "pallas_grid_findings",
+           "shape_branch_findings", "kernel_op_findings",
+           "pallas_grid_findings",
            "lint_donation_source", "donation_findings", "run"]

@@ -25,7 +25,6 @@ import dataclasses
 from typing import NamedTuple
 
 import jax.numpy as jnp
-from jax import lax
 
 from repro.core import engine as eng
 from repro.core.engine import (ACTIVE, EV_ANS_FAIL, EV_ANS_OK,
@@ -100,16 +99,16 @@ class AdaptiveModel(eng.TaskModel):
 
     def init(self, arrays, scn: Scenario, core: eng.CoreState):
         p, cap = self.p, self.cfg.pool_cap
-        idle_at = core.idle_at.at[0].set(scn.W)
+        idle_at = eng.write(core.idle_at, 0, scn.W)
         core = core._replace(
             idle_at=idle_at,
             ev_time=idle_at,
             stolen=jnp.full((p,), -1, jnp.int32),
-            executed=core.executed.at[0].set(scn.W),
+            executed=eng.write(core.executed, 0, scn.W),
         )
         ms = AdaptiveState(
-            cur_task=jnp.full((p,), -1, jnp.int32).at[0].set(0),
-            tdur=jnp.zeros((cap,), jnp.int32).at[0].set(scn.W),
+            cur_task=eng.write(jnp.full((p,), -1, jnp.int32), 0, 0),
+            tdur=eng.write(jnp.zeros((cap,), jnp.int32), 0, scn.W),
             mpar=jnp.full((cap,), -1, jnp.int32),
             tpred=jnp.zeros((cap,), jnp.int32),
             is_merge=jnp.zeros((cap,), jnp.bool_),
@@ -130,12 +129,13 @@ class AdaptiveModel(eng.TaskModel):
     def _push(self, core, ms: AdaptiveState, i, task):
         """Push a ready merge task to i's deque tail (overflow halts)."""
         cap = self.cfg.deque_cap
-        tl = ms.tail[i]
+        tl = eng.read(ms.tail, i)
         ok = tl < cap
         pos = jnp.minimum(tl, cap - 1)
         ms = ms._replace(
-            buf=ms.buf.at[i, pos].set(jnp.where(ok, task, ms.buf[i, pos])),
-            tail=ms.tail.at[i].add(jnp.where(ok, 1, 0)),
+            buf=eng.write(ms.buf, (i, pos),
+                          jnp.where(ok, task, eng.read(ms.buf, i, pos))),
+            tail=eng.add(ms.tail, i, jnp.where(ok, 1, 0)),
         )
         return core._replace(halt=core.halt | ~ok), ms
 
@@ -143,21 +143,24 @@ class AdaptiveModel(eng.TaskModel):
         """Task c completes on proc i: decrement its merge parent, maybe
         ready it."""
         ms = ms._replace(n_completed=ms.n_completed + 1)
-        m = ms.mpar[c]
+        m = eng.read(ms.mpar, c)
         has_parent = m >= 0
-        pc = jnp.where(has_parent, ms.tpred[jnp.maximum(m, 0)] - 1, 1)
-        ms = ms._replace(tpred=ms.tpred.at[jnp.maximum(m, 0)].set(
-            jnp.where(has_parent, pc, ms.tpred[jnp.maximum(m, 0)])))
+        pc = jnp.where(has_parent,
+                       eng.read(ms.tpred, jnp.maximum(m, 0)) - 1, 1)
+        ms = ms._replace(tpred=eng.write(
+            ms.tpred, jnp.maximum(m, 0),
+            jnp.where(has_parent, pc,
+                      eng.read(ms.tpred, jnp.maximum(m, 0)))))
         ready = has_parent & (pc == 0)
-        return lax.cond(ready, lambda s: self._push(s[0], s[1], i, m),
+        return eng.cond(ready, lambda s: self._push(s[0], s[1], i, m),
                         lambda s: s, (core, ms))
 
     def on_idle(self, arrays, cid, hops, scn, core, ms: AdaptiveState, i, t):
-        c = ms.cur_task[i]
-        core, ms = lax.cond(
+        c = eng.read(ms.cur_task, i)
+        core, ms = eng.cond(
             c >= 0, lambda s: self._complete_task(s[0], s[1], i, c, t),
             lambda s: s, (core, ms))
-        ms = ms._replace(cur_task=ms.cur_task.at[i].set(-1))
+        ms = ms._replace(cur_task=eng.write(ms.cur_task, i, -1))
 
         finished = self.is_done(arrays, core, ms, i, t)
 
@@ -170,21 +173,22 @@ class AdaptiveModel(eng.TaskModel):
 
         def _continue(s):
             core, ms = s
-            empty = ms.head[i] >= ms.tail[i]
+            empty = eng.read(ms.head, i) >= eng.read(ms.tail, i)
 
             def pop_local(s):
                 core, ms = s
-                pos = ms.tail[i] - 1     # merges: LIFO locally
-                task = ms.buf[i, pos]
-                end = t + ms.tdur[task]
+                pos = eng.read(ms.tail, i) - 1     # merges: LIFO locally
+                task = eng.read(ms.buf, i, pos)
+                end = t + eng.read(ms.tdur, task)
                 ms = ms._replace(
-                    tail=ms.tail.at[i].add(-1),
-                    cur_task=ms.cur_task.at[i].set(task),
+                    tail=eng.add(ms.tail, i, -1),
+                    cur_task=eng.write(ms.cur_task, i, task),
                 )
                 core = core._replace(
-                    idle_at=core.idle_at.at[i].set(end),
-                    ev_time=core.ev_time.at[i].set(end),
-                    executed=core.executed.at[i].add(ms.tdur[task]),
+                    idle_at=eng.write(core.idle_at, i, end),
+                    ev_time=eng.write(core.ev_time, i, end),
+                    executed=eng.add(core.executed, i,
+                                     eng.read(ms.tdur, task)),
                 )
                 return core, ms
 
@@ -194,23 +198,23 @@ class AdaptiveModel(eng.TaskModel):
                 core = eng.log(self, core, t, i, EV_IDLE, 0)
                 return eng.start_stealing(self, cid, hops, scn, core, i, t), ms
 
-            return lax.cond(empty, steal, pop_local, s)
+            return eng.cond(empty, steal, pop_local, s)
 
-        return lax.cond(finished, _finish, _continue, (core, ms))
+        return eng.cond(finished, _finish, _continue, (core, ms))
 
     def on_request(self, arrays, cid, hops, scn, core, ms: AdaptiveState, i, t):
-        v = core.victim[i]
+        v = eng.read(core.victim, i)
         d_vi = eng.dist(cid, hops, scn, v, i)
         free = eng.chan_free(self, core, v, t)
 
-        qlen = ms.tail[v] - ms.head[v]
+        qlen = eng.read(ms.tail, v) - eng.read(ms.head, v)
         can_queue = (qlen > 0) & free
 
         # split only a *running work* task
-        c_v = ms.cur_task[v]
-        running_work = ((core.state[v] == ACTIVE) & (c_v >= 0)
-                        & ~ms.is_merge[jnp.maximum(c_v, 0)])
-        w_v = jnp.where(running_work, core.idle_at[v] - t, 0)
+        c_v = eng.read(ms.cur_task, v)
+        running_work = ((eng.read(core.state, v) == ACTIVE) & (c_v >= 0)
+                        & ~eng.read(ms.is_merge, jnp.maximum(c_v, 0)))
+        w_v = jnp.where(running_work, eng.read(core.idle_at, v) - t, 0)
         thr = eng.steal_threshold(scn, d_vi)
         amt = w_v // 2
         room = ms.next_free + 2 <= self.cfg.pool_cap
@@ -218,8 +222,8 @@ class AdaptiveModel(eng.TaskModel):
 
         def steal_queue(s):
             core, ms = s
-            task = ms.buf[v, ms.head[v]]
-            ms = ms._replace(head=ms.head.at[v].add(1))
+            task = eng.read(ms.buf, v, eng.read(ms.head, v))
+            ms = ms._replace(head=eng.add(ms.head, v, 1))
             return core, ms, task
 
         def steal_split(s):
@@ -229,20 +233,22 @@ class AdaptiveModel(eng.TaskModel):
             mdur = self.cfg.merge_dur(amt)
             new_idle_v = t + (w_v - amt)
             ms = ms._replace(
-                tdur=ms.tdur.at[m_id].set(mdur).at[t_id].set(amt),
-                mpar=ms.mpar.at[m_id].set(ms.mpar[c_v]).at[t_id].set(m_id)
-                        .at[c_v].set(m_id),
-                tpred=ms.tpred.at[m_id].set(2).at[t_id].set(0),
-                is_merge=ms.is_merge.at[m_id].set(True).at[t_id].set(False),
+                tdur=eng.write(eng.write(ms.tdur, m_id, mdur), t_id, amt),
+                mpar=eng.write(eng.write(eng.write(
+                    ms.mpar, m_id, eng.read(ms.mpar, c_v)), t_id, m_id),
+                    c_v, m_id),
+                tpred=eng.write(eng.write(ms.tpred, m_id, 2), t_id, 0),
+                is_merge=eng.write(eng.write(ms.is_merge, m_id, True),
+                                   t_id, False),
                 next_free=ms.next_free + 2,
                 n_created=ms.n_created + 2,
                 n_splits=ms.n_splits + 1,
                 total_merge_work=ms.total_merge_work + mdur,
             )
             core = core._replace(
-                idle_at=core.idle_at.at[v].set(new_idle_v),
-                ev_time=core.ev_time.at[v].set(new_idle_v),
-                executed=core.executed.at[v].add(-amt),
+                idle_at=eng.write(core.idle_at, v, new_idle_v),
+                ev_time=eng.write(core.ev_time, v, new_idle_v),
+                executed=eng.add(core.executed, v, -amt),
             )
             return core, ms, t_id
 
@@ -251,7 +257,7 @@ class AdaptiveModel(eng.TaskModel):
             return core, ms, jnp.int32(-1)
 
         branch = jnp.where(can_queue, 0, jnp.where(can_split, 1, 2))
-        core, ms, payload = lax.switch(
+        core, ms, payload = eng.switch(
             branch, [steal_queue, steal_split, fail], (core, ms))
         ok = can_queue | can_split
         core = eng.deliver_answer(core, i, v, t, d_vi, ok, payload)
@@ -260,23 +266,24 @@ class AdaptiveModel(eng.TaskModel):
         return core, ms
 
     def on_answer(self, arrays, cid, hops, scn, core, ms: AdaptiveState, i, t):
-        task = core.stolen[i]
+        task = eng.read(core.stolen, i)
         ok = task >= 0
 
         def got(s):
             core, ms = s
-            end = t + ms.tdur[task]
-            core = eng.acquire_work(self, core, i, t, end, ms.tdur[task],
-                                    jnp.int32(-1))
-            ms = ms._replace(cur_task=ms.cur_task.at[i].set(task))
+            end = t + eng.read(ms.tdur, task)
+            core = eng.acquire_work(self, core, i, t, end,
+                                    eng.read(ms.tdur, task), jnp.int32(-1))
+            ms = ms._replace(cur_task=eng.write(ms.cur_task, i, task))
             return eng.log(self, core, t, i, EV_ANS_OK, task), ms
 
         def retry(s):
             core, ms = s
             core = eng.start_stealing(self, cid, hops, scn, core, i, t)
-            return eng.log(self, core, t, i, EV_ANS_FAIL, core.victim[i]), ms
+            return eng.log(self, core, t, i, EV_ANS_FAIL,
+                           eng.read(core.victim, i)), ms
 
-        return lax.cond(ok, got, retry, (core, ms))
+        return eng.cond(ok, got, retry, (core, ms))
 
     def results(self, core: eng.CoreState, ms: AdaptiveState) -> AdaptiveSimResult:
         return AdaptiveSimResult(

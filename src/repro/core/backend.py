@@ -51,9 +51,10 @@ BACKEND_ENV = "REPRO_WS_BACKEND"
 #: a positive int forces that segment length, "0" disables segmentation.
 SEG_LEN_ENV = "REPRO_WS_SEG_LEN"
 
-#: Opt-in path for JAX's persistent compilation cache
-#: (:func:`enable_compile_cache`).
-JIT_CACHE_ENV = "REPRO_WS_JIT_CACHE"
+#: JAX's own variable for its persistent compilation cache, read by JAX at
+#: start-up; where it is set, :func:`enable_compile_cache` sets no other
+#: directory.
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 _fault_point_impl = None
 
@@ -153,7 +154,7 @@ class ExecutionBackend:
         bounds = np.linspace(0, n, nd + 1).astype(int)
         return [(int(lo), int(hi), devs[k])
                 for k, (lo, hi) in enumerate(zip(bounds[:-1], bounds[1:]))
-                if hi > lo]
+                if hi > lo] or [(0, n, None)]
 
     def run_rows(self, model, rows: "sw.GridRows", remote_prob: float = 0.25,
                  ev_budget=None, devices: Optional[Sequence] = None,
@@ -193,23 +194,29 @@ class ExecutionBackend:
                       ev_budget=ev_budget, grid=out)
             return out
 
-    def _run_rows(self, model, rows, remote_prob, ev_budget, devices):
-        n = len(rows)
-        chunks = self._device_chunks(n, devices)
-        if len(chunks) <= 1:
-            dev = chunks[0][2] if chunks else None
-            scn = sw.scenario_from_rows(rows, remote_prob=remote_prob,
-                                        ev_budget=ev_budget)
-            res = self._run_batch(model, scn, device=dev)
-            return sw.grid_from_result(model.p, rows, res)
+    def _chunk_scenarios(self, rows, remote_prob, ev_budget, chunks):
+        """One Scenario per (lo, hi, device) chunk, built on the host and
+        placed straight onto its device; counts the rows each device
+        runs (``backend.device_rows``)."""
         budgets = None if ev_budget is None else np.broadcast_to(
-            np.asarray(ev_budget, np.int64), (n,))
-        outs = []
-        for lo, hi, dev in chunks:  # dispatch everything before any sync
-            scn = sw.scenario_from_rows(
+            np.asarray(ev_budget, np.int64), (len(rows),))
+        scns = []
+        for lo, hi, dev in chunks:
+            scns.append(sw.scenario_from_rows(
                 rows.slice(lo, hi), remote_prob=remote_prob,
-                ev_budget=None if budgets is None else budgets[lo:hi])
-            outs.append(self._run_batch(model, scn, device=dev))
+                ev_budget=None if budgets is None else budgets[lo:hi],
+                device=dev))
+            if dev is not None:
+                obs.REGISTRY.counter("backend.device_rows", {
+                    "backend": self.name, "device": str(dev.id)}).inc(hi - lo)
+        return scns
+
+    def _run_rows(self, model, rows, remote_prob, ev_budget, devices):
+        chunks = self._device_chunks(len(rows), devices)
+        scns = self._chunk_scenarios(rows, remote_prob, ev_budget, chunks)
+        # dispatch everything before any sync
+        outs = [self._run_batch(model, scn, device=dev)
+                for scn, (_, _, dev) in zip(scns, chunks)]
         return sw.concat_grids(
             [sw.grid_from_result(model.p, rows.slice(lo, hi), res)
              for (lo, hi, _), res in zip(chunks, outs)])
@@ -358,8 +365,6 @@ class JaxBackend(ExecutionBackend):
         return eng.default_segment_len(model.max_events, ev_budget)
 
     def _run_batch(self, model, scn, device=None):
-        if device is not None:
-            scn = jax.device_put(scn, device)
         return eng.simulate_batch(model, scn)
 
     def _run_rows(self, model, rows, remote_prob, ev_budget, devices):
@@ -369,12 +374,7 @@ class JaxBackend(ExecutionBackend):
             return super()._run_rows(model, rows, remote_prob, ev_budget,
                                      devices)
         chunks = self._device_chunks(n, devices)
-        budgets = None if ev_budget is None else np.broadcast_to(
-            np.asarray(ev_budget, np.int64), (n,))
-        scns = [sw.scenario_from_rows(
-                    rows.slice(lo, hi), remote_prob=remote_prob,
-                    ev_budget=None if budgets is None else budgets[lo:hi])
-                for lo, hi, _ in chunks]
+        scns = self._chunk_scenarios(rows, remote_prob, ev_budget, chunks)
         results, stats = eng.run_segmented_chunks(
             model, scns, [d for _, _, d in chunks], seg_len=seg_len)
         merged = stats[0]
@@ -414,8 +414,6 @@ class PallasBackend(ExecutionBackend):
 
     def _run_batch(self, model, scn, device=None):
         from repro.kernels.ws_sim import ws_sim_pallas
-        if device is not None:
-            scn = jax.device_put(scn, device)
         return ws_sim_pallas(model, scn, interpret=self._interpret,
                              grid_chunk=self.grid_chunk)
 
@@ -528,27 +526,29 @@ def default_jit_cache_dir() -> Path:
     return Path(__file__).resolve().parents[3] / "artifacts" / "jit_cache"
 
 
-def enable_compile_cache(path: Union[None, str, os.PathLike] = None) -> Path:
-    """Opt into JAX's persistent compilation cache so worker processes stop
+def compile_cache_dir() -> Optional[Path]:
+    """Directory of JAX's persistent compilation cache, if one is on."""
+    d = jax.config.jax_compilation_cache_dir
+    return Path(d) if d else None
+
+
+def enable_compile_cache() -> Path:
+    """Turn on JAX's persistent compilation cache so processes stop
     re-jitting identical programs across runs.
 
-    ``path`` defaults to the ``REPRO_WS_JIT_CACHE`` environment variable,
-    else ``artifacts/jit_cache/`` in the repo. The directory is created and
-    ``jax_compilation_cache_dir`` pointed at it; the persistence thresholds
-    are dropped to zero so even the small event-loop programs are kept.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache
+    there and no other directory is set; otherwise the cache is the fixed
+    ``artifacts/jit_cache/`` of the checkout (the path is part of each
+    entry's key, so it must not move). The persistence thresholds are
+    dropped to zero so even the small event-loop programs are kept.
     Returns the cache directory. Safe to call repeatedly."""
-    if path is None:
-        env = os.environ.get(JIT_CACHE_ENV, "").strip()
-        path = env or default_jit_cache_dir()
-    p = Path(path)
+    env = os.environ.get(JAX_CACHE_ENV, "").strip()
+    p = Path(env) if env else default_jit_cache_dir()
     p.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(p))
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(opt, val)
-        except (AttributeError, ValueError):  # older jax: defaults are fine
-            pass
+    if not env:
+        jax.config.update("jax_compilation_cache_dir", str(p))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return p
 
 

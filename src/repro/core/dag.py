@@ -24,7 +24,6 @@ import dataclasses
 from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
-from jax import lax
 
 from repro.core import engine as eng
 from repro.core.dag_gen import TaskDag
@@ -98,11 +97,11 @@ class DagModel(eng.TaskModel):
         p = self.p
         src = int(self.cfg.dag.sources[0])
         core = core._replace(
-            ev_time=core.ev_time.at[0].set(dur[src]),
+            ev_time=eng.write(core.ev_time, 0, eng.read(dur, src)),
             stolen=jnp.full((p,), -1, jnp.int32),
         )
         ms = DagState(
-            cur_task=jnp.full((p,), -1, jnp.int32).at[0].set(src),
+            cur_task=eng.write(jnp.full((p,), -1, jnp.int32), 0, src),
             pred=pred0,
             buf=jnp.zeros((p, self.cfg.cap), jnp.int32),
             head=jnp.zeros((p,), jnp.int32),
@@ -122,37 +121,40 @@ class DagModel(eng.TaskModel):
 
         def body(k, s):
             core, ms = s
-            child = cidx[k]
-            pc = ms.pred[child] - 1
+            child = eng.read(cidx, k)
+            pc = eng.read(ms.pred, child) - 1
             ready = pc == 0
-            tl = ms.tail[i]
+            tl = eng.read(ms.tail, i)
             ok = tl < cap
             pos = jnp.minimum(tl, cap - 1)
             ms = ms._replace(
-                pred=ms.pred.at[child].set(pc),
-                buf=ms.buf.at[i, pos].set(
-                    jnp.where(ready & ok, child, ms.buf[i, pos])),
-                tail=ms.tail.at[i].add(jnp.where(ready & ok, 1, 0)),
+                pred=eng.write(ms.pred, child, pc),
+                buf=eng.write(ms.buf, (i, pos),
+                              jnp.where(ready & ok, child,
+                                        eng.read(ms.buf, i, pos))),
+                tail=eng.add(ms.tail, i, jnp.where(ready & ok, 1, 0)),
             )
             core = core._replace(halt=core.halt | (ready & ~ok))
             return core, ms
 
-        return lax.fori_loop(cptr[c], cptr[c + 1], body, (core, ms))
+        return eng.fori_loop(eng.read(cptr, c), eng.read(cptr, c + 1), body,
+                             (core, ms), self.cfg.dag.max_children)
 
     def on_idle(self, arrays, cid, hops, scn, core, ms: DagState, i, t):
         dur, cptr, cidx, _ = arrays
-        c = ms.cur_task[i]
+        c = eng.read(ms.cur_task, i)
         has_task = c >= 0
 
         def complete(s):
             core, ms = s
             ms = ms._replace(n_completed=ms.n_completed + 1,
-                             tasks_run=ms.tasks_run.at[i].add(1))
-            core = core._replace(executed=core.executed.at[i].add(dur[c]))
+                             tasks_run=eng.add(ms.tasks_run, i, 1))
+            core = core._replace(
+                executed=eng.add(core.executed, i, eng.read(dur, c)))
             return self._activate_children(cptr, cidx, core, ms, i, c)
 
-        core, ms = lax.cond(has_task, complete, lambda s: s, (core, ms))
-        ms = ms._replace(cur_task=ms.cur_task.at[i].set(-1))
+        core, ms = eng.cond(has_task, complete, lambda s: s, (core, ms))
+        ms = ms._replace(cur_task=eng.write(ms.cur_task, i, -1))
 
         finished = self.is_done(arrays, core, ms, i, t)
 
@@ -165,20 +167,21 @@ class DagModel(eng.TaskModel):
 
         def _continue(s):
             core, ms = s
-            empty = ms.head[i] >= ms.tail[i]
+            empty = eng.read(ms.head, i) >= eng.read(ms.tail, i)
 
             def pop_local(s):
                 core, ms = s
                 if self.cfg.owner_lifo:
-                    pos = ms.tail[i] - 1
-                    ms = ms._replace(tail=ms.tail.at[i].add(-1))
+                    pos = eng.read(ms.tail, i) - 1
+                    ms = ms._replace(tail=eng.add(ms.tail, i, -1))
                 else:
-                    pos = ms.head[i]
-                    ms = ms._replace(head=ms.head.at[i].add(1))
-                task = ms.buf[i, pos]
-                ms = ms._replace(cur_task=ms.cur_task.at[i].set(task))
+                    pos = eng.read(ms.head, i)
+                    ms = ms._replace(head=eng.add(ms.head, i, 1))
+                task = eng.read(ms.buf, i, pos)
+                ms = ms._replace(cur_task=eng.write(ms.cur_task, i, task))
                 core = core._replace(
-                    ev_time=core.ev_time.at[i].set(t + dur[task]))
+                    ev_time=eng.write(core.ev_time, i,
+                                      t + eng.read(dur, task)))
                 return core, ms
 
             def steal(s):
@@ -187,18 +190,18 @@ class DagModel(eng.TaskModel):
                 core = eng.log(self, core, t, i, EV_IDLE, 0)
                 return eng.start_stealing(self, cid, hops, scn, core, i, t), ms
 
-            return lax.cond(empty, steal, pop_local, s)
+            return eng.cond(empty, steal, pop_local, s)
 
-        return lax.cond(finished, _finish, _continue, (core, ms))
+        return eng.cond(finished, _finish, _continue, (core, ms))
 
     def on_request(self, arrays, cid, hops, scn, core, ms: DagState, i, t):
-        v = core.victim[i]
-        qlen = ms.tail[v] - ms.head[v]
+        v = eng.read(core.victim, i)
+        qlen = eng.read(ms.tail, v) - eng.read(ms.head, v)
         d_vi = eng.dist(cid, hops, scn, v, i)
         free = eng.chan_free(self, core, v, t)
         ok = (qlen > scn.theta_static) & free
-        task = jnp.where(ok, ms.buf[v, ms.head[v]], -1)
-        ms = ms._replace(head=ms.head.at[v].add(jnp.where(ok, 1, 0)))
+        task = jnp.where(ok, eng.read(ms.buf, v, eng.read(ms.head, v)), -1)
+        ms = ms._replace(head=eng.add(ms.head, v, jnp.where(ok, 1, 0)))
         core = eng.deliver_answer(core, i, v, t, d_vi, ok, task)
         core = eng.log(self, core, t, i,
                        jnp.where(ok, EV_REQ_OK, EV_REQ_FAIL), v)
@@ -206,22 +209,24 @@ class DagModel(eng.TaskModel):
 
     def on_answer(self, arrays, cid, hops, scn, core, ms: DagState, i, t):
         dur = arrays[0]
-        task = core.stolen[i]
+        task = eng.read(core.stolen, i)
         ok = task >= 0
 
         def got(s):
             core, ms = s
-            core = eng.acquire_work(self, core, i, t, t + dur[task],
+            core = eng.acquire_work(self, core, i, t,
+                                    t + eng.read(dur, task),
                                     jnp.int32(0), jnp.int32(-1))
-            ms = ms._replace(cur_task=ms.cur_task.at[i].set(task))
+            ms = ms._replace(cur_task=eng.write(ms.cur_task, i, task))
             return eng.log(self, core, t, i, EV_ANS_OK, task), ms
 
         def retry(s):
             core, ms = s
             core = eng.start_stealing(self, cid, hops, scn, core, i, t)
-            return eng.log(self, core, t, i, EV_ANS_FAIL, core.victim[i]), ms
+            return eng.log(self, core, t, i, EV_ANS_FAIL,
+                           eng.read(core.victim, i)), ms
 
-        return lax.cond(ok, got, retry, (core, ms))
+        return eng.cond(ok, got, retry, (core, ms))
 
     def results(self, core: eng.CoreState, ms: DagState) -> DagSimResult:
         return DagSimResult(

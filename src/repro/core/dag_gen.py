@@ -33,6 +33,11 @@ class TaskDag:
         return int(self.dur.shape[0])
 
     @property
+    def max_children(self) -> int:
+        """Largest out-degree of any task."""
+        return int(np.diff(self.child_ptr).max(initial=0))
+
+    @property
     def total_work(self) -> int:
         return int(self.dur.sum())
 

@@ -26,7 +26,6 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from repro.core import engine as eng
 # Re-exported for backward compatibility (these historically lived here).
@@ -56,25 +55,25 @@ class DivisibleModel(eng.TaskModel):
     cfg: EngineConfig
 
     def init(self, arrays, scn: Scenario, core: eng.CoreState):
-        idle_at = core.idle_at.at[0].set(scn.W)
+        idle_at = eng.write(core.idle_at, 0, scn.W)
         core = core._replace(
             idle_at=idle_at,
             ev_time=idle_at,      # everyone's first event is its idle event
-            executed=core.executed.at[0].set(scn.W),
+            executed=eng.write(core.executed, 0, scn.W),
         )
         return core, ()
 
     def is_done(self, arrays, core: eng.CoreState, ms, i, t):
         """No remaining work anywhere: neither running nor in flight
         (processor i's exhaustion is already reflected via state2)."""
-        state2 = core.state.at[i].set(REQ_FLIGHT)
+        state2 = eng.write(core.state, i, REQ_FLIGHT)
         rem_active = jnp.sum(jnp.where(state2 == ACTIVE, core.idle_at - t, 0))
         rem_flight = jnp.sum(jnp.where(state2 == ANS_FLIGHT, core.stolen, 0))
         return (rem_active + rem_flight) == 0
 
     def on_idle(self, arrays, cid, hops, scn, core, ms, i, t):
         """idle event: processor i's running work is exhausted (paper idle())."""
-        state2 = core.state.at[i].set(REQ_FLIGHT)  # tentatively not-active
+        state2 = eng.write(core.state, i, REQ_FLIGHT)  # tentatively not-active
         finished = self.is_done(arrays, core, ms, i, t)
 
         core = eng.enter_idle(core, i, t)
@@ -88,13 +87,14 @@ class DivisibleModel(eng.TaskModel):
         def _steal(c: eng.CoreState) -> eng.CoreState:
             return eng.start_stealing(self, cid, hops, scn, c, i, t)
 
-        return lax.cond(finished, _finish, _steal, core), ms
+        return eng.cond(finished, _finish, _steal, core), ms
 
     def on_request(self, arrays, cid, hops, scn, core, ms, i, t):
         """steal-request event: thief i's request reaches victim v
         (paper answer_steal_request() + get_part_of_work_if_exist())."""
-        v = core.victim[i]
-        w_v = jnp.where(core.state[v] == ACTIVE, core.idle_at[v] - t, 0)
+        v = eng.read(core.victim, i)
+        w_v = jnp.where(eng.read(core.state, v) == ACTIVE,
+                        eng.read(core.idle_at, v) - t, 0)
         d_vi = eng.dist(cid, hops, scn, v, i)
         thr = eng.steal_threshold(scn, d_vi)
         free = eng.chan_free(self, core, v, t)
@@ -104,11 +104,13 @@ class DivisibleModel(eng.TaskModel):
 
         new_idle_v = t + (w_v - amt)
         core = core._replace(
-            idle_at=core.idle_at.at[v].set(
-                jnp.where(ok, new_idle_v, core.idle_at[v])),
-            ev_time=core.ev_time.at[v].set(
-                jnp.where(ok, new_idle_v, core.ev_time[v])),
-            executed=core.executed.at[v].add(-amt),
+            idle_at=eng.write(core.idle_at, v,
+                              jnp.where(ok, new_idle_v,
+                                        eng.read(core.idle_at, v))),
+            ev_time=eng.write(core.ev_time, v,
+                              jnp.where(ok, new_idle_v,
+                                        eng.read(core.ev_time, v))),
+            executed=eng.add(core.executed, v, -amt),
         )
         core = eng.deliver_answer(core, i, v, t, d_vi, ok, amt)
         return eng.log(self, core, t, i,
@@ -117,7 +119,7 @@ class DivisibleModel(eng.TaskModel):
     def on_answer(self, arrays, cid, hops, scn, core, ms, i, t):
         """steal-answer event: the (possibly empty) answer reaches thief i
         (paper steal_answer())."""
-        amt = core.stolen[i]
+        amt = eng.read(core.stolen, i)
         ok = amt > 0
 
         def _got_work(c: eng.CoreState) -> eng.CoreState:
@@ -126,9 +128,10 @@ class DivisibleModel(eng.TaskModel):
 
         def _retry(c: eng.CoreState) -> eng.CoreState:
             c = eng.start_stealing(self, cid, hops, scn, c, i, t)
-            return eng.log(self, c, t, i, EV_ANS_FAIL, c.victim[i])
+            return eng.log(self, c, t, i, EV_ANS_FAIL,
+                           eng.read(c.victim, i))
 
-        return lax.cond(ok, _got_work, _retry, core), ms
+        return eng.cond(ok, _got_work, _retry, core), ms
 
     def results(self, core: eng.CoreState, ms) -> SimResult:
         return SimResult(

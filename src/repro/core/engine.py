@@ -42,6 +42,8 @@ mesh (``sweep.simulate_sharded``), or inside the Pallas kernel
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import functools
 from typing import NamedTuple, Optional, Tuple
@@ -220,10 +222,165 @@ class TaskModel:
 # Shared machinery: distance, victim selection, stealing, answers, logging.
 # ---------------------------------------------------------------------------
 
+_SELECT_FORMS = contextvars.ContextVar("select_forms", default=False)
+
+
+@contextlib.contextmanager
+def select_forms():
+    """Trace the event core with one-hot select forms (the Pallas kernel
+    body, ``kernels.ws_sim``). Mosaic has no lowering for ``scatter``,
+    ``scatter-add``, ``dynamic_slice`` or an int32 ``argmin``; the helpers
+    below emit those forms under XLA and masks, selects and reductions here.
+    All operands are int32/uint32/bool, so both forms are exact."""
+    token = _SELECT_FORMS.set(True)
+    try:
+        yield
+    finally:
+        _SELECT_FORMS.reset(token)
+
+
+def _hit(x, idx):
+    """Bool mask over ``x``'s shape that is true at leading index ``idx``."""
+    m = None
+    for d, i in enumerate(idx):
+        h = lax.broadcasted_iota(jnp.int32, x.shape, d) == i
+        m = h if m is None else m & h
+    return m
+
+
+def _select(m, a, b):
+    """``where(m, a, b)``; bool operands as logic ops, since Mosaic cannot
+    select between bool vectors."""
+    if jnp.result_type(b) == jnp.bool_:
+        return (m & a) | (~m & b)
+    return jnp.where(m, a, b)
+
+
+def read(x, *idx):
+    """``x[idx]``: an element, or a row when ``idx`` indexes fewer dims."""
+    if not _SELECT_FORMS.get():
+        return x[idx[0]] if len(idx) == 1 else x[idx]
+    axes = tuple(range(len(idx)))
+    m = _hit(x, idx)
+    if x.dtype == jnp.bool_:
+        return jnp.sum((m & x).astype(jnp.int32), axis=axes) > 0
+    if jnp.issubdtype(x.dtype, jnp.unsignedinteger):
+        xi = lax.bitcast_convert_type(x, jnp.int32)
+        return jnp.sum(jnp.where(m, xi, 0), axis=axes).astype(x.dtype)
+    return jnp.sum(jnp.where(m, x, 0), axis=axes)
+
+
+def write(x, idx, v):
+    """``x.at[idx].set(v)`` (``idx`` an index or a tuple of indices)."""
+    if not _SELECT_FORMS.get():
+        return x.at[idx].set(v)
+    idx = idx if isinstance(idx, tuple) else (idx,)
+    return _select(_hit(x, idx), jnp.asarray(v, x.dtype), x)
+
+
+def add(x, idx, v):
+    """``x.at[idx].add(v)``."""
+    if not _SELECT_FORMS.get():
+        return x.at[idx].add(v)
+    idx = idx if isinstance(idx, tuple) else (idx,)
+    return jnp.where(_hit(x, idx), x + jnp.asarray(v, x.dtype), x)
+
+
+def argmin(x):
+    """First index of the minimum of a 1-D vector, as int32."""
+    if not _SELECT_FORMS.get():
+        return jnp.argmin(x).astype(jnp.int32)
+    iota = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.min(jnp.where(x == jnp.min(x), iota, x.shape[0]))
+
+
+def cond(pred, true_fun, false_fun, operand):
+    """``lax.cond``. In the kernel body both branches run and a select
+    picks: Mosaic's canonicalizer turns an ``scf.if`` whose branches yield
+    values computed before it into a select on a scalar predicate over
+    vectors, which it then cannot lower."""
+    if not _SELECT_FORMS.get():
+        return lax.cond(pred, true_fun, false_fun, operand)
+    return jax.tree.map(lambda a, b: _select(pred, a, b),
+                        true_fun(operand), false_fun(operand))
+
+
+def _is_flags(x) -> bool:
+    return x.dtype == jnp.bool_ and x.ndim > 0
+
+
+def _to_i32(tree):
+    return jax.tree.map(lambda x: x.astype(jnp.int32) if _is_flags(x) else x,
+                        tree)
+
+
+def _via_i32(fn, like):
+    """``fn`` over int32 stand-ins for the bool vectors of ``like``: it
+    takes and returns int32 where ``fn`` takes and returns bool vectors."""
+    leaves, tree = jax.tree.flatten(like)
+    flags = [_is_flags(x) for x in leaves]
+
+    def wrapped(*args):
+        xs = [x != 0 if f else x
+              for x, f in zip(jax.tree.leaves(args), flags)]
+        return _to_i32(fn(*jax.tree.unflatten(tree, xs)))
+
+    return wrapped
+
+
+def _from_i32(tree, like):
+    return jax.tree.map(lambda x, l: x != 0 if _is_flags(l) else x,
+                        tree, like)
+
+
+def switch(index, branches, *operands):
+    """``lax.switch``. In the kernel body, bool vectors cross the branches
+    as int32: Mosaic cannot legalize an ``scf.if`` that yields them."""
+    if not _SELECT_FORMS.get():
+        return lax.switch(index, branches, *operands)
+    out_like = jax.eval_shape(branches[0], *operands)
+    out = lax.switch(index, [_via_i32(f, operands) for f in branches],
+                     *_to_i32(operands))
+    return _from_i32(out, out_like)
+
+
+def while_loop(cond_fun, body_fun, init_val):
+    """``lax.while_loop``; in the kernel body bool vectors of the carry
+    travel as int32, as in :func:`switch`."""
+    if not _SELECT_FORMS.get():
+        return lax.while_loop(cond_fun, body_fun, init_val)
+    out = lax.while_loop(_via_i32(cond_fun, (init_val,)),
+                         _via_i32(body_fun, (init_val,)), _to_i32(init_val))
+    return _from_i32(out, init_val)
+
+
+def fori_loop(lower, upper, body_fun, init_val, max_trips: int):
+    """``lax.fori_loop`` with traced bounds, ``upper - lower <= max_trips``.
+    In the kernel body it is unrolled ``max_trips`` times, each trip
+    selected on ``k < upper``: Mosaic crashes on a loop with 1-D vector
+    carries inside a branch of the event dispatch."""
+    if not _SELECT_FORMS.get():
+        return lax.fori_loop(lower, upper, body_fun, init_val)
+    x = init_val
+    for r in range(max_trips):
+        k = lower + r
+        x = cond(k < upper, lambda x, k=k: body_fun(k, x), lambda x: x, x)
+    return x
+
+
+def first_true(mask):
+    """First true index of a 1-D bool vector (0 when none), as int32."""
+    if not _SELECT_FORMS.get():
+        return jnp.argmax(mask).astype(jnp.int32)
+    n = mask.shape[0]
+    k = jnp.min(jnp.where(mask, lax.broadcasted_iota(jnp.int32, (n,), 0), n))
+    return jnp.where(k == n, 0, k)
+
+
 def dist(cid, hops, scn: Scenario, i, j):
     """Scalar distance d(i, j) under the scenario's latency scalars."""
-    same = cid[i] == cid[j]
-    d = jnp.where(same, scn.lam_local, scn.lam_remote * hops[i, j])
+    same = read(cid, i) == read(cid, j)
+    d = jnp.where(same, scn.lam_local, scn.lam_remote * read(hops, i, j))
     return jnp.where(i == j, jnp.int32(0), d).astype(jnp.int32)
 
 
@@ -239,7 +396,7 @@ def select_victim(strategy: int, p: int, cid, hops, scn: Scenario,
         rng_i = topo_mod.xorshift32(rng_i)
         go_remote = rng_i < scn.remote_prob
         rng_i = topo_mod.xorshift32(rng_i)
-        my = cid[i]
+        my = read(cid, i)
         idx = jnp.arange(p, dtype=jnp.int32)
         local_mask = (cid == my) & (idx != i)
         remote_mask = cid != my
@@ -247,18 +404,19 @@ def select_victim(strategy: int, p: int, cid, hops, scn: Scenario,
         n = jnp.maximum(mask.sum().astype(jnp.uint32), jnp.uint32(1))
         k = (rng_i % n).astype(jnp.int32)
         csum = jnp.cumsum(mask.astype(jnp.int32))
-        v = jnp.argmax(csum > k).astype(jnp.int32)
+        v = first_true(csum > k)
         v = jnp.where(v == i, (i + 1) % p, v)  # only if both masks empty
         return v, rng_i, rr_i
     if strategy == topo_mod.INV_DISTANCE:
         idx = jnp.arange(p, dtype=jnp.int32)
-        same = cid == cid[i]
-        d = jnp.where(same, scn.lam_local, scn.lam_remote * hops[i]).astype(jnp.float32)
+        same = cid == read(cid, i)
+        d = jnp.where(same, scn.lam_local,
+                      scn.lam_remote * read(hops, i)).astype(jnp.float32)
         w = jnp.where(idx == i, 0.0, 1.0 / jnp.maximum(d, 1.0))
         c = jnp.cumsum(w)
         rng_i = topo_mod.xorshift32(rng_i)
         u = (rng_i.astype(jnp.float32) / jnp.float32(2**32)) * c[-1]
-        v = jnp.argmax(c > u).astype(jnp.int32)
+        v = first_true(c > u)
         v = jnp.where(v == i, (i + 1) % p, v)
         return v, rng_i, rr_i
     if strategy == topo_mod.ROUND_ROBIN:
@@ -272,27 +430,28 @@ def start_stealing(model: TaskModel, cid, hops, scn: Scenario,
                    core: CoreState, i, t) -> CoreState:
     """processor engine start_stealing(): pick victim, emit request event."""
     v, rng_i, rr_i = select_victim(model.topology.strategy, model.p, cid, hops,
-                                   scn, core.rng[i], core.rr_aux[i], i)
+                                   scn, read(core.rng, i),
+                                   read(core.rr_aux, i), i)
     d = dist(cid, hops, scn, i, v)
     return core._replace(
-        state=core.state.at[i].set(REQ_FLIGHT),
-        victim=core.victim.at[i].set(v),
-        ev_time=core.ev_time.at[i].set(t + d),
-        rng=core.rng.at[i].set(rng_i),
-        rr_aux=core.rr_aux.at[i].set(rr_i),
+        state=write(core.state, i, REQ_FLIGHT),
+        victim=write(core.victim, i, v),
+        ev_time=write(core.ev_time, i, t + d),
+        rng=write(core.rng, i, rng_i),
+        rr_aux=write(core.rr_aux, i, rr_i),
     )
 
 
 def enter_idle(core: CoreState, i, t) -> CoreState:
     """Bookkeeping when processor i runs out of work (before it steals)."""
     return core._replace(active_count=core.active_count - 1,
-                         idle_since=core.idle_since.at[i].set(t))
+                         idle_since=write(core.idle_since, i, t))
 
 
 def chan_free(model: TaskModel, core: CoreState, v, t):
     """SWT/MWT answer-channel policy (paper §2.4.1): under SWT a victim
     refuses while a previous answer is still in flight."""
-    return jnp.bool_(model.mwt) | (t >= core.busy_until[v])
+    return jnp.bool_(model.mwt) | (t >= read(core.busy_until, v))
 
 
 def steal_threshold(scn: Scenario, d_vi):
@@ -305,11 +464,11 @@ def deliver_answer(core: CoreState, i, v, t, d_vi, ok, payload) -> CoreState:
     victim's answer channel on success, put ``payload`` in flight toward the
     thief, and account the request."""
     return core._replace(
-        busy_until=core.busy_until.at[v].set(
-            jnp.where(ok, t + d_vi, core.busy_until[v])),
-        stolen=core.stolen.at[i].set(payload),
-        state=core.state.at[i].set(ANS_FLIGHT),
-        ev_time=core.ev_time.at[i].set(t + d_vi),
+        busy_until=write(core.busy_until, v,
+                         jnp.where(ok, t + d_vi, read(core.busy_until, v))),
+        stolen=write(core.stolen, i, payload),
+        state=write(core.state, i, ANS_FLIGHT),
+        ev_time=write(core.ev_time, i, t + d_vi),
         n_requests=core.n_requests + 1,
         n_success=core.n_success + ok.astype(jnp.int32),
         n_fail=core.n_fail + (~ok).astype(jnp.int32),
@@ -323,13 +482,13 @@ def acquire_work(model: TaskModel, core: CoreState, i, t, end, exec_add,
     new_active = core.active_count + 1
     first_full = (new_active == model.p) & (core.startup_end < 0)
     return core._replace(
-        state=core.state.at[i].set(ACTIVE),
-        idle_at=core.idle_at.at[i].set(end),
-        ev_time=core.ev_time.at[i].set(end),
-        stolen=core.stolen.at[i].set(stolen_reset),
-        executed=core.executed.at[i].add(exec_add),
+        state=write(core.state, i, ACTIVE),
+        idle_at=write(core.idle_at, i, end),
+        ev_time=write(core.ev_time, i, end),
+        stolen=write(core.stolen, i, stolen_reset),
+        executed=add(core.executed, i, exec_add),
         active_count=new_active,
-        total_idle=core.total_idle + (t - core.idle_since[i]),
+        total_idle=core.total_idle + (t - read(core.idle_since, i)),
         startup_end=jnp.where(first_full, t, core.startup_end),
     )
 
@@ -352,9 +511,12 @@ def log(model: TaskModel, core: CoreState, t, proc, kind, aux) -> CoreState:
     row = jnp.stack([t, proc, jnp.int32(kind), jnp.asarray(aux, jnp.int32)])
     idx = jnp.minimum(core.n_trace, model.max_trace - 1)
     keep = core.n_trace < model.max_trace
-    trace = lax.dynamic_update_slice(
-        core.trace, jnp.where(keep, row, core.trace[idx])[None, :],
-        (idx, jnp.int32(0)))
+    row = jnp.where(keep, row, read(core.trace, idx))
+    if _SELECT_FORMS.get():
+        trace = write(core.trace, idx, row)
+    else:
+        trace = lax.dynamic_update_slice(core.trace, row[None, :],
+                                         (idx, jnp.int32(0)))
     return core._replace(trace=trace,
                          n_trace=core.n_trace + keep.astype(jnp.int32))
 
@@ -419,12 +581,12 @@ def _simulate_impl(model: TaskModel, cid, hops, arrays, scn: Scenario):
 
     def body(s):
         c, m = s
-        i = jnp.argmin(c.ev_time).astype(jnp.int32)
-        t = c.ev_time[i]
+        i = argmin(c.ev_time)
+        t = read(c.ev_time, i)
         c = c._replace(t=t, n_events=c.n_events + 1)
-        return lax.switch(c.state[i], handlers, c, m, i, t)
+        return switch(read(c.state, i), handlers, c, m, i, t)
 
-    core, ms = lax.while_loop(cond, body, (core, ms))
+    core, ms = while_loop(cond, body, (core, ms))
     return model.results(core, ms)
 
 
@@ -485,10 +647,10 @@ def _segment_impl(model: TaskModel, cid, hops, arrays, scn: Scenario,
 
     def body(s):
         c, m, k = s
-        i = jnp.argmin(c.ev_time).astype(jnp.int32)
-        t = c.ev_time[i]
+        i = argmin(c.ev_time)
+        t = read(c.ev_time, i)
         c = c._replace(t=t, n_events=c.n_events + 1)
-        c, m = lax.switch(c.state[i], handlers, c, m, i, t)
+        c, m = switch(read(c.state, i), handlers, c, m, i, t)
         return (c, m, k + jnp.int32(1))
 
     core, ms, k = lax.while_loop(cond, body, (core, ms, jnp.int32(0)))
@@ -690,8 +852,9 @@ class SegmentedRun:
             keep = np.flatnonzero(real)
             gidx = np.concatenate(
                 [keep, np.zeros(new_width - k, np.int64)]).astype(np.int32)
+            # host operands: they go straight to the run's device
             self.state, self.scn = _compact_fn()(
-                self.state, self.scn, jnp.asarray(gidx), jnp.int32(k))
+                self.state, self.scn, gidx, np.int32(k))
             self.idx = np.concatenate(
                 [self.idx[keep], np.full(new_width - k, -1)])
             self.stats.n_compactions += 1

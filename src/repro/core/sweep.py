@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import adaptive as ad
 from repro.core import divisible
 from repro.core import dag as dg
@@ -206,25 +207,35 @@ def canonical_grid(
 
 
 def scenario_from_rows(rows: GridRows, remote_prob: float = 0.25,
-                       ev_budget=None) -> Scenario:
+                       ev_budget=None, device=None) -> Scenario:
     """Batched Scenario from canonical rows (λ sets both latency scalars).
 
     ``ev_budget`` (scalar or per-row array) fills the per-row event-budget
     column; None defers every row to the model's static ``max_events`` cap.
+    The columns are built on the host and placed straight onto ``device``
+    (a device or a sharding; None: the default device), so a chunk bound
+    for one device is never staged on another.
     """
+    return jax.device_put(_host_scenario(rows, remote_prob, ev_budget),
+                          device)
+
+
+def _host_scenario(rows: GridRows, remote_prob: float,
+                   ev_budget) -> Scenario:
+    """:func:`scenario_from_rows`'s columns as host (numpy) arrays."""
     n = len(rows)
-    budget = eng.INF32 if ev_budget is None else ev_budget
+    budget = eng.INF32 if ev_budget is None else np.asarray(ev_budget)
     return Scenario(
-        W=jnp.asarray(rows.W),
-        seed=jnp.asarray(rows.seed),
-        lam_local=jnp.asarray(rows.lam_local),
-        lam_remote=jnp.asarray(rows.lam_remote),
-        theta_static=jnp.asarray(rows.theta_static),
-        theta_comm=jnp.asarray(rows.theta_comm),
-        remote_prob=jnp.full((n,),
-                             np.uint32(remote_prob_u32(float(remote_prob)))),
-        max_events=jnp.broadcast_to(
-            jnp.asarray(budget, jnp.int32), (n,)),
+        W=np.asarray(rows.W, np.int32),
+        seed=np.asarray(rows.seed, np.uint32),
+        lam_local=np.asarray(rows.lam_local, np.int32),
+        lam_remote=np.asarray(rows.lam_remote, np.int32),
+        theta_static=np.asarray(rows.theta_static, np.int32),
+        theta_comm=np.asarray(rows.theta_comm, np.int32),
+        remote_prob=np.full((n,),
+                            np.uint32(remote_prob_u32(float(remote_prob)))),
+        max_events=np.broadcast_to(
+            np.asarray(budget).astype(np.int32), (n,)),
     )
 
 
@@ -378,9 +389,10 @@ def run_rows(model: eng.TaskModel, rows: GridRows, remote_prob: float = 0.25,
                 f"mesh-sharded sweeps require the 'jax' backend, got "
                 f"{be.name!r}")
         model = as_model(model)
-        scn = scenario_from_rows(rows, remote_prob=remote_prob,
-                                 ev_budget=ev_budget)
-        res = simulate_sharded(model, scn, mesh, shard_axes)
+        # host columns: simulate_sharded puts each shard on its device
+        res = simulate_sharded(
+            model, _host_scenario(rows, remote_prob, ev_budget), mesh,
+            shard_axes)
         return grid_from_result(model.p, rows, res)
     be = bk.get_backend(backend)
     if reroute is None:
@@ -493,15 +505,21 @@ def simulate_sharded(model, scn: Scenario, mesh: Mesh,
     def pad_leaf(x):
         if pad == 0:
             return x
-        filler = jnp.ones((pad,), x.dtype)  # W=1 dummy scenarios terminate fast
-        return jnp.concatenate([x, filler], axis=0)
+        filler = np.ones((pad,), x.dtype)  # W=1 dummy scenarios terminate fast
+        cat = np.concatenate if isinstance(x, np.ndarray) else jnp.concatenate
+        return cat([x, filler], axis=0)
 
     scn_p = jax.tree.map(pad_leaf, scn)
     sharding = NamedSharding(mesh, P(tuple(shard_axes)))
     scn_p = jax.tree.map(lambda x: jax.device_put(x, sharding), scn_p)
+    for shard in scn_p.W.addressable_shards:
+        obs.REGISTRY.counter("backend.device_rows", {
+            "backend": "jax", "device": str(shard.device.id)}).inc(
+                int(shard.data.shape[0]))
     out = eng.simulate_batch(model, scn_p)
     if pad:
-        out = jax.tree.map(lambda x: x[:n], out)
+        # a static slice: no index array crosses devices
+        out = jax.tree.map(lambda x: jax.lax.slice_in_dim(x, 0, n), out)
     return out
 
 

@@ -11,13 +11,16 @@ the library engine (``repro.core.engine._simulate_impl``), so the kernel is
 bit-identical to the oracle-validated engine by construction — for EVERY
 task model (divisible, DAG, adaptive), not just the divisible hot path.
 
-Grid: ``(G,)`` scenarios; BlockSpecs give each cell one scenario row of each
-parameter vector and one row of each result leaf. The wrapper is fully
-generic: it derives the output pytree via ``jax.eval_shape`` on the model's
-result type and threads the model's static arrays (DAG durations/edges) as
-kernel inputs rather than closure constants. Validated in interpret mode on
-CPU; on a real TPU the same call compiles via Mosaic (the body is
-argmin/compare/select vector ops over int32 lanes — all VPU-friendly).
+Grid: ``(G,)`` scenarios. The scenario parameters are whole ``(G,)``
+columns in SMEM read at the grid index; each result leaf has a block whose
+last two dims are the array's own (the TPU's block rule), reshaped back in
+the wrapper. The wrapper is fully generic: it derives the output pytree via
+``jax.eval_shape`` on the model's result type and threads the model's static
+arrays (DAG durations/edges) as kernel inputs rather than closure constants.
+The body is traced under ``engine.select_forms()``, so every indexed access
+of the event core is a one-hot mask, select and reduction over int32 lanes:
+Mosaic lowers those, and not gather/scatter or an int32 ``argmin``. It runs
+in interpret mode on the CPU and compiles via Mosaic on a TPU.
 """
 from __future__ import annotations
 
@@ -26,23 +29,54 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import engine as eng
 from repro.core.backend import pallas_interpret_default
 from repro.core.sweep import as_model
 
 
+def _fresh(x):
+    """``x`` through a select. Mosaic fails to lay out a vector loaded
+    from a ref that is then carried through the event loop's branches; a
+    computed value carries fine."""
+    return jnp.where(lax.broadcasted_iota(jnp.int32, x.shape, 0) >= 0, x, 0)
+
+
 def _kernel(*refs, model, n_const, n_scn, scn_def, bool_mask):
-    consts = [refs[k][...] for k in range(n_const)]
+    consts = [_fresh(refs[k][...]) for k in range(n_const)]
+    row = pl.program_id(0)
     scn = jax.tree.unflatten(
-        scn_def, [refs[n_const + k][0] for k in range(n_scn)])
-    res = eng._simulate_impl(model, consts[0], consts[1],
-                             tuple(consts[2:]), scn)
+        scn_def, [refs[n_const + k][row] for k in range(n_scn)])
+    with eng.select_forms():
+        res = eng._simulate_impl(model, consts[0], consts[1],
+                                 tuple(consts[2:]), scn)
     out_refs = refs[n_const + n_scn:]
     for leaf, ref, is_bool in zip(jax.tree.leaves(res), out_refs, bool_mask):
         val = leaf.astype(jnp.int32) if is_bool else leaf
-        ref[(0,) + (slice(None),) * leaf.ndim] = val
+        ref[...] = val.reshape(ref.shape)
+
+
+def _tile_shape(shape) -> tuple:
+    """A result leaf's shape with unit dims prepended up to rank 2, so that
+    the last two dims of every output block equal the array's own (the
+    TPU's block rule); the wrapper reshapes back."""
+    shape = tuple(shape)
+    return (1,) * max(2 - len(shape), 0) + shape
+
+
+@functools.lru_cache(maxsize=64)
+def _host_consts(model) -> tuple:
+    """The kernel's constant inputs (topology, then the model's static
+    arrays) as host arrays, fetched once per model. Each dispatch copies
+    them from the host to its scenarios' device, so a row chunk on one
+    chip never reads another chip's copy."""
+    return ((np.asarray(model.topology.cluster_id),
+             np.asarray(model.topology.hops))
+            + tuple(jax.device_get(model.static_arrays())))
 
 
 def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
@@ -79,7 +113,10 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
                 return jnp.concatenate(
                     [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])])
             scn = jax.tree.map(pad_leaf, scn)
-            scn = scn._replace(max_events=scn.max_events.at[G:].set(0))
+            # pad rows get a zero budget; a host mask, where ``.at[G:]``
+            # would stage its index on the default device
+            scn = scn._replace(max_events=jnp.where(
+                np.arange(G + pad) < G, scn.max_events, 0))
         chunks = [jax.tree.map(lambda x: x[lo:lo + c], scn)
                   for lo in range(0, G + pad, c)]
         outs = [ws_sim_pallas(model, ck, interpret=interpret)
@@ -87,34 +124,35 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
         res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *outs)
         return jax.tree.map(lambda x: x[:G], res) if pad else res
 
-    consts = (jnp.asarray(model.topology.cluster_id),
-              jnp.asarray(model.topology.hops)) + tuple(model.static_arrays())
     scn_leaves, scn_def = jax.tree.flatten(scn)
+    consts = _host_consts(model)
 
     scn1 = jax.tree.unflatten(
         scn_def, [jax.ShapeDtypeStruct((), l.dtype) for l in scn_leaves])
     res_struct = jax.eval_shape(
-        lambda s: eng._simulate_impl(model, consts[0], consts[1],
-                                     consts[2:], s), scn1)
+        lambda c, s: eng._simulate_impl(model, c[0], c[1], c[2:], s),
+        consts, scn1)
     res_leaves, res_def = jax.tree.flatten(res_struct)
     bool_mask = [l.dtype == jnp.bool_ for l in res_leaves]
-
-    def _block(shape):
-        rank = len(shape)
-        return pl.BlockSpec((1,) + tuple(shape),
-                            lambda i, rank=rank: (i,) + (0,) * rank)
 
     def _const_spec(x):
         rank = x.ndim
         return pl.BlockSpec(x.shape, lambda i, rank=rank: (0,) * rank)
 
-    scalar_spec = pl.BlockSpec((1,), lambda i: (i,))
+    def _out_spec(shape):
+        rank = len(shape)
+        return pl.BlockSpec((1,) + shape,
+                            lambda i, rank=rank: (i,) + (0,) * rank)
+
+    # Scenario scalars: the whole (G,) column in SMEM, read at the grid
+    # index (a (1,) VMEM block is below the TPU's 128-lane tiling).
     in_specs = ([_const_spec(c) for c in consts]
-                + [scalar_spec] * len(scn_leaves))
-    out_shape = [jax.ShapeDtypeStruct((G,) + tuple(l.shape),
+                + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scn_leaves))
+    tiles = [_tile_shape(l.shape) for l in res_leaves]
+    out_shape = [jax.ShapeDtypeStruct((G,) + tile,
                                       jnp.int32 if b else l.dtype)
-                 for l, b in zip(res_leaves, bool_mask)]
-    out_specs = [_block(l.shape) for l in res_leaves]
+                 for l, b, tile in zip(res_leaves, bool_mask, tiles)]
+    out_specs = [_out_spec(tile) for tile in tiles]
 
     outs = pl.pallas_call(
         functools.partial(_kernel, model=model, n_const=len(consts),
@@ -127,6 +165,7 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
         interpret=interpret,
     )(*consts, *scn_leaves)
 
+    outs = [o.reshape((G,) + l.shape) for o, l in zip(outs, res_leaves)]
     outs = [o.astype(jnp.bool_) if b else o for o, b in zip(outs, bool_mask)]
     return jax.tree.unflatten(res_def, outs)
 

@@ -44,7 +44,7 @@ class SimulationService:
                  relax_max_events: bool = True,
                  lock_wait_s: Optional[float] = 60.0,
                  straggler_sort: bool = True,
-                 compile_cache: Union[None, bool, str, os.PathLike] = None,
+                 compile_cache: bool = False,
                  dispatch_log_max: Optional[int] = 1024,
                  metrics: Optional[obs.MetricsRegistry] = None,
                  resilience: Optional[rz.ResilienceConfig] = None):
@@ -64,17 +64,13 @@ class SimulationService:
                                   metrics=self.metrics,
                                   resilience=resilience)
         self.confidence = float(confidence)
-        # Opt-in persistent XLA compilation cache: None defers to the
-        # REPRO_WS_JIT_CACHE env var, True uses the default
-        # artifacts/jit_cache/ dir, a path uses that path, False disables.
-        if compile_cache is None:
-            compile_cache = bool(
-                os.environ.get(bk_mod.JIT_CACHE_ENV, "").strip())
-        if compile_cache:
-            self.compile_cache_dir = bk_mod.enable_compile_cache(
-                None if compile_cache is True else compile_cache)
-        else:
-            self.compile_cache_dir = None
+        # JAX's persistent compilation cache: on wherever
+        # JAX_COMPILATION_CACHE_DIR is set (JAX reads it itself);
+        # compile_cache=True also turns it on without it
+        # (backend.enable_compile_cache).
+        self.compile_cache_dir = (bk_mod.enable_compile_cache()
+                                  if compile_cache
+                                  else bk_mod.compile_cache_dir())
 
     # -- query construction -------------------------------------------------
 

@@ -5,7 +5,9 @@ RPC to a :class:`~repro.service.daemon.SimulationDaemon` when one is
 listening, and *degrades to in-process library mode transparently* when it
 is not — absent socket, daemon killed mid-round, version mismatch, a
 question that cannot cross the wire (DAG arrays): every path ends in an
-answer, never a client-visible transport exception. Mixing the two modes
+answer, never a client-visible transport exception — except where library
+mode would run on another platform than the daemon's, which raises
+:class:`DaemonUnavailable` instead of hiding the device. Mixing the two modes
 is safe by construction: daemon and library fill the same content-addressed
 store with byte-identical artifacts, so whatever one mode computed the
 other serves as a cache hit.
@@ -38,8 +40,10 @@ from repro.service.wire import WireError
 
 
 class DaemonUnavailable(RuntimeError):
-    """Raised only when ``fallback=False`` and the daemon path failed;
-    with fallback enabled (the default) it is never visible to callers."""
+    """Raised when ``fallback=False`` and the daemon path failed, and when
+    an in-process fallback would run on another platform than the daemon
+    (a daemon on a TPU host holds the chip, so this process would get the
+    CPU)."""
 
 
 class WireQuery:
@@ -88,6 +92,7 @@ class DaemonClient:
         self.n_daemon_answers = 0
         self.n_fallbacks = 0
         self.n_busy_retries = 0
+        self.daemon_platform: Optional[str] = None  # as the last ping said
 
     # -- the two substrates --------------------------------------------------
 
@@ -105,10 +110,28 @@ class DaemonClient:
     def _fall_back(self, why: str):
         if not self.fallback:
             raise DaemonUnavailable(why)
+        self._check_fallback_platform(why)
         self.n_fallbacks += 1
         self.metrics.counter("client.fallbacks").inc()
         obs.REGISTRY.info("client.last_fallback").set(why)
         return self.local
+
+    def _check_fallback_platform(self, why: str) -> None:
+        """Refuse an in-process fallback onto another platform than the
+        daemon's: while a daemon holds the host's accelerator, this process
+        cannot open it and JAX would quietly run the simulation on the
+        CPU."""
+        if self.daemon_platform is None:
+            self.alive()
+        if self.daemon_platform is None:
+            return
+        import jax
+        local = jax.devices()[0].platform
+        if local != self.daemon_platform:
+            raise DaemonUnavailable(
+                f"the daemon runs on {self.daemon_platform!r} but this "
+                f"process sees {local!r}; refusing an in-process fallback "
+                f"onto another platform ({why})")
 
     # -- transport -----------------------------------------------------------
 
@@ -163,8 +186,11 @@ class DaemonClient:
             resp = self._rpc_once({"op": "ping"})
         except (OSError, WireError):
             return False
-        return bool(resp.get("ok")) \
+        ok = bool(resp.get("ok")) \
             and resp.get("protocol") == PROTOCOL_VERSION
+        if ok:
+            self.daemon_platform = resp.get("platform")
+        return ok
 
     # -- queries -------------------------------------------------------------
 

@@ -7,7 +7,8 @@ the warm JIT/compile caches, one :class:`QueryBroker` and its
 length-prefixed JSON RPC of :mod:`repro.service.wire` over a unix socket:
 
 ``ping``
-    liveness probe (also returns the protocol version).
+    liveness probe (also returns the protocol version and the platform of
+    the device the daemon runs on).
 ``submit``
     enqueue one query (solo or paired) on this connection; admission
     controlled — over ``max_pending`` queries daemon-wide it soft-rejects
@@ -326,7 +327,8 @@ class SimulationDaemon:
                 self.n_rpcs += 1
             if op == "ping":
                 return {"ok": True, "pong": True,
-                        "protocol": PROTOCOL_VERSION, "pid": os.getpid()}
+                        "protocol": PROTOCOL_VERSION, "pid": os.getpid(),
+                        "platform": _platform()}
             if op == "submit":
                 return self._op_submit(client, req)
             if op == "flush":
@@ -555,6 +557,13 @@ class SimulationDaemon:
         return out
 
 
+def _platform() -> str:
+    """Platform of the device the daemon dispatches to (``tpu``, ``cpu``,
+    ...): clients refuse to fall back in-process onto another one."""
+    import jax
+    return jax.devices()[0].platform
+
+
 def _make_query_kw(kw: dict) -> dict:
     """Wire kwargs -> ``make_query`` kwargs (JSON lists re-tupled where
     the query dataclass wants tuples; unknown keys pass through as
@@ -600,7 +609,8 @@ def main(argv=None) -> int:
         socket_path=args.socket, root=args.root,
         max_pending=args.max_pending,
         coalesce_window_s=args.coalesce_window_s,
-        max_round_queries=args.max_round_queries)
+        max_round_queries=args.max_round_queries,
+        compile_cache=True)
 
     def _term(signum, frame):
         daemon.stop()

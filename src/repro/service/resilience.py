@@ -475,9 +475,10 @@ class CircuitBreaker:
 # -- backend fallback chain ---------------------------------------------------
 
 #: Demotion preference among registered backends: fastest real substrate
-#: first, the serial oracle as the dependable floor, interpret mode last
-#: (correct everywhere but far slower than the oracle on small batches).
-FALLBACK_ORDER = ("pallas", "jax", "oracle", "pallas_interpret")
+#: first, the serial oracle as the dependable floor. Interpret mode is a
+#: test substrate, never a recovery: a demotion onto it would hide that the
+#: device path failed.
+FALLBACK_ORDER = ("pallas", "jax", "oracle")
 
 
 def backend_compatible(be, model) -> bool:

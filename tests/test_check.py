@@ -225,11 +225,37 @@ def test_jaxpr_flags_host_callback():
 
 
 def test_jaxpr_flags_float64():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * 2.0)(jnp.float64(1.0))
     got = jaxpr_lint.scan_jaxpr(closed, where="synthetic", symbol="t")
     assert "dtype.f64" in {g.rule for g in got}
+
+
+@pytest.mark.parametrize("name", ["divisible", "dag", "adaptive"])
+def test_kernel_body_has_no_mosaic_unlowerable_op(name):
+    model = dict(jaxpr_lint.tiny_models())[name]
+    closed = jaxpr_lint.trace_pallas(model, 4)
+    prims = {e.primitive.name for e in jaxpr_lint.iter_eqns(closed.jaxpr)}
+    assert "pallas_call" in prims
+    assert jaxpr_lint.kernel_op_findings(closed, name) == []
+
+
+def test_kernel_op_rule_flags_scatter_and_int_argmin():
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        x = x_ref[...]
+        i = jnp.argmin(x).astype(jnp.int32)
+        o_ref[...] = x.at[i].set(0)
+
+    def f(x):
+        return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+            x.shape, x.dtype), interpret=True)(x)
+
+    closed = jax.make_jaxpr(f)(jnp.arange(8, dtype=jnp.int32))
+    got = {g.message.split("'")[1]
+           for g in jaxpr_lint.kernel_op_findings(closed, "t")}
+    assert got == {"argmin[int32]", "scatter"}
 
 
 def test_structural_signature_catches_shape_branch():

@@ -1,0 +1,117 @@
+"""Compile the main path for a described TPU v5e chip, with no chip attached.
+
+The TPU's compiler is installed with JAX, so the programs the chip would run
+are compiled here at their real sizes: the ``ws_sim`` Pallas kernel for each
+task model at the sizes ``chip_smoke.py`` runs, and the ``jax`` backend's
+segment step at the paper cell. This catches what interpret mode cannot: a
+block shape, an op or a layout the chip's kernel compiler refuses.
+
+The topology is described inside a fixture (never at import), since only
+one process at a time may load the TPU library; the persistent compilation
+cache is off around these compiles, as an entry written for a described chip
+cannot be read back.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import dag_gen
+from repro.core import divisible as dv
+from repro.core import engine as eng
+from repro.core import sweep as sw
+from repro.core.backend import PallasBackend
+from repro.core.topology import one_cluster
+from repro.kernels.ws_sim import ws_sim_pallas
+
+GRID_CHUNK = PallasBackend.grid_chunk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _scenario_spec(n, sharding):
+    scn = sw.scenario_from_rows(sw.grid_rows([1], [1], n))
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        scn)
+
+
+def _paper_model():
+    """The main path's model: one row of the paper's grid at p=256."""
+    return sw.resolve_model(one_cluster(256, 1), "divisible",
+                            W_list=[10**7], lam_list=[2, 62, 262, 482],
+                            pow2_max_events=True)
+
+
+def _models():
+    p, lam, W = 32, 10, 200_000           # benchmarks' model_throughput
+    topo = one_cluster(p, lam)
+    return {
+        "divisible": _paper_model(),
+        "dag": sw.make_model("dag", topology=topo,
+                             dag=dag_gen.merge_sort(20_000, 64),
+                             max_events=1 << 20),
+        "adaptive": sw.make_model("adaptive", topology=topo,
+                                  pool_cap=1 << 13,
+                                  max_events=dv.default_max_events(W, p, lam)),
+    }
+
+
+@pytest.mark.parametrize("name", ["divisible", "dag", "adaptive"])
+def test_ws_sim_kernel_compiles_for_v5e(one_chip, name):
+    model = _models()[name]
+    fn = jax.jit(functools.partial(ws_sim_pallas, model, interpret=False,
+                                   grid_chunk=GRID_CHUNK))
+    lowered = fn.lower(_scenario_spec(GRID_CHUNK, one_chip))
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_jax_segment_step_compiles_for_v5e(one_chip):
+    model = _paper_model()
+    n = 256
+    scn = _scenario_spec(n, one_chip)
+    state = jax.eval_shape(eng._init_fn(model), scn)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        state)
+    step = eng._segment_step(model, eng.default_segment_len(model.max_events))
+    compiled = step.lower(scn, state).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_jax_compaction_compiles_for_v5e(one_chip):
+    model = _paper_model()
+    scn = _scenario_spec(256, one_chip)
+    state = jax.eval_shape(eng._init_fn(model), scn)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        state)
+    idx = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    eng._compact_fn().lower(state, scn, idx, k).compile()

@@ -419,6 +419,22 @@ def test_client_without_daemon_is_library_mode(tmp_path):
             TOPO, W_list=[500], lam_list=[2], reps=3)
 
 
+def test_fallback_refused_onto_another_platform(tmp_path, daemon,
+                                                monkeypatch):
+    """The ping names the daemon's platform. Where it differs from this
+    process's (a daemon holding a host's TPU leaves this process the CPU),
+    an in-process fallback raises instead of running somewhere else."""
+    import jax
+    from repro.service import daemon as daemon_mod
+    c = DaemonClient(root=tmp_path / "store")
+    assert c.alive() and c.daemon_platform == jax.devices()[0].platform
+    monkeypatch.setattr(daemon_mod, "_platform", lambda: "tpu")
+    c = DaemonClient(root=tmp_path / "store")
+    with pytest.raises(DaemonUnavailable, match="another platform"):
+        c.query_many([c.make_query(TOPO, dag=np.zeros(3))])
+    assert c.daemon_platform == "tpu" and c.n_fallbacks == 0
+
+
 def test_unserializable_query_uses_library_mode(tmp_path, daemon):
     """Array-valued model kwargs cannot cross the wire; with fallback off
     that is a DaemonUnavailable at *encode* time — the daemon is never

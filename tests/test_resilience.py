@@ -286,6 +286,19 @@ def test_fallback_chain_excludes_incompatible_oracle():
     assert "oracle" not in rz.fallback_chain("jax", dag)
 
 
+def test_fallback_chain_never_demotes_to_interpret_mode():
+    # Interpret mode is a test substrate: a demotion onto it would hide
+    # that the device path failed.
+    from repro.core import dag_gen as gen
+    dag = resolve_model(TOPO, "dag", W_list=[100], lam_list=[2],
+                        dag=gen.binary_tree(4))
+    for primary in ("pallas", "jax"):
+        for model in (_model(), dag):
+            chain = rz.fallback_chain(primary, model)
+            assert "pallas_interpret" not in chain
+            assert chain[0] == primary
+
+
 # ---------------------------------------------------------------------------
 # dispatch_resilient: bisection salvage economics
 # ---------------------------------------------------------------------------

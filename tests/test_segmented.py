@@ -257,6 +257,65 @@ def test_run_rows_shards_across_forced_host_devices(tmp_path):
     assert "MULTIDEV_OK" in proc.stdout
 
 
+NO_CROSSING_SCRIPT = r"""
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro import obs
+from repro.core import backend as bk
+from repro.core import topology as T
+from repro.core.sweep import (grid_from_result, grid_rows, resolve_model,
+                              run_rows, scenario_from_rows)
+from repro.kernels.ws_sim import ws_sim_pallas
+
+devs = jax.local_devices()
+model = resolve_model(T.one_cluster(4, 2), "divisible", W_list=[800],
+                      lam_list=[2])
+rows = grid_rows([800], [2, 5], 15)                 # 30: not a multiple of 4
+ref = run_rows(model, rows, backend="jax", devices=devs[:1])
+
+
+def same(g):
+    return all(np.array_equal(np.asarray(getattr(g, c)),
+                              np.asarray(getattr(ref, c)))
+               for c in ("makespan", "n_requests", "total_idle", "overflow"))
+
+
+def device_rows(backend):
+    c = obs.REGISTRY.snapshot()["counters"]
+    return [c.get(f"backend.device_rows{{backend={backend},device={d.id}}}",
+                  0) for d in devs]
+
+
+# A chunk bound for device k is placed from the host and never staged on,
+# or copied from, another device.
+with jax.transfer_guard_device_to_device("disallow_explicit"):
+    assert same(run_rows(model, rows, backend="jax"))
+    assert same(run_rows(model, rows, mesh=Mesh(np.array(devs), ("data",))))
+    scn = scenario_from_rows(rows, device=devs[2])
+    out = ws_sim_pallas(model, scn, interpret=True, grid_chunk=8)
+    assert out.makespan.devices() == {devs[2]}
+    assert same(grid_from_result(model.p, rows, jax.device_get(out)))
+assert all(n > 0 for n in device_rows("jax")), device_rows("jax")
+print("NO_CROSSING_OK")
+"""
+
+
+def test_sharded_chunks_never_cross_devices(tmp_path):
+    import repro
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    env["JAX_PLATFORMS"] = "cpu"
+    src = str(Path(list(repro.__path__)[0]).resolve().parent)
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    script = tmp_path / "no_crossing.py"
+    script.write_text(NO_CROSSING_SCRIPT)
+    proc = subprocess.run([sys.executable, str(script)], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "NO_CROSSING_OK" in proc.stdout
+
+
 # ---------------------------------------------------------------------------
 # Small-batch crossover reroute.
 # ---------------------------------------------------------------------------
@@ -333,30 +392,42 @@ def test_straggler_sort_orders_dispatch_bitexact(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Persistent compile cache (opt-in).
+# Persistent compile cache: JAX_COMPILATION_CACHE_DIR, else artifacts/.
 # ---------------------------------------------------------------------------
 
 def test_compile_cache_opt_in(tmp_path, monkeypatch):
-    monkeypatch.delenv(bk.JIT_CACHE_ENV, raising=False)
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.delenv(bk.JAX_CACHE_ENV, raising=False)
     prev = jax.config.jax_compilation_cache_dir
     try:
+        jax.config.update("jax_compilation_cache_dir", None)
         svc0 = SimulationService(root=tmp_path / "s0")
         assert svc0.compile_cache_dir is None          # default: off
         assert svc0.stats()["compile_cache"] is None
 
-        cache = tmp_path / "jit"
-        svc = SimulationService(root=tmp_path / "s1", compile_cache=cache)
-        assert svc.compile_cache_dir == cache and cache.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(cache)
-        r = svc.query(T.one_cluster(4, 1), W_list=[500], lam_list=[2],
-                      reps=2)
-        assert not r.grid.overflow.any()
-        st = svc.stats()
-        assert st["compile_cache"] == str(cache)
-        assert st["n_devices"] >= 1 and "n_history_cells" in st
+        # Turned on without the variable: the fixed checkout directory.
+        assert bk.enable_compile_cache() == bk.default_jit_cache_dir()
+        assert jax.config.jax_compilation_cache_dir == str(
+            bk.default_jit_cache_dir())
 
-        monkeypatch.setenv(bk.JIT_CACHE_ENV, str(tmp_path / "env_jit"))
-        svc2 = SimulationService(root=tmp_path / "s2")  # env var opt-in
-        assert svc2.compile_cache_dir == tmp_path / "env_jit"
+        # With the variable set (JAX reads it at start-up), the program
+        # sets no other directory and the cache is written there alone.
+        env_dir = tmp_path / "env_jit"
+        monkeypatch.setenv(bk.JAX_CACHE_ENV, str(env_dir))
+        jax.config.update("jax_compilation_cache_dir", str(env_dir))
+        cc.reset_cache()
+        svc = SimulationService(root=tmp_path / "s1", compile_cache=True)
+        assert svc.compile_cache_dir == env_dir and env_dir.is_dir()
+        assert jax.config.jax_compilation_cache_dir == str(env_dir)
+        r = svc.query(T.one_cluster(4, 1), W_list=[500], lam_list=[2],
+                      reps=16)                 # above the oracle reroute
+        assert not r.grid.overflow.any()
+        assert any(env_dir.iterdir())
+        st = svc.stats()
+        assert st["compile_cache"] == str(env_dir)
+        assert st["n_devices"] >= 1 and "n_history_cells" in st
+        assert SimulationService(
+            root=tmp_path / "s2").compile_cache_dir == env_dir
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+        cc.reset_cache()
