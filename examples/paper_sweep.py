@@ -163,13 +163,16 @@ def all_task_models(reps=8):
           f"(median splits {float(np.median(g.extras['n_splits'])):.0f})")
 
 
-def trace_and_metrics(out="paper_sweep_trace.json"):
+def trace_and_metrics(out="paper_sweep_trace"):
     """Beyond-paper: the observability layer (DESIGN.md §9). Trace one
-    query end-to-end — service.query -> broker.flush -> broker.dispatch ->
-    backend.run_rows -> engine.segment -> store puts/gets — into a
-    Perfetto-loadable Chrome-trace JSON, and print the span summary plus
-    the metrics snapshot that a monitoring daemon would scrape. The same
-    tracing is available process-wide via ``REPRO_WS_TRACE=path.json``."""
+    query end-to-end — service.query -> broker.flush -> broker.round ->
+    broker.dispatch -> backend.run_rows -> engine.segment / ws_sim.chunk ->
+    backend.fetch, estimator.update, store puts/gets — in a profiler
+    session written to the directory ``out`` (the xplane, and a
+    Perfetto-loadable trace beside JAX's own events), and print the span
+    summary plus the metrics snapshot that a monitoring daemon would
+    scrape, ``compile.*`` counters included. The same tracing is available
+    process-wide via ``REPRO_WS_TRACE=<dir>``."""
     print("\n=== Observability: one traced query + metrics snapshot ===")
     svc = SimulationService(metrics=obs.MetricsRegistry())
     topo = one_cluster(16, 5)
@@ -177,8 +180,9 @@ def trace_and_metrics(out="paper_sweep_trace.json"):
         svc.query(topo, W_list=[10**5], lam_list=[5], reps=32)
         svc.query(topo, W_list=[10**5], lam_list=[5], reps=32)  # cache hit
     print(tr.summary())
-    print(f"  Chrome trace -> {out} "
-          f"({len(tr.events())} events; open in ui.perfetto.dev)")
+    print(f"  profiler trace -> {out}/ ({len(tr.spans())} spans among "
+          f"{len(tr.events())} host events; open perfetto_trace.json.gz "
+          f"in ui.perfetto.dev)")
     snap = svc.stats()["metrics"]
     print("  metrics snapshot (daemon payload):")
     for kind in ("counters", "gauges"):

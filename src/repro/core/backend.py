@@ -56,6 +56,37 @@ SEG_LEN_ENV = "REPRO_WS_SEG_LEN"
 #: directory.
 JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
+#: JAX's compile-path events (``jax.monitoring`` durations) and the
+#: ``compile.*`` counter and ``compile.seconds`` phase each one feeds.
+#: A backend compile includes a load from the persistent cache.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("compile.traces", "trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("compile.lowerings", "lower"),
+    "/jax/core/compile/backend_compile_duration":
+        ("compile.backend_compiles", "compile"),
+}
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_compile_duration(event: str, duration: float, **_):
+    counted = COMPILE_EVENTS.get(event)
+    if counted is not None:
+        name, phase = counted
+        obs.REGISTRY.counter(name).inc()
+        obs.REGISTRY.counter("compile.seconds", {"phase": phase}).inc(duration)
+
+
+def _on_event(event: str, **_):
+    if event == CACHE_HIT_EVENT:
+        obs.REGISTRY.counter("compile.cache_hits").inc()
+
+
+# Always on, like every registry series: an operator's stats() shows a
+# dispatch that re-traces and re-lowers its kernel without a profiler.
+jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+jax.monitoring.register_event_listener(_on_event)
+
 _fault_point_impl = None
 
 
@@ -217,9 +248,19 @@ class ExecutionBackend:
         # dispatch everything before any sync
         outs = [self._run_batch(model, scn, device=dev)
                 for scn, (_, _, dev) in zip(scns, chunks)]
-        return sw.concat_grids(
-            [sw.grid_from_result(model.p, rows.slice(lo, hi), res)
-             for (lo, hi, _), res in zip(chunks, outs)])
+        return self._fetch(model, rows, chunks, outs)
+
+    @staticmethod
+    def _fetch(model, rows, chunks, outs) -> "sw.GridResult":
+        """Each chunk's result to the host, in order: one ``backend.fetch``
+        span a chunk, the wait for its device and the copy."""
+        grids = []
+        for (lo, hi, dev), res in zip(chunks, outs):
+            with obs.span("backend.fetch",
+                          device=None if dev is None else dev.id):
+                grids.append(sw.grid_from_result(model.p, rows.slice(lo, hi),
+                                                 res))
+        return sw.concat_grids(grids)
 
 
 def _device_platforms() -> Tuple[str, ...]:
@@ -381,9 +422,7 @@ class JaxBackend(ExecutionBackend):
         for s in stats[1:]:
             merged = merged.merge(s)
         self.last_stats = merged
-        return sw.concat_grids(
-            [sw.grid_from_result(model.p, rows.slice(lo, hi), res)
-             for (lo, hi, _), res in zip(chunks, results)])
+        return self._fetch(model, rows, chunks, results)
 
 
 class PallasBackend(ExecutionBackend):

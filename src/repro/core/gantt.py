@@ -9,8 +9,8 @@ The jitted engine fills a preallocated int32 trace buffer with rows
 * a JSON dump of the executed schedule (paper's JSON log, Fig 9 input),
 * Chrome-trace/Perfetto events (:func:`to_chrome_events`): the engine's
   *simulated-time* Gantt as its own Perfetto track group, mergeable with
-  the service's *wall-time* spans (``repro.obs``) into one timeline —
-  ``obs.write_chrome_trace(path, tracer.chrome_events(),
+  a profiler session's *wall-time* spans (``repro.obs``) into one timeline —
+  ``obs.write_chrome_trace(path, trace.chrome_events(),
   row_chrome_events(...))`` gives a file with both track groups.
 """
 from __future__ import annotations
@@ -26,8 +26,8 @@ from repro.core import divisible as dv
 STATE_RUN = "RUN"
 STATE_IDLE = "IDLE"
 
-#: Chrome-trace process id of the simulated-time track group (the service's
-#: wall-time spans live on ``obs.HOST_PID``).
+#: Chrome-trace process id of the simulated-time track group, apart from
+#: the profiler's own process ids for the host and its devices.
 SIM_PID = 2
 SIM_PROCESS_NAME = "engine (simulated time)"
 
@@ -109,7 +109,7 @@ def to_chrome_events(decoded: dict, makespan: int, pid: int = SIM_PID,
     One Perfetto thread track per processor: B/E ``RUN`` pairs for its run
     intervals (ts in simulated time units, rendered as µs) plus instant
     events for steal arrows (``steal`` on the thief at answer delivery,
-    ``steal_req`` at the granted request). Merge with the service tracer's
+    ``steal_req`` at the granted request). Merge with a profiler session's
     wall-time events via :func:`repro.obs.chrome_trace_doc` — distinct pids
     keep the two time axes in separate track groups.
     """

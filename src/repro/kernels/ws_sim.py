@@ -34,6 +34,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core import engine as eng
 from repro.core.backend import pallas_interpret_default
 from repro.core.sweep import as_model
@@ -68,6 +69,32 @@ def _tile_shape(shape) -> tuple:
     return (1,) * max(2 - len(shape), 0) + shape
 
 
+def _task_model(model) -> str:
+    """``divisible``, ``dag`` or ``adaptive``: the kernel is
+    ``ws_sim_<task model>``."""
+    return type(model).__name__.removesuffix("Model").lower()
+
+
+def _device_id(x) -> Optional[int]:
+    """The id of the device a concrete array lives on; None for a tracer
+    or a host array."""
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        return next(iter(x.devices())).id
+    return None
+
+
+def _pad_chunk(scn: eng.Scenario, c: int) -> eng.Scenario:
+    """A chunk of ``n < c`` rows padded to ``c`` with copies of its first
+    row whose event budget is zero: they exit the loop before a single
+    event. The budget mask is a host array, where ``.at[n:]`` would stage
+    its index on the default device."""
+    n = int(scn.W.shape[0])
+    scn = jax.tree.map(lambda x: jnp.concatenate(
+        [x, jnp.broadcast_to(x[:1], (c - n,) + x.shape[1:])]), scn)
+    return scn._replace(
+        max_events=jnp.where(np.arange(c) < n, scn.max_events, 0))
+
+
 @functools.lru_cache(maxsize=64)
 def _host_consts(model) -> tuple:
     """The kernel's constant inputs (topology, then the model's static
@@ -95,11 +122,13 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
     ``grid_chunk`` splits the ``(G,)`` grid into fixed-size segments run as
     separate ``pallas_call`` dispatches: every dispatch then has the same
     grid shape, so Mosaic compiles one program per model regardless of
-    batch size (and the chunks are independently shardable). The batch is
-    padded up to a chunk multiple with copies of row 0 whose event budget
-    is zero — the padded lanes exit the loop before executing a single
-    event, and their rows are dropped from the output. Bit-exactness is
-    untouched: grid cells are independent.
+    batch size (and the chunks are independently shardable). The last
+    chunk is padded up to the chunk size with copies of its first row whose
+    event budget is zero — the padded lanes exit the loop before executing
+    a single event, and their rows are dropped from the output.
+    Bit-exactness is untouched: grid cells are independent. Each chunk is
+    one ``ws_sim.chunk`` span: its slice and pad, and the eager
+    ``pallas_call`` with its trace, lowering and compile or cache load.
     """
     if interpret is None:
         interpret = pallas_interpret_default()
@@ -107,22 +136,18 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
     G = int(scn.W.shape[0])
     if grid_chunk is not None and G > 0:
         c = max(int(grid_chunk), 1)
-        pad = (-G) % c
-        if pad:
-            def pad_leaf(x):
-                return jnp.concatenate(
-                    [x, jnp.broadcast_to(x[:1], (pad,) + x.shape[1:])])
-            scn = jax.tree.map(pad_leaf, scn)
-            # pad rows get a zero budget; a host mask, where ``.at[G:]``
-            # would stage its index on the default device
-            scn = scn._replace(max_events=jnp.where(
-                np.arange(G + pad) < G, scn.max_events, 0))
-        chunks = [jax.tree.map(lambda x: x[lo:lo + c], scn)
-                  for lo in range(0, G + pad, c)]
-        outs = [ws_sim_pallas(model, ck, interpret=interpret)
-                for ck in chunks]
+        attrs = dict(task_model=_task_model(model),
+                     device=_device_id(scn.W))
+        outs = []
+        for lo in range(0, G, c):
+            n = min(c, G - lo)
+            with obs.span("ws_sim.chunk", n_rows=n, **attrs):
+                ck = jax.tree.map(lambda x: x[lo:lo + n], scn)
+                if n < c:
+                    ck = _pad_chunk(ck, c)
+                outs.append(ws_sim_pallas(model, ck, interpret=interpret))
         res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *outs)
-        return jax.tree.map(lambda x: x[:G], res) if pad else res
+        return jax.tree.map(lambda x: x[:G], res) if G % c else res
 
     scn_leaves, scn_def = jax.tree.flatten(scn)
     consts = _host_consts(model)
@@ -154,6 +179,10 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
                  for l, b, tile in zip(res_leaves, bool_mask, tiles)]
     out_specs = [_out_spec(tile) for tile in tiles]
 
+    # The kernel's name rides in the custom call's ``kernel_metadata``, which
+    # the device trace prints with the op. ``name=`` or a named scope would
+    # rename the op itself, which the trace reduction finds as
+    # ``%tpu_custom_call``.
     outs = pl.pallas_call(
         functools.partial(_kernel, model=model, n_const=len(consts),
                           n_scn=len(scn_leaves), scn_def=scn_def,
@@ -163,6 +192,7 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        metadata={"kernel": f"ws_sim_{_task_model(model)}"},
     )(*consts, *scn_leaves)
 
     outs = [o.reshape((G,) + l.shape) for o, l in zip(outs, res_leaves)]

@@ -1,19 +1,15 @@
 """Observability layer: tracing spans + metrics registry (DESIGN.md §9).
 
-Dependency-free by design — ``repro.obs`` imports nothing from the rest of
-``repro``, so every layer (service, core, benchmarks) can import it without
-cycles. See :mod:`repro.obs.trace` and :mod:`repro.obs.metrics`.
+``repro.obs`` imports nothing from the rest of ``repro``, so every layer
+(service, core, benchmarks) can import it without cycles; it imports JAX
+only inside the functions that trace. See :mod:`repro.obs.trace` and
+:mod:`repro.obs.metrics`.
 """
 from .trace import (  # noqa: F401
-    HOST_PID,
-    HOST_PROCESS_NAME,
     TRACE_ENV,
-    NullTracer,
-    Tracer,
+    Trace,
     chrome_trace_doc,
     enabled,
-    get_tracer,
-    set_tracer,
     span,
     trace_to,
     write_chrome_trace,
@@ -29,15 +25,10 @@ from .metrics import (  # noqa: F401
 )
 
 __all__ = [
-    "HOST_PID",
-    "HOST_PROCESS_NAME",
     "TRACE_ENV",
-    "NullTracer",
-    "Tracer",
+    "Trace",
     "chrome_trace_doc",
     "enabled",
-    "get_tracer",
-    "set_tracer",
     "span",
     "trace_to",
     "write_chrome_trace",

@@ -1,57 +1,51 @@
-"""Tracing half of the observability layer (DESIGN.md §9): nestable spans.
+"""Tracing half of the observability layer (DESIGN.md §9): spans on the
+profiler's clock.
 
-Dependency-free (stdlib only). A :class:`Tracer` records *spans* — named
-wall-clock intervals with structured attributes — as the service stack runs:
-``service.query → broker.flush → broker.dispatch → backend.run_rows →
-engine.segment`` plus ``store.get / store.put / broker.lock_wait``. Spans
-nest by call structure (Chrome's trace model infers nesting from B/E event
-order per thread), so an exported trace shows exactly where a query's
-wall-clock went.
+A span is a named wall-clock interval with structured attributes, opened at
+a layer boundary of the service stack: ``service.query → broker.flush →
+broker.round → broker.dispatch → backend.run_rows → ws_sim.chunk /
+engine.segment → backend.fetch``, beside ``estimator.update``,
+``store.get / store.put`` and ``broker.lock_wait``. :func:`span` opens a
+``jax.profiler.TraceAnnotation`` (a TraceMe) whenever a profiler session is
+collecting, so the program's spans land in the profiler's own trace, on the
+clock of JAX's compile-path events and of the device's operations, whoever
+opened the session: :func:`trace_to`, ``REPRO_WS_TRACE``, an operator's
+``jax.profiler.trace``, or a profiler server capture of the daemon. With no
+session collecting, a span is one ``is_enabled()`` check and a shared no-op.
 
-Export targets:
+Enabling, for a block::
 
-* **Chrome-trace / Perfetto JSON** (:meth:`Tracer.write`,
-  :func:`chrome_trace_doc`): load the file in ``ui.perfetto.dev`` or
-  ``chrome://tracing``. Host spans live on pid ``HOST_PID`` ("service (wall
-  time)"); the log engine (``repro.core.gantt.to_chrome_events``) emits a
-  *simulated-time* track group on its own pid, so one file can carry both
-  timelines side by side.
-* **Human summary** (:meth:`Tracer.summary`): a per-span-name table of
-  count / total / mean / max milliseconds.
-
-Enabling: tracing is OFF by default — the module-level :func:`span` hits a
-shared no-op null span (no timestamps taken, no events stored, nothing
-measurable on the hot path; the ``obs_overhead`` bench enforces <3% even
-when ON). Turn it on with the ``REPRO_WS_TRACE=path.json`` environment
-variable (trace written at process exit), or scoped via::
-
-    with obs.trace_to("query.json") as tr:
+    with obs.trace_to("trace_dir") as tr:
         svc.query(...)
     print(tr.summary())
 
-Instrumentation never changes what is computed — stored artifacts are
+or for a whole process, ``REPRO_WS_TRACE=trace_dir``. Either writes the
+profiler's ``trace_dir/plugins/profile/<time>/*.xplane.pb`` and
+``perfetto_trace.json.gz`` (open it in ``ui.perfetto.dev``): host spans and
+device operations in one file. One session collects at a time.
+
+``chrome_trace_doc`` / ``write_chrome_trace`` merge Chrome-trace event
+lists, for ``core/gantt``'s simulated-time tracks beside a session's own.
+
+Instrumentation never changes what is computed: stored artifacts are
 byte-identical with tracing on or off (tested).
 """
 from __future__ import annotations
 
 import atexit
+import gzip
 import json
 import os
-import threading
-import time
+import sys
+import warnings
 from contextlib import contextmanager
 from pathlib import Path
-from typing import Dict, List, Union
+from typing import Dict, List, Optional, Union
 
-#: Set to a file path to enable tracing process-wide; the Chrome-trace JSON
-#: is written there at interpreter exit.
+#: Set to a directory to trace the whole process into it.
 TRACE_ENV = "REPRO_WS_TRACE"
 
-#: Chrome-trace process id of the host (wall-time) track group. Simulated
-#: timelines (``repro.core.gantt``) use their own pid so Perfetto renders
-#: them as a separate track group.
-HOST_PID = 1
-HOST_PROCESS_NAME = "service (wall time)"
+PathLike = Union[str, os.PathLike]
 
 
 class _NullSpan:
@@ -71,146 +65,114 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
-
-class NullTracer:
-    """Disabled tracer: every span is the shared no-op instance."""
-
-    enabled = False
-
-    def span(self, name: str, **attrs) -> _NullSpan:
-        return _NULL_SPAN
+#: The span class, made once JAX is loaded (see :func:`_span_class`).
+_SPAN_CLS = None
+#: Names :func:`span` has recorded in this process: what tells the
+#: program's spans from JAX's own events in a trace read back.
+_NAMES: set = set()
 
 
-class _Span:
-    """One live span of a real :class:`Tracer` (context manager).
+def _span_class():
+    """A ``TraceAnnotation`` with ``set()`` for late attributes; None while
+    the process has not imported JAX, when no profiler can be collecting."""
+    global _SPAN_CLS
+    if _SPAN_CLS is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
 
-    Attributes passed to ``span()`` ride on the Chrome ``B`` event;
-    late attributes added via :meth:`set` (values only known at the end,
-    e.g. cache hit/miss, wasted_frac) ride on the matching ``E`` event —
-    Perfetto merges both into the span's args.
-    """
+        class _Span(TraceAnnotation):
+            def set(self, **attrs):
+                self.set_metadata(**attrs)
+                return self
 
-    __slots__ = ("_tracer", "name", "_attrs", "_late")
+        _SPAN_CLS = _Span
+    return _SPAN_CLS
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
-        self._tracer = tracer
-        self.name = name
-        self._attrs = attrs
-        self._late: dict = {}
 
-    def set(self, **attrs) -> "_Span":
-        self._late.update(attrs)
+def enabled() -> bool:
+    """Whether a profiler session is collecting, so spans are recorded."""
+    cls = _SPAN_CLS or _span_class()
+    return cls is not None and cls.is_enabled()
+
+
+def span(name: str, **attrs):
+    """Open a span (the one call instrumented code makes). ``sp.set(**late)``
+    adds attributes known only at the end (cache tier, wasted lanes)."""
+    cls = _SPAN_CLS or _span_class()
+    if cls is not None and cls.is_enabled():
+        _NAMES.add(name)
+        return cls(name, **attrs)
+    return _NULL_SPAN
+
+
+class Trace:
+    """A profiler session that writes ``path``, and the host events it
+    recorded, read back once it has closed."""
+
+    def __init__(self, path: PathLike):
+        self.path = Path(path)
+        self._events: Optional[List[dict]] = None
+
+    def start(self) -> "Trace":
+        import jax
+        if enabled():
+            raise RuntimeError(
+                "a profiler session is already collecting in this process "
+                "(obs.trace_to, REPRO_WS_TRACE or jax.profiler); close it "
+                "before opening another")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(self.path), create_perfetto_trace=True,
+                                 profiler_options=opts)
         return self
 
-    def __enter__(self) -> "_Span":
-        self._tracer._emit("B", self.name, self._attrs)
-        return self
+    def stop(self):
+        import jax
+        jax.profiler.stop_trace()
 
-    def __exit__(self, *exc):
-        self._tracer._emit("E", self.name, self._late)
-        return False
-
-
-class Tracer:
-    """Collects spans and exports them as Chrome-trace JSON + a summary.
-
-    Thread-safe: each thread gets its own Chrome ``tid`` (dense ints in
-    order of first appearance), so B/E pairs keep stack discipline per
-    thread. Timestamps are microseconds since tracer construction
-    (``perf_counter_ns`` based, hence monotonic).
-    """
-
-    enabled = True
-
-    def __init__(self, path: Union[None, str, os.PathLike] = None):
-        self.path = None if path is None else Path(path)
-        self._t0 = time.perf_counter_ns()
-        self._events: List[dict] = []
-        self._lock = threading.Lock()
-        self._tids: Dict[int, int] = {}
-
-    def __len__(self) -> int:
-        return len(self._events)
-
-    def _tid(self) -> int:
-        ident = threading.get_ident()
-        tid = self._tids.get(ident)
-        if tid is None:
-            with self._lock:
-                tid = self._tids.setdefault(ident, len(self._tids))
-        return tid
-
-    def _emit(self, ph: str, name: str, args: dict):
-        ev = {
-            "ph": ph,
-            "name": name,
-            "cat": "service",
-            "pid": HOST_PID,
-            "tid": self._tid(),
-            "ts": round((time.perf_counter_ns() - self._t0) / 1e3, 3),
-        }
-        if args:
-            ev["args"] = dict(args)
-        self._events.append(ev)
-
-    def span(self, name: str, **attrs) -> _Span:
-        return _Span(self, name, attrs)
-
-    def clear(self):
-        self._events = []
-
-    # -- export -------------------------------------------------------------
+    def _newest(self, pattern: str) -> Path:
+        found = list(self.path.rglob(pattern))
+        if not found:
+            raise FileNotFoundError(f"no {pattern} under {self.path}")
+        return max(found, key=lambda p: p.stat().st_mtime)
 
     def events(self) -> List[dict]:
-        """Raw recorded B/E events (copies; chronological order)."""
-        return [dict(e) for e in self._events]
+        """Every host event of the session, the program's spans and JAX's
+        own, by start: ``{name, start_ns, dur_ns, line, args}``, where
+        ``line`` numbers the host's threads."""
+        if self._events is None:
+            import jax
+            prof = jax.profiler.ProfileData.from_file(
+                str(self._newest("*.xplane.pb")))
+            lines = [line for plane in prof.planes
+                     if plane.name.startswith("/host:")
+                     for line in plane.lines]
+            out = []
+            with warnings.catch_warnings():
+                warnings.simplefilter("ignore", DeprecationWarning)
+                for k, line in enumerate(lines):
+                    for e in line.events:
+                        out.append(dict(
+                            name=e.name, start_ns=e.start_ns,
+                            dur_ns=e.duration_ns, line=k,
+                            args=dict(e.stats)))
+            self._events = sorted(out, key=lambda e: e["start_ns"])
+        return self._events
 
-    def chrome_events(self) -> List[dict]:
-        """Recorded events plus the host track group's metadata events."""
-        meta = [{"ph": "M", "name": "process_name", "pid": HOST_PID,
-                 "tid": 0, "args": {"name": HOST_PROCESS_NAME}}]
-        for ident, tid in sorted(self._tids.items(), key=lambda kv: kv[1]):
-            meta.append({"ph": "M", "name": "thread_name", "pid": HOST_PID,
-                         "tid": tid, "args": {"name": f"host-{tid}"}})
-        return meta + self.events()
-
-    def trace_doc(self, *extra_event_lists) -> dict:
-        """Full Chrome-trace document; ``extra_event_lists`` append other
-        track groups (e.g. a simulated-time Gantt from ``core/gantt``)."""
-        return chrome_trace_doc(self.chrome_events(), *extra_event_lists)
-
-    def write(self, path: Union[None, str, os.PathLike] = None,
-              *extra_event_lists) -> Path:
-        """Write the Chrome-trace JSON to ``path`` (default: the tracer's
-        configured path). Returns the written path."""
-        out = Path(path) if path is not None else self.path
-        if out is None:
-            raise ValueError("Tracer has no path; pass write(path=...)")
-        out.parent.mkdir(parents=True, exist_ok=True)
-        doc = self.trace_doc(*extra_event_lists)
-        out.write_text(json.dumps(doc, indent=1))
-        return out
-
-    # -- human summary ------------------------------------------------------
+    def spans(self) -> List[dict]:
+        """The program's spans among :meth:`events`."""
+        return [e for e in self.events() if e["name"] in _NAMES]
 
     def durations_ms(self) -> Dict[str, List[float]]:
-        """Matched span durations in ms, keyed by span name (B/E pairing by
-        per-thread stack discipline)."""
-        stacks: Dict[int, list] = {}
+        """Span durations in milliseconds, by name."""
         out: Dict[str, List[float]] = {}
-        for ev in self._events:
-            stack = stacks.setdefault(ev["tid"], [])
-            if ev["ph"] == "B":
-                stack.append((ev["name"], ev["ts"]))
-            elif ev["ph"] == "E" and stack:
-                name, ts0 = stack.pop()
-                out.setdefault(name, []).append((ev["ts"] - ts0) / 1e3)
+        for e in self.spans():
+            out.setdefault(e["name"], []).append(e["dur_ns"] / 1e6)
         return out
 
     def summary(self) -> str:
         """Per-span-name table: count, total/mean/max milliseconds."""
-        durs = self.durations_ms()
-        rows = sorted(((sum(v), name, v) for name, v in durs.items()),
+        rows = sorted(((sum(v), name, v)
+                       for name, v in self.durations_ms().items()),
                       reverse=True)
         lines = [f"{'span':<24s} {'count':>6s} {'total_ms':>10s} "
                  f"{'mean_ms':>9s} {'max_ms':>9s}"]
@@ -219,9 +181,26 @@ class Tracer:
                          f"{total / len(v):>9.3f} {max(v):>9.3f}")
         return "\n".join(lines)
 
+    def chrome_events(self) -> List[dict]:
+        """The session's Chrome-trace events as the profiler wrote them
+        (``perfetto_trace.json.gz``), to merge with other track groups."""
+        with gzip.open(self._newest("perfetto_trace.json.gz"), "rt") as f:
+            return [e for e in json.load(f)["traceEvents"] if e]
+
+
+@contextmanager
+def trace_to(path: PathLike):
+    """Trace the block into ``path`` (a directory); yields the
+    :class:`Trace`, whose spans read back after the block."""
+    tr = Trace(path).start()
+    try:
+        yield tr
+    finally:
+        tr.stop()
+
 
 # ---------------------------------------------------------------------------
-# Chrome-trace document helpers (shared with core/gantt's simulated tracks).
+# Chrome-trace document helpers (core/gantt's simulated-time tracks).
 # ---------------------------------------------------------------------------
 
 def chrome_trace_doc(*event_lists) -> dict:
@@ -237,8 +216,7 @@ def chrome_trace_doc(*event_lists) -> dict:
     return {"traceEvents": meta + timed, "displayTimeUnit": "ms"}
 
 
-def write_chrome_trace(path: Union[str, os.PathLike],
-                       *event_lists) -> Path:
+def write_chrome_trace(path: PathLike, *event_lists) -> Path:
     """Write merged event lists as a Chrome-trace JSON file."""
     out = Path(path)
     out.parent.mkdir(parents=True, exist_ok=True)
@@ -246,58 +224,10 @@ def write_chrome_trace(path: Union[str, os.PathLike],
     return out
 
 
-# ---------------------------------------------------------------------------
-# The active tracer (process-global; NullTracer unless enabled).
-# ---------------------------------------------------------------------------
-
-_active: Union[Tracer, NullTracer] = NullTracer()
-
-
-def get_tracer() -> Union[Tracer, NullTracer]:
-    return _active
-
-
-def set_tracer(tracer: Union[None, Tracer, NullTracer]):
-    """Install ``tracer`` as the process's active tracer (None disables).
-    Returns the previous tracer."""
-    global _active
-    prev = _active
-    _active = tracer if tracer is not None else NullTracer()
-    return prev
-
-
-def enabled() -> bool:
-    return _active.enabled
-
-
-def span(name: str, **attrs):
-    """Open a span on the active tracer (the one call instrumented code
-    makes; a shared no-op when tracing is disabled)."""
-    return _active.span(name, **attrs)
-
-
-@contextmanager
-def trace_to(path: Union[None, str, os.PathLike] = None):
-    """Scoped tracing: install a fresh :class:`Tracer` for the block, yield
-    it, restore the previous tracer after; when ``path`` is given the
-    Chrome-trace JSON is written on exit."""
-    tracer = Tracer(path)
-    prev = set_tracer(tracer)
-    try:
-        yield tracer
-    finally:
-        set_tracer(prev)
-        if tracer.path is not None:
-            tracer.write()
-
-
 def _install_from_env():
     path = os.environ.get(TRACE_ENV, "").strip()
-    if not path:
-        return
-    tracer = Tracer(path)
-    set_tracer(tracer)
-    atexit.register(lambda: tracer.write() if len(tracer) else None)
+    if path:
+        atexit.register(Trace(path).start().stop)
 
 
 _install_from_env()

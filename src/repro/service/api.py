@@ -24,7 +24,7 @@ from repro.core.sweep import (GridResult, canonical_grid, lam_pair,
                               resolve_model, run_grid)
 from repro.core.topology import Topology
 from repro.service.broker import (PairedQuery, PairedResult, QueryBroker,
-                                  QueryResult, SimQuery)
+                                  QueryResult, SimQuery, key_prefixes)
 from repro.service.estimator import (AdaptivePolicy, PairedPolicy,
                                      QuantilePolicy)
 from repro.service import resilience as rz
@@ -140,7 +140,8 @@ class SimulationService:
             for q in queries:
                 self.broker.submit(q)
             out = self.broker.flush()
-            sp.set(n_cached=sum(1 for r in out if r.from_cache))
+            sp.set(n_cached=sum(1 for r in out if r.from_cache),
+                   keys=key_prefixes(r.key for r in out))
             return out
 
     def query_pair(self, query_a: SimQuery, query_b: SimQuery,
@@ -231,12 +232,13 @@ class SimulationService:
         store_stats = self.store.stats()    # syncs the store.lru_len gauge
         snapshot = m.snapshot()
         if m is not obs.REGISTRY:
-            # Engine/backend instrumentation always writes to the global
-            # registry (core must not depend on service wiring); graft those
-            # series in so a private-registry snapshot is still complete.
+            # Engine/backend/compile instrumentation always writes to the
+            # global registry (core must not depend on service wiring);
+            # graft those series in so a private-registry snapshot is still
+            # complete.
             for kind, series in obs.REGISTRY.snapshot().items():
                 for key, val in series.items():
-                    if key.startswith(("engine.", "backend.")):
+                    if key.startswith(("engine.", "backend.", "compile.")):
                         snapshot[kind].setdefault(key, val)
         return dict(store=store_stats,
                     n_dispatches=self.broker.n_dispatches,

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import json
 import random
 import time
@@ -771,56 +772,69 @@ class QueryBroker:
         return results
 
     def _run_pendings(self, queue, keys, results, pendings, owned):
-        while True:
-            # Heartbeat our advisory locks once per dispatch round so
-            # cross-process waiters see a live mtime and keep waiting
-            # instead of declaring us dead mid-computation.
-            for key in owned:
-                self.store.heartbeat(key)
-            # (canonical static config, rp, backend) -> coalesced dispatch
-            buckets: Dict[Tuple[str, int, str], _Bucket] = {}
-            for i, pend in pendings.items():
-                if results[i] is not None:
-                    continue
-                wants = pend.wants()
-                if not wants:
-                    results[i] = pend.result(keys[i])
-                    self._observe_reps(results[i], pend)
-                    pend.persist(self.store, keys[i])
-                    if keys[i] in owned:
-                        self.store.unlock(keys[i])
-                        owned.discard(keys[i])
-                    continue
-                for tag, model, canon, rp, backend, rows in wants:
-                    bname = backend or (
-                        "jax" if self._mesh is not None
-                        else bk.default_backend_name())
-                    if self.relax_max_events:
-                        # Drop the static cap from the bucket identity:
-                        # members coalesce across max_events and the
-                        # dispatch cap is relaxed to a shared pow2 bound.
-                        canon_b = {k: v for k, v in canon.items()
-                                   if k != "max_events"}
-                    else:
-                        canon_b = canon
-                    bkey = (json.dumps(canon_b, sort_keys=True,
-                                       separators=(",", ":")),
-                            remote_prob_u32(float(rp)), bname)
-                    bucket = buckets.get(bkey)
-                    if bucket is None:
-                        bucket = buckets[bkey] = _Bucket(model, canon_b, rp,
-                                                         bname)
-                    else:
-                        assert bucket.canon == canon_b, (
-                            "bucket members' canonical model configs "
-                            "disagree despite equal bucket keys")
-                    bucket.explicit |= backend is not None
-                    bucket.members.append((i, tag, rows,
-                                           int(model.max_events)))
+        for rnd in itertools.count():
+            with obs.span("broker.round", round=rnd) as sp:
+                # Heartbeat our advisory locks once per dispatch round so
+                # cross-process waiters see a live mtime and keep waiting
+                # instead of declaring us dead mid-computation.
+                for key in owned:
+                    self.store.heartbeat(key)
+                buckets = self._round_buckets(keys, results, pendings, owned)
+                members = [m for b in buckets.values() for m in b.members]
+                sp.set(n_queries=len({m[0] for m in members}),
+                       n_rows=sum(len(m[2]) for m in members))
+                for bucket in buckets.values():
+                    self._dispatch_bucket(bucket, pendings, keys)
             if not buckets:
                 return
-            for bucket in buckets.values():
-                self._dispatch_bucket(bucket, pendings)
+
+    def _round_buckets(self, keys, results, pendings, owned):
+        """One replication round: each unanswered query's stopping rule;
+        a query that wants no more rows is answered and persisted, the rows
+        the others want are coalesced into buckets, one dispatch each."""
+        # (canonical static config, rp, backend) -> coalesced dispatch
+        buckets: Dict[Tuple[str, int, str], _Bucket] = {}
+        for i, pend in pendings.items():
+            if results[i] is not None:
+                continue
+            with obs.span("estimator.update"):
+                wants = pend.wants()
+                done = None if wants else pend.result(keys[i])
+            if done is not None:
+                results[i] = done
+                self._observe_reps(done, pend)
+                pend.persist(self.store, keys[i])
+                if keys[i] in owned:
+                    self.store.unlock(keys[i])
+                    owned.discard(keys[i])
+                continue
+            for tag, model, canon, rp, backend, rows in wants:
+                bname = backend or (
+                    "jax" if self._mesh is not None
+                    else bk.default_backend_name())
+                if self.relax_max_events:
+                    # Drop the static cap from the bucket identity:
+                    # members coalesce across max_events and the
+                    # dispatch cap is relaxed to a shared pow2 bound.
+                    canon_b = {k: v for k, v in canon.items()
+                               if k != "max_events"}
+                else:
+                    canon_b = canon
+                bkey = (json.dumps(canon_b, sort_keys=True,
+                                   separators=(",", ":")),
+                        remote_prob_u32(float(rp)), bname)
+                bucket = buckets.get(bkey)
+                if bucket is None:
+                    bucket = buckets[bkey] = _Bucket(model, canon_b, rp,
+                                                     bname)
+                else:
+                    assert bucket.canon == canon_b, (
+                        "bucket members' canonical model configs "
+                        "disagree despite equal bucket keys")
+                bucket.explicit |= backend is not None
+                bucket.members.append((i, tag, rows,
+                                       int(model.max_events)))
+        return buckets
 
     def _observe_reps(self, res, pend) -> None:
         """Metrics on how much replication an adaptive/paired stopping rule
@@ -840,7 +854,7 @@ class QueryBroker:
         self.metrics.counter("broker.adaptive_reps_saved").inc(
             max(0, worst - used))
 
-    def _dispatch_bucket(self, bucket: _Bucket, pendings):
+    def _dispatch_bucket(self, bucket: _Bucket, pendings, keys):
         rows = _concat_rows([r for _, _, r, _ in bucket.members])
         n = len(rows)
         caps = [c for _, _, _, c in bucket.members]
@@ -903,7 +917,9 @@ class QueryBroker:
                                   ev_budget=buds,
                                   reroute=(not bucket.explicit) and top)
 
-        with obs.span("broker.dispatch", sig=sig[-16:], **entry):
+        member_keys = dict.fromkeys(keys[i] for i, *_ in bucket.members)
+        with obs.span("broker.dispatch", keys=key_prefixes(member_keys),
+                      **entry):
             if cfg.enabled:
                 grid, degraded = rz.dispatch_resilient(
                     call, padded, budgets, chain, retry=cfg.retry,
@@ -935,10 +951,19 @@ class QueryBroker:
                       ev=np.asarray(ev)[:n], cap=cap, history=self.history,
                       p=model.p)
         off = 0
-        for i, tag, rws, _ in bucket.members:
-            part = _slice_grid(grid, off, off + len(rws))
-            pendings[i].feed_part(tag, part)
-            off += len(rws)
+        with obs.span("estimator.update", n_queries=len(bucket.members)):
+            for i, tag, rws, _ in bucket.members:
+                part = _slice_grid(grid, off, off + len(rws))
+                pendings[i].feed_part(tag, part)
+                off += len(rws)
+
+
+def key_prefixes(keys) -> str:
+    """``[<12 hex digits> ...]``, the start of each store key: the span
+    attribute that joins one request's spans (``service.query``,
+    ``broker.dispatch``). In brackets and without commas, so that the
+    profiler keeps it as one string and never reads a number."""
+    return "[" + " ".join(k[:12] for k in keys) + "]"
 
 
 def _take_grid(grid: GridResult, idx: np.ndarray) -> GridResult:

@@ -308,16 +308,20 @@ class ResultStore:
         self._last_touch[key] = now
         self._touch(self._path(key))
 
-    def _write_atomic(self, path: Path, writer):
+    def _write_atomic(self, path: Path, writer) -> int:
+        """Write ``path`` through a temporary file and ``os.replace``;
+        returns the bytes written."""
         fd, tmp = tempfile.mkstemp(dir=self.root, suffix=".tmp")
         try:
             with os.fdopen(fd, "wb") as f:
                 writer(f)
+                n = f.tell()
             os.replace(tmp, path)
         except BaseException:
             if os.path.exists(tmp):
                 os.unlink(tmp)
             raise
+        return n
 
     def put(self, key: str, grid: GridResult,
             meta: Optional[dict] = None) -> Path:
@@ -337,21 +341,22 @@ class ResultStore:
             # process's LRU keeps the good copy (readers recover via
             # quarantine + recompute).
             act = rz.fault_point("store.put", key=key)
-            self._write_atomic(
+            n = self._write_atomic(
                 path, lambda f: np.savez_compressed(f, **_grid_to_npz(grid)))
-            return act
+            return act, n
 
-        action = self.retry.call(_write, retry_on=(OSError,),
-                                 metrics=self.metrics, label="store.put")
+        action, nbytes = self.retry.call(_write, retry_on=(OSError,),
+                                         metrics=self.metrics,
+                                         label="store.put")
         if action:
             self._corrupt_in_place(path, action)
         if meta is not None:
             blob = json.dumps(meta, sort_keys=True, indent=1).encode()
-            self._write_atomic(self._sidecar(key), lambda f: f.write(blob))
+            nbytes += self._write_atomic(self._sidecar(key),
+                                         lambda f: f.write(blob))
         self._remember(key, grid)
         self._count("puts")
-        if obs.enabled():          # _entry_bytes stats the files — skip when off
-            sp.set(bytes=self._entry_bytes(key))
+        sp.set(bytes=nbytes)
         if self.gc_bytes is not None:
             # Amortized budget check: one full directory scan seeds a
             # running byte estimate, each put increments it, and the real

@@ -18,45 +18,81 @@ from repro.service import SimulationService
 from repro.service.store import ResultStore
 
 
-# -- tracer ------------------------------------------------------------------
+# -- tracer: spans in the profiler's trace ---------------------------------
 
-def test_span_nesting_and_summary():
-    with obs.trace_to() as tr:
-        with obs.span("outer", a=1):
-            with obs.span("inner"):
+def _one(tr, name):
+    found = [e for e in tr.spans() if e["name"] == name]
+    assert len(found) == 1, found
+    return found[0]
+
+
+def _within(inner, outer):
+    return (outer["start_ns"] <= inner["start_ns"]
+            and inner["start_ns"] + inner["dur_ns"]
+            <= outer["start_ns"] + outer["dur_ns"])
+
+
+def test_span_nesting_and_summary(tmp_path):
+    with obs.trace_to(tmp_path) as tr:
+        with obs.span("obs_test.outer", a=1):
+            with obs.span("obs_test.inner"):
                 pass
-            with obs.span("inner"):
+            with obs.span("obs_test.inner"):
                 pass
     durs = tr.durations_ms()
-    assert len(durs["outer"]) == 1 and len(durs["inner"]) == 2
+    assert len(durs["obs_test.outer"]) == 1
+    assert len(durs["obs_test.inner"]) == 2
     assert all(d >= 0 for v in durs.values() for d in v)
+    outer = _one(tr, "obs_test.outer")
+    inners = [e for e in tr.spans() if e["name"] == "obs_test.inner"]
+    assert all(_within(e, outer) for e in inners)
     summary = tr.summary()
-    assert "outer" in summary and "inner" in summary
+    assert "obs_test.outer" in summary and "obs_test.inner" in summary
 
-def test_late_attrs_land_on_end_event():
-    with obs.trace_to() as tr:
-        with obs.span("s", early=1) as sp:
-            sp.set(late="x")
-    b, e = tr.events()
-    assert b["ph"] == "B" and b["args"] == {"early": 1}
-    assert e["ph"] == "E" and e["args"] == {"late": "x"}
+
+def test_late_attrs_land_on_end_event(tmp_path):
+    """A span's attributes, those given at its start and those set late,
+    read back from the xplane with their types."""
+    with obs.trace_to(tmp_path) as tr:
+        with obs.span("obs_test.s", early=1, tier="disk") as sp:
+            sp.set(late="x", frac=0.25)
+    assert _one(tr, "obs_test.s")["args"] == {
+        "early": 1, "tier": "disk", "late": "x", "frac": 0.25}
 
 
 def test_tracer_write_valid_chrome_trace(tmp_path):
-    path = tmp_path / "t.json"
-    with obs.trace_to(path) as tr:
-        with obs.span("a"):
-            with obs.span("b"):
+    """One session writes the xplane and a Chrome-trace (Perfetto) file in
+    which the spans are complete events, nested by time."""
+    with obs.trace_to(tmp_path) as tr:
+        with obs.span("obs_test.a"):
+            with obs.span("obs_test.b"):
                 pass
-    doc = json.loads(path.read_text())
-    assert doc["displayTimeUnit"] == "ms"
-    events = doc["traceEvents"]
+    assert list(tmp_path.rglob("*.xplane.pb"))
+    events = tr.chrome_events()
+    json.dumps(events)
     assert any(e["ph"] == "M" and e["name"] == "process_name"
                for e in events)
-    timed = [e for e in events if e["ph"] in ("B", "E")]
-    assert [e["ph"] for e in timed] == ["B", "B", "E", "E"]  # nested pairs
-    ts = [e["ts"] for e in timed]
-    assert ts == sorted(ts)
+    a, b = (next(e for e in events if e.get("name") == n)
+            for n in ("obs_test.a", "obs_test.b"))
+    assert a["ph"] == b["ph"] == "X"
+    assert (a["pid"], a["tid"]) == (b["pid"], b["tid"])
+    assert a["ts"] <= b["ts"] and b["ts"] + b["dur"] <= a["ts"] + a["dur"]
+
+
+def test_span_and_jax_op_share_one_clock(tmp_path):
+    """A program span and the XLA operation run inside it land in one
+    trace, the operation's interval inside the span's."""
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: jnp.cumsum(x * 3))
+    x = jnp.arange(4096, dtype=jnp.int32)
+    f(x).block_until_ready()
+    with obs.trace_to(tmp_path) as tr:
+        with obs.span("obs_test.run"):
+            f(x).block_until_ready()
+    run = _one(tr, "obs_test.run")
+    ops = [e for e in tr.events() if "hlo_op" in e["args"]]
+    assert ops and all(_within(e, run) for e in ops)
 
 
 def test_disabled_tracing_is_noop():
@@ -67,45 +103,90 @@ def test_disabled_tracing_is_noop():
         s.set(y=2)                          # all no-ops
 
 
-def test_trace_to_restores_previous_tracer():
+def test_trace_to_restores_previous_tracer(tmp_path):
+    """One session at a time: a second refuses with a clear error and
+    leaves the first collecting; closing the first stops the spans."""
     assert not obs.enabled()
-    with obs.trace_to():
+    with obs.trace_to(tmp_path / "a") as tr:
         assert obs.enabled()
-        with obs.trace_to() as inner:
-            assert obs.get_tracer() is inner
-        assert obs.enabled()                # outer tracer restored
-    assert not obs.enabled()
-
-
-def test_tracer_thread_tids():
-    with obs.trace_to() as tr:
-        def work():
-            with obs.span("worker"):
+        with pytest.raises(RuntimeError, match="already collecting"):
+            with obs.trace_to(tmp_path / "b"):
                 pass
-        with obs.span("main"):
+        assert obs.enabled()                # the first still collects
+        with obs.span("obs_test.after_refusal"):
+            pass
+    assert not obs.enabled()
+    assert _one(tr, "obs_test.after_refusal")
+
+
+def test_tracer_thread_tids(tmp_path):
+    with obs.trace_to(tmp_path) as tr:
+        def work():
+            with obs.span("obs_test.worker"):
+                pass
+        with obs.span("obs_test.main"):
             th = threading.Thread(target=work)
             th.start()
-            th.join()
-    tids = {e["tid"] for e in tr.events()}
-    assert len(tids) == 2                   # one track per thread
-    assert tr.durations_ms()["worker"]      # cross-thread pairing intact
+            th.join(timeout=30)
+        assert not th.is_alive()
+    worker, main = _one(tr, "obs_test.worker"), _one(tr, "obs_test.main")
+    assert worker["line"] != main["line"]   # one line per thread
 
 
 def test_trace_env_var_activates(tmp_path):
-    """REPRO_WS_TRACE=path enables process-wide tracing; the Chrome-trace
-    JSON lands at exit."""
-    out = tmp_path / "env_trace.json"
+    """REPRO_WS_TRACE=dir traces the whole process into dir."""
+    out = tmp_path / "env_trace"
     env = dict(os.environ, REPRO_WS_TRACE=str(out))
     env["PYTHONPATH"] = os.pathsep.join(
         [str(os.path.join(os.path.dirname(__file__), "..", "src")),
          env.get("PYTHONPATH", "")])
     code = ("import repro.obs as obs\n"
             "assert obs.enabled()\n"
-            "with obs.span('from_env'):\n"
+            "with obs.span('from_env', n=3):\n"
             "    pass\n")
-    subprocess.run([sys.executable, "-c", code], check=True, env=env)
-    doc = json.loads(out.read_text())
-    assert any(e.get("name") == "from_env" for e in doc["traceEvents"])
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=300)
+    found = [e for e in obs.Trace(out).events() if e["name"] == "from_env"]
+    assert len(found) == 1 and found[0]["args"] == {"n": 3}
+
+
+def test_ws_sim_chunk_spans(tmp_path):
+    """One ws_sim.chunk span per 128-row grid chunk."""
+    from repro.core import divisible as dv
+    from repro.core import engine as eng
+    from repro.kernels.ws_sim import ws_sim_pallas
+    cfg = dv.EngineConfig(topology=T.one_cluster(4, 2), max_events=1 << 10)
+    scn = eng.batch_scenarios(40, np.arange(256, dtype=np.uint32) + 1,
+                              lam=2)
+    with obs.trace_to(tmp_path) as tr:
+        ws_sim_pallas(cfg, scn, interpret=True, grid_chunk=128)
+    chunks = [e for e in tr.spans() if e["name"] == "ws_sim.chunk"]
+    assert len(chunks) == 2
+    assert all(e["args"]["task_model"] == "divisible"
+               and e["args"]["n_rows"] == 128 for e in chunks)
+
+
+def test_compile_counters_count_lowerings(tmp_path):
+    """compile.* counters (registered by repro.core.backend) rise by one
+    lowering for a new jitted function and by none for a cached call, and
+    reach SimulationService.stats()."""
+    import jax
+    import jax.numpy as jnp
+
+    def lowerings():
+        return obs.REGISTRY.snapshot()["counters"].get("compile.lowerings", 0)
+
+    f = jax.jit(lambda x: x * 7 + 3)
+    x = jnp.ones(37, jnp.int32)
+    n0 = lowerings()
+    f(x).block_until_ready()
+    n1 = lowerings()
+    f(x).block_until_ready()
+    assert (n1 - n0, lowerings() - n1) == (1, 0)
+    counters = _small_service(tmp_path).stats()["metrics"]["counters"]
+    for name in ("compile.traces", "compile.lowerings",
+                 "compile.backend_compiles", "compile.seconds{phase=lower}"):
+        assert counters[name] > 0, name
 
 
 # -- metrics registry --------------------------------------------------------
@@ -188,18 +269,29 @@ def test_service_metrics_supersede_stats(tmp_path):
 def test_service_trace_spans(tmp_path):
     svc = _small_service(tmp_path)
     topo = T.one_cluster(8, 5)
-    with obs.trace_to() as tr:
-        svc.query(topo, W_list=[2000], lam_list=[5], reps=40, backend="jax")
-    names = {e["name"] for e in tr.events() if e["ph"] == "B"}
-    assert {"service.query", "broker.flush", "broker.dispatch",
-            "backend.run_rows", "store.get", "store.put"} <= names
+    with obs.trace_to(tmp_path / "trace") as tr:
+        r = svc.query(topo, W_list=[2000], lam_list=[5], reps=40,
+                      backend="jax")
+    names = {e["name"] for e in tr.spans()}
+    assert {"service.query", "broker.flush", "broker.round",
+            "broker.dispatch", "estimator.update", "backend.run_rows",
+            "backend.fetch", "store.get", "store.put"} <= names
     assert "engine.segment" in names        # 40 rows >= seg_min_rows
     # dispatch span carries the bucket attributes
-    disp = next(e for e in tr.events()
-                if e["ph"] == "B" and e["name"] == "broker.dispatch")
+    disp = next(e for e in tr.spans() if e["name"] == "broker.dispatch")
     assert disp["args"]["backend"] == "jax"
     assert disp["args"]["n_rows"] == 40
     assert disp["args"]["n_padded"] == 64   # pow2 padding
+    # one request's spans join on its store key prefix
+    query = next(e for e in tr.spans() if e["name"] == "service.query")
+    assert query["args"]["keys"] == disp["args"]["keys"] == f"[{r.key[:12]}]"
+    rounds = [e for e in tr.spans() if e["name"] == "broker.round"]
+    assert [e["args"]["round"] for e in rounds] == [0, 1]
+    assert rounds[0]["args"]["n_rows"] == 40
+    assert rounds[1]["args"]["n_rows"] == 0     # answered and persisted
+    put = next(e for e in tr.spans() if e["name"] == "store.put")
+    assert put["args"]["bytes"] == sum(
+        p.stat().st_size for p in (tmp_path / "store").iterdir())
 
 
 def test_artifacts_byte_identical_with_tracing(tmp_path):
@@ -210,7 +302,7 @@ def test_artifacts_byte_identical_with_tracing(tmp_path):
     svc_off = _small_service(tmp_path / "off")
     svc_off.query(topo, **kw)
     svc_on = _small_service(tmp_path / "on")
-    with obs.trace_to():
+    with obs.trace_to(tmp_path / "trace"):
         svc_on.query(topo, **kw)
     off = sorted((tmp_path / "off" / "store").glob("*.npz"))
     on = sorted((tmp_path / "on" / "store").glob("*.npz"))
