@@ -17,6 +17,8 @@ last two dims are the array's own (the TPU's block rule), reshaped back in
 the wrapper. The wrapper is fully generic: it derives the output pytree via
 ``jax.eval_shape`` on the model's result type and threads the model's static
 arrays (DAG durations/edges) as kernel inputs rather than closure constants.
+It builds that ``pallas_call`` once per model and shape (:func:`kernel_call`),
+so a dispatch re-uses JAX's compiled kernel instead of re-tracing it.
 The body is traced under ``engine.select_forms()``, so every indexed access
 of the event core is a one-hot mask, select and reduction over int32 lanes:
 Mosaic lowers those, and not gather/scatter or an int32 ``argmin``. It runs
@@ -25,7 +27,7 @@ in interpret mode on the CPU and compiles via Mosaic on a TPU.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +108,70 @@ def _host_consts(model) -> tuple:
             + tuple(jax.device_get(model.static_arrays())))
 
 
+class KernelCall(NamedTuple):
+    """A built ``pallas_call`` and how to read its outputs back into the
+    model's result pytree."""
+    call: Callable          # (*consts, *scenario leaves) -> int32/... tiles
+    res_leaves: tuple       # per-row ShapeDtypeStruct of each result leaf
+    res_def: Any            # the result's treedef
+    bool_mask: tuple        # leaves carried as int32 and cast back to bool
+
+
+@functools.lru_cache(maxsize=64)
+def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
+                ) -> KernelCall:
+    """The ``pallas_call`` over ``G`` scenarios of ``model``, built once per
+    key. The call is JAX's own jitted wrapper, so one object reused across
+    dispatches traces and lowers once per device and input shape; a fresh
+    kernel closure per dispatch would miss JAX's cache every time. The
+    key holds avals, not values, so tracers build it too."""
+    consts = _host_consts(model)
+    scn1 = jax.tree.unflatten(
+        scn_def, [jax.ShapeDtypeStruct((), d) for d in scn_dtypes])
+    res_struct = jax.eval_shape(
+        lambda c, s: eng._simulate_impl(model, c[0], c[1], c[2:], s),
+        consts, scn1)
+    res_leaves, res_def = jax.tree.flatten(res_struct)
+    bool_mask = tuple(l.dtype == jnp.bool_ for l in res_leaves)
+
+    def _const_spec(x):
+        rank = x.ndim
+        return pl.BlockSpec(x.shape, lambda i, rank=rank: (0,) * rank)
+
+    def _out_spec(shape):
+        rank = len(shape)
+        return pl.BlockSpec((1,) + shape,
+                            lambda i, rank=rank: (i,) + (0,) * rank)
+
+    # Scenario scalars: the whole (G,) column in SMEM, read at the grid
+    # index (a (1,) VMEM block is below the TPU's 128-lane tiling).
+    in_specs = ([_const_spec(c) for c in consts]
+                + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scn_dtypes))
+    tiles = [_tile_shape(l.shape) for l in res_leaves]
+    out_shape = [jax.ShapeDtypeStruct((G,) + tile,
+                                      jnp.int32 if b else l.dtype)
+                 for l, b, tile in zip(res_leaves, bool_mask, tiles)]
+    out_specs = [_out_spec(tile) for tile in tiles]
+
+    # The kernel's name rides in the custom call's ``kernel_metadata``, which
+    # the device trace prints with the op. ``name=`` or a named scope would
+    # rename the op itself, which the trace reduction finds as
+    # ``%tpu_custom_call``; so would an outer ``jax.jit``, so the backend
+    # calls this object eagerly.
+    call = pl.pallas_call(
+        functools.partial(_kernel, model=model, n_const=len(consts),
+                          n_scn=len(scn_dtypes), scn_def=scn_def,
+                          bool_mask=bool_mask),
+        grid=(G,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+        metadata={"kernel": f"ws_sim_{_task_model(model)}"},
+    )
+    return KernelCall(call, tuple(res_leaves), res_def, bool_mask)
+
+
 def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
                   grid_chunk: Optional[int] = None):
     """Batched simulation; ``scn`` leaves have leading batch dim G.
@@ -127,8 +193,10 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
     event budget is zero — the padded lanes exit the loop before executing
     a single event, and their rows are dropped from the output.
     Bit-exactness is untouched: grid cells are independent. Each chunk is
-    one ``ws_sim.chunk`` span: its slice and pad, and the eager
-    ``pallas_call`` with its trace, lowering and compile or cache load.
+    one ``ws_sim.chunk`` span: its slice and pad, and the eager call of the
+    cached ``pallas_call`` (:func:`kernel_call`), which traces, lowers and
+    compiles or loads only on its first dispatch of a shape to a device.
+    Each dispatch counts ``ws_sim.kernel_cache{result=hit|miss}``.
     """
     if interpret is None:
         interpret = pallas_interpret_default()
@@ -150,54 +218,18 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
         return jax.tree.map(lambda x: x[:G], res) if G % c else res
 
     scn_leaves, scn_def = jax.tree.flatten(scn)
-    consts = _host_consts(model)
+    misses = kernel_call.cache_info().misses
+    kc = kernel_call(model, G, interpret, scn_def,
+                     tuple(l.dtype for l in scn_leaves))
+    hit = kernel_call.cache_info().misses == misses
+    obs.REGISTRY.counter("ws_sim.kernel_cache",
+                         {"result": "hit" if hit else "miss"}).inc()
 
-    scn1 = jax.tree.unflatten(
-        scn_def, [jax.ShapeDtypeStruct((), l.dtype) for l in scn_leaves])
-    res_struct = jax.eval_shape(
-        lambda c, s: eng._simulate_impl(model, c[0], c[1], c[2:], s),
-        consts, scn1)
-    res_leaves, res_def = jax.tree.flatten(res_struct)
-    bool_mask = [l.dtype == jnp.bool_ for l in res_leaves]
-
-    def _const_spec(x):
-        rank = x.ndim
-        return pl.BlockSpec(x.shape, lambda i, rank=rank: (0,) * rank)
-
-    def _out_spec(shape):
-        rank = len(shape)
-        return pl.BlockSpec((1,) + shape,
-                            lambda i, rank=rank: (i,) + (0,) * rank)
-
-    # Scenario scalars: the whole (G,) column in SMEM, read at the grid
-    # index (a (1,) VMEM block is below the TPU's 128-lane tiling).
-    in_specs = ([_const_spec(c) for c in consts]
-                + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scn_leaves))
-    tiles = [_tile_shape(l.shape) for l in res_leaves]
-    out_shape = [jax.ShapeDtypeStruct((G,) + tile,
-                                      jnp.int32 if b else l.dtype)
-                 for l, b, tile in zip(res_leaves, bool_mask, tiles)]
-    out_specs = [_out_spec(tile) for tile in tiles]
-
-    # The kernel's name rides in the custom call's ``kernel_metadata``, which
-    # the device trace prints with the op. ``name=`` or a named scope would
-    # rename the op itself, which the trace reduction finds as
-    # ``%tpu_custom_call``.
-    outs = pl.pallas_call(
-        functools.partial(_kernel, model=model, n_const=len(consts),
-                          n_scn=len(scn_leaves), scn_def=scn_def,
-                          bool_mask=bool_mask),
-        grid=(G,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-        metadata={"kernel": f"ws_sim_{_task_model(model)}"},
-    )(*consts, *scn_leaves)
-
-    outs = [o.reshape((G,) + l.shape) for o, l in zip(outs, res_leaves)]
-    outs = [o.astype(jnp.bool_) if b else o for o, b in zip(outs, bool_mask)]
-    return jax.tree.unflatten(res_def, outs)
+    outs = kc.call(*_host_consts(model), *scn_leaves)
+    outs = [o.reshape((G,) + l.shape) for o, l in zip(outs, kc.res_leaves)]
+    outs = [o.astype(jnp.bool_) if b else o
+            for o, b in zip(outs, kc.bool_mask)]
+    return jax.tree.unflatten(kc.res_def, outs)
 
 
 def grid_shape_hazards(grid_chunk: Optional[int],

@@ -238,7 +238,8 @@ class SimulationService:
             # complete.
             for kind, series in obs.REGISTRY.snapshot().items():
                 for key, val in series.items():
-                    if key.startswith(("engine.", "backend.", "compile.")):
+                    if key.startswith(("engine.", "backend.", "compile.",
+                                        "ws_sim.")):
                         snapshot[kind].setdefault(key, val)
         return dict(store=store_stats,
                     n_dispatches=self.broker.n_dispatches,

@@ -13,6 +13,7 @@ cannot be read back.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ from repro.core import engine as eng
 from repro.core import sweep as sw
 from repro.core.backend import PallasBackend
 from repro.core.topology import one_cluster
-from repro.kernels.ws_sim import ws_sim_pallas
+from repro.kernels.ws_sim import _host_consts, kernel_call, ws_sim_pallas
 
 GRID_CHUNK = PallasBackend.grid_chunk
 
@@ -90,6 +91,22 @@ def test_ws_sim_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in lowered.as_text()
     compiled = lowered.compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_cached_kernel_call_keeps_the_custom_call_name(one_chip):
+    """The object the backend calls eagerly, lowered by itself, compiles to
+    an op named ``%tpu_custom_call``: the name the benchmark's kernel
+    metric matches. An outer ``jax.jit`` would name it after its function."""
+    model = _paper_model()
+    leaves, scn_def = jax.tree.flatten(_scenario_spec(GRID_CHUNK, one_chip))
+    kc = kernel_call(model, GRID_CHUNK, False, scn_def,
+                     tuple(l.dtype for l in leaves))
+    consts = [jax.ShapeDtypeStruct(c.shape, c.dtype, sharding=one_chip)
+              for c in _host_consts(model)]
+    text = kc.call.lower(*consts, *leaves).compile().as_text()
+    ops = re.findall(r"^\s*(?:ROOT )?(%\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                     text, re.M)
+    assert ops and all(op.startswith("%tpu_custom_call") for op in ops), ops
 
 
 def test_jax_segment_step_compiles_for_v5e(one_chip):
