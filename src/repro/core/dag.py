@@ -1,12 +1,14 @@
 """DAG-of-tasks task model (paper §2.1.2) over the unified event core.
 
-Each processor keeps a deque of *activated* tasks. An active processor runs
-one task; completion decrements the children's predecessor counts and pushes
-newly-ready tasks to its own deque end. Idle processors pop locally
-(``owner_lifo=True`` = classic ABP: owner pops the newest end, thieves steal
-the oldest end, which holds the activated task with the **largest height** —
-exactly the steal rule of the paper) or FIFO (``owner_lifo=False``, the
-literal reading of the paper's text); steals always take the head.
+Each processor keeps a deque of *activated* tasks, a ring of ``cap`` slots
+(``TaskDag.deque_bound`` by default, which no run can exceed). An active
+processor runs one task; completion decrements the children's predecessor
+counts and pushes newly-ready tasks to its own deque end. Idle processors
+pop locally (``owner_lifo=True`` = classic ABP: owner pops the newest end,
+thieves steal the oldest end, which holds the activated task with the
+**largest height** — exactly the steal rule of the paper) or FIFO
+(``owner_lifo=False``, the literal reading of the paper's text); steals
+always take the head.
 
 Event machinery, victim selection, SWT/MWT and steal-threshold semantics are
 shared with every other task model through ``repro.core.engine`` (one pending
@@ -24,6 +26,7 @@ import dataclasses
 from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import engine as eng
 from repro.core.dag_gen import TaskDag
@@ -52,9 +55,9 @@ class DagState(NamedTuple):
     """Per-model state pytree: the task engine's deques + activation front."""
     cur_task: jnp.ndarray      # int32[p]; -1 = no running task
     pred: jnp.ndarray          # int32[n] remaining predecessor counts
-    buf: jnp.ndarray           # int32[p, L] deques
-    head: jnp.ndarray          # int32[p]
-    tail: jnp.ndarray          # int32[p]
+    buf: jnp.ndarray           # int32[p, cap] deques, slot = index % cap
+    head: jnp.ndarray          # int32[p] tasks ever taken from the head
+    tail: jnp.ndarray          # int32[p] head + the deque's length
     tasks_run: jnp.ndarray     # int32[p]
     n_completed: jnp.ndarray
 
@@ -65,7 +68,9 @@ class DagEngineConfig:
     dag: TaskDag
     mwt: bool = False
     owner_lifo: bool = True       # ABP discipline (steal-largest-height)
-    deque_cap: Optional[int] = None  # default: n tasks (always sufficient)
+    # default: the DAG's bound for the discipline (TaskDag.deque_bound),
+    # which no run can exceed; a smaller cap halts an overflowing row
+    deque_cap: Optional[int] = None
     max_events: int = 1 << 20
     log_trace: bool = False
     max_trace: int = 0
@@ -76,7 +81,9 @@ class DagEngineConfig:
 
     @property
     def cap(self) -> int:
-        return self.dag.n if self.deque_cap is None else self.deque_cap
+        if self.deque_cap is None:
+            return self.dag.deque_bound(self.owner_lifo)
+        return self.deque_cap
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,8 +132,8 @@ class DagModel(eng.TaskModel):
             pc = eng.read(ms.pred, child) - 1
             ready = pc == 0
             tl = eng.read(ms.tail, i)
-            ok = tl < cap
-            pos = jnp.minimum(tl, cap - 1)
+            ok = tl - eng.read(ms.head, i) < cap
+            pos = lax.rem(tl, cap)
             ms = ms._replace(
                 pred=eng.write(ms.pred, child, pc),
                 buf=eng.write(ms.buf, (i, pos),
@@ -177,7 +184,7 @@ class DagModel(eng.TaskModel):
                 else:
                     pos = eng.read(ms.head, i)
                     ms = ms._replace(head=eng.add(ms.head, i, 1))
-                task = eng.read(ms.buf, i, pos)
+                task = eng.read(ms.buf, i, lax.rem(pos, self.cfg.cap))
                 ms = ms._replace(cur_task=eng.write(ms.cur_task, i, task))
                 core = core._replace(
                     ev_time=eng.write(core.ev_time, i,
@@ -200,7 +207,8 @@ class DagModel(eng.TaskModel):
         d_vi = eng.dist(cid, hops, scn, v, i)
         free = eng.chan_free(self, core, v, t)
         ok = (qlen > scn.theta_static) & free
-        task = jnp.where(ok, eng.read(ms.buf, v, eng.read(ms.head, v)), -1)
+        slot = lax.rem(eng.read(ms.head, v), self.cfg.cap)
+        task = jnp.where(ok, eng.read(ms.buf, v, slot), -1)
         ms = ms._replace(head=eng.add(ms.head, v, jnp.where(ok, 1, 0)))
         core = eng.deliver_answer(core, i, v, t, d_vi, ok, task)
         core = eng.log(self, core, t, i,

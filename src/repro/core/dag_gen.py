@@ -9,11 +9,12 @@ applications in JSON. A DAG here is a static single-source structure:
 * ``children`` -- CSR (ptr, idx) of activation edges.
 
 Generators: binary fork trees, fork-join diamonds, merge sort (Fig 9),
-random layered DAGs and chains. All return a :class:`TaskDag`.
+BOTS's cilksort, random layered DAGs and chains. All return a :class:`TaskDag`.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from typing import List, Optional, Sequence, Tuple
 
@@ -55,6 +56,64 @@ class TaskDag:
     def sources(self) -> np.ndarray:
         return np.nonzero(self.pred_count == 0)[0]
 
+    def deque_bound(self, owner_lifo: bool = True) -> int:
+        """Most tasks that one processor's deque can hold at once, for any
+        interleaving of steals: the number of paths in a path cover of the
+        DAG (at most ``n``), and under owner-LIFO also at most
+        ``(D - 1) * H + 1``, where D is the largest out-degree and H the
+        largest height (:meth:`heights`, in edges).
+
+        Proof. Only processor ``i`` pushes on deque ``i``: when a task it
+        ran completes, the children that became ready. ``i`` steals only
+        when its deque is empty, so a task it starts by a steal (or the
+        source, on processor 0) starts with deque ``i`` empty.
+
+        Any discipline: a task in a deque is ready and not started, and
+        of two tasks where one precedes the other, the later one is not
+        ready before the earlier completes. So the tasks in all deques
+        together are pairwise unordered, and a path of the DAG holds at
+        most one of them. A set of vertex-disjoint paths that covers every
+        task (each task keeps at most one edge to a child and one from a
+        parent, picked greedily) then bounds them by its number of paths.
+
+        Owner-LIFO: call the children pushed by one completion a batch,
+        and P the height of the task whose completion pushed it; each of
+        its tasks has height at most P - 1. The owner pops the newest task
+        and a thief takes the oldest, so the deque is always its batches in
+        the order they were pushed, each a contiguous run. Invariant: while
+        ``i`` runs task z, the batches' P are strictly decreasing from the
+        oldest to the newest and all exceed h(z). It holds when z starts on
+        an empty deque. When z completes it pushes a batch of P = h(z),
+        below the older batches' P; unless the deque is empty the owner
+        then pops y, the newest task, of height below its batch's P, and
+        that batch, or the older ones if it empties, keeps P above h(y).
+        A steal only shortens or drops the oldest batch. So each batch
+        holds at most D - 1 tasks after the pop that follows its push, and
+        the batches older than z's, whose P are distinct heights in
+        h(z) + 1..H with h(z) >= 1, number at most H - 1: at most
+        (D - 1)(H - 1) + D = (D - 1) H + 1 tasks. Under FIFO the owner
+        pops the oldest batch and the invariant fails: one processor runs
+        a binary tree breadth first and holds a whole level, up to 2^H
+        tasks, against H + 1.
+        """
+        chains, lifo = self._deque_bounds
+        return min(chains, lifo) if owner_lifo else chains
+
+    @functools.cached_property
+    def _deque_bounds(self) -> Tuple[int, int]:
+        """(paths of a greedy path cover, (D - 1) * H + 1)."""
+        # Each task extends the path of its first child that no other task
+        # has extended yet.
+        taken = np.zeros(self.n, bool)
+        for u in range(self.n):
+            kids = self.child_idx[self.child_ptr[u]:self.child_ptr[u + 1]]
+            free = kids[~taken[kids]]
+            if free.size:
+                taken[free[0]] = True
+        chains = self.n - int(taken.sum())
+        height = int(self.heights().max(initial=0))
+        return chains, (max(self.max_children, 1) - 1) * height + 1
+
     def critical_path(self) -> int:
         """Longest path length (sum of durations) — the D of the WS bound."""
         n = self.n
@@ -77,7 +136,8 @@ class TaskDag:
         return int(finish.max() + 0)
 
     def heights(self) -> np.ndarray:
-        """Height = length (in tasks) of the longest path to a sink (paper §2.1.2)."""
+        """Height = length (in edges) of the longest path to a sink, 0 at
+        a sink (paper §2.1.2)."""
         n = self.n
         h = np.zeros(n, np.int64)
         outdeg = np.diff(self.child_ptr).astype(np.int64)
@@ -188,6 +248,69 @@ def merge_sort(n_elems: int, cutoff: int = 16, split_dur: int = 1) -> TaskDag:
 
     rec(n_elems, None)
     return _build(dur, edges, f"merge_sort(n={n_elems},cutoff={cutoff})")
+
+
+def bots_sort(n_elems: int, merge_cutoff: int = 2048,
+              quick_cutoff: int = 2048, split_dur: int = 1) -> TaskDag:
+    """Cilksort's task DAG, as BOTS *sort* runs it (Duran et al., ICPP
+    2009: ``cilksort_par`` and ``cilkmerge_par``; defaults from its
+    ``sort/app-config.h``). Tasks are numbered in the order below, depth
+    first; every spawn and join costs ``split_dur``.
+
+    * Sort of ``m`` elements: below ``quick_cutoff`` one leaf of the paper's
+      cost ``max(m log2 m / 4, 1)`` (Fig 9; BOTS's insertion cutoff only
+      changes a leaf's inner cost). Else a spawn of four sorts, of ``q``,
+      ``q``, ``q`` and ``m - 3q`` (``q = m // 4``); a join after them that
+      spawns the merges ``(q, q)`` and ``(q, m - 3q)``; a join after those,
+      then the merge ``(2q, m - 2q)``, which BOTS runs inline, so it is the
+      join's continuation and no spawned task.
+    * Merge of ``(a, b)``, ordered so that ``a >= b``: for ``b = 0`` a copy
+      of cost ``max(a // 2, 1)``; below ``merge_cutoff`` a serial merge of
+      the paper's cost ``max((a + b) // 2, 1)``; else a binary search of
+      cost ``max(floor(log2 b), 1)`` that spawns the merges
+      ``(a // 2, b // 2)`` and ``(a - a // 2 - 1, b - b // 2)``, and a join
+      after them. BOTS's binary search splits on the data; this assumes the
+      even split, a random permutation's expectation.
+
+    One source (the top sort's first task) and one sink (its last merge)."""
+    dur: List[int] = []
+    edges: List[Tuple[int, int]] = []
+
+    def task(cost: int, *parents: int) -> int:
+        tid = len(dur)
+        dur.append(cost)
+        edges.extend((u, tid) for u in parents)
+        return tid
+
+    def merge(a: int, b: int, parent: Optional[int]) -> int:
+        """Returns the task that ends the merge."""
+        a, b = max(a, b), min(a, b)
+        up = () if parent is None else (parent,)
+        if b == 0:
+            return task(max(a // 2, 1), *up)
+        if b < merge_cutoff:
+            return task(max((a + b) // 2, 1), *up)
+        search = task(max(b.bit_length() - 1, 1), *up)
+        lo = merge(a // 2, b // 2, search)
+        hi = merge(a - a // 2 - 1, b - b // 2, search)
+        return task(split_dur, lo, hi)
+
+    def sort(m: int, parent: Optional[int]) -> int:
+        """Returns the task that ends the sort."""
+        up = () if parent is None else (parent,)
+        if m < quick_cutoff:
+            return task(max(int(m * max(np.log2(max(m, 2)), 1.0) / 4), 1),
+                        *up)
+        q = m // 4
+        spawn = task(split_dur, *up)
+        parts = [sort(s, spawn) for s in (q, q, q, m - 3 * q)]
+        join = task(split_dur, *parts)
+        halves = [merge(q, q, join), merge(q, m - 3 * q, join)]
+        return merge(2 * q, m - 2 * q, task(split_dur, *halves))
+
+    sort(n_elems, None)
+    return _build(dur, edges, f"bots_sort(n={n_elems},merge={merge_cutoff},"
+                              f"quick={quick_cutoff})")
 
 
 def random_layered(n_layers: int, width: int, p_edge: float = 0.3,

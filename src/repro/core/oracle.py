@@ -252,6 +252,7 @@ def simulate_dag_oracle(
     executed = np.zeros(p, np.int64)
     tasks_run = np.zeros(p, np.int64)
     deques = [[] for _ in range(p)]  # list: index 0 = head (steal side)
+    max_deque = 0                    # the longest any deque grew
 
     active_count = p
     n_completed = n_events = n_requests = n_success = n_fail = 0
@@ -287,6 +288,7 @@ def simulate_dag_oracle(
                     pred[child] -= 1
                     if pred[child] == 0:
                         deques[i].append(child)
+                max_deque = max(max_deque, len(deques[i]))
             cur[i] = -1
             if n_completed >= n:
                 done = True
@@ -339,7 +341,7 @@ def simulate_dag_oracle(
         makespan=makespan, n_events=n_events, n_requests=n_requests,
         n_success=n_success, n_fail=n_fail, total_idle=total_idle,
         startup_end=startup_end, executed=executed, tasks_run=tasks_run,
-        n_completed=n_completed, overflow=not done,
+        n_completed=n_completed, overflow=not done, max_deque=max_deque,
     )
 
 

@@ -39,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro import obs
 from repro.core import engine as eng
 from repro.core.backend import pallas_interpret_default
+from repro.core.dag import DagModel
 from repro.core.sweep import as_model
 
 
@@ -108,6 +109,15 @@ def _host_consts(model) -> tuple:
             + tuple(jax.device_get(model.static_arrays())))
 
 
+def _state_bytes(model, consts, scn1) -> int:
+    """Bytes of the state that one scenario carries through the event
+    loop: the event core's ``CoreState`` and the task model's own."""
+    state = jax.eval_shape(
+        lambda c, s: model.init(c[2:], s, eng.init_core(model, s)),
+        consts, scn1)
+    return sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(state))
+
+
 class KernelCall(NamedTuple):
     """A built ``pallas_call`` and how to read its outputs back into the
     model's result pytree."""
@@ -124,7 +134,9 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
     key. The call is JAX's own jitted wrapper, so one object reused across
     dispatches traces and lowers once per device and input shape; a fresh
     kernel closure per dispatch would miss JAX's cache every time. The
-    key holds avals, not values, so tracers build it too."""
+    key holds avals, not values, so tracers build it too. A build sets the
+    gauge ``ws_sim.state_bytes{task_model}``, the bytes of one scenario's
+    carried state, so no dispatch pays for it."""
     consts = _host_consts(model)
     scn1 = jax.tree.unflatten(
         scn_def, [jax.ShapeDtypeStruct((), d) for d in scn_dtypes])
@@ -133,6 +145,9 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
         consts, scn1)
     res_leaves, res_def = jax.tree.flatten(res_struct)
     bool_mask = tuple(l.dtype == jnp.bool_ for l in res_leaves)
+    obs.REGISTRY.gauge("ws_sim.state_bytes",
+                       {"task_model": _task_model(model)}).set(
+        _state_bytes(model, consts, scn1))
 
     def _const_spec(x):
         rank = x.ndim
@@ -193,9 +208,10 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
     event budget is zero — the padded lanes exit the loop before executing
     a single event, and their rows are dropped from the output.
     Bit-exactness is untouched: grid cells are independent. Each chunk is
-    one ``ws_sim.chunk`` span: its slice and pad, and the eager call of the
-    cached ``pallas_call`` (:func:`kernel_call`), which traces, lowers and
-    compiles or loads only on its first dispatch of a shape to a device.
+    one ``ws_sim.chunk`` span (a DAG's with its ``deque_cap``): its slice
+    and pad, and the eager call of the cached ``pallas_call``
+    (:func:`kernel_call`), which traces, lowers and compiles or loads only
+    on its first dispatch of a shape to a device.
     Each dispatch counts ``ws_sim.kernel_cache{result=hit|miss}``.
     """
     if interpret is None:
@@ -206,6 +222,8 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
         c = max(int(grid_chunk), 1)
         attrs = dict(task_model=_task_model(model),
                      device=_device_id(scn.W))
+        if isinstance(model, DagModel):
+            attrs["deque_cap"] = model.cfg.cap
         outs = []
         for lo in range(0, G, c):
             n = min(c, G - lo)

@@ -2,9 +2,10 @@
 
 The TPU's compiler is installed with JAX, so the programs the chip would run
 are compiled here at their real sizes: the ``ws_sim`` Pallas kernel for each
-task model at the sizes ``chip_smoke.py`` runs, and the ``jax`` backend's
-segment step at the paper cell. This catches what interpret mode cannot: a
-block shape, an op or a layout the chip's kernel compiler refuses.
+task model at the sizes ``chip_smoke.py`` runs and at the benchmark's BOTS
+sort DAG, and the ``jax`` backend's segment step at the paper cell. This
+catches what interpret mode cannot: a block shape, an op or a layout the
+chip's kernel compiler refuses.
 
 The topology is described inside a fixture (never at import), since only
 one process at a time may load the TPU library; the persistent compilation
@@ -79,10 +80,15 @@ def _models():
         "adaptive": sw.make_model("adaptive", topology=topo,
                                   pool_cap=1 << 13,
                                   max_events=dv.default_max_events(W, p, lam)),
+        # the benchmark's dag_batch cell, with the derived deque bound
+        "bots_sort": sw.make_model("dag", topology=one_cluster(32, 2),
+                                   dag=dag_gen.bots_sort(1 << 21),
+                                   max_events=1 << 20),
     }
 
 
-@pytest.mark.parametrize("name", ["divisible", "dag", "adaptive"])
+@pytest.mark.parametrize("name", ["divisible", "dag", "adaptive",
+                                  "bots_sort"])
 def test_ws_sim_kernel_compiles_for_v5e(one_chip, name):
     model = _models()[name]
     fn = jax.jit(functools.partial(ws_sim_pallas, model, interpret=False,
