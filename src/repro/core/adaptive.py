@@ -155,7 +155,7 @@ class AdaptiveModel(eng.TaskModel):
         return eng.cond(ready, lambda s: self._push(s[0], s[1], i, m),
                         lambda s: s, (core, ms))
 
-    def on_idle(self, arrays, cid, hops, scn, core, ms: AdaptiveState, i, t):
+    def on_idle(self, arrays, cid, chops, scn, core, ms: AdaptiveState, i, t):
         c = eng.read(ms.cur_task, i)
         core, ms = eng.cond(
             c >= 0, lambda s: self._complete_task(s[0], s[1], i, c, t),
@@ -196,15 +196,15 @@ class AdaptiveModel(eng.TaskModel):
                 core, ms = s
                 core = eng.enter_idle(core, i, t)
                 core = eng.log(self, core, t, i, EV_IDLE, 0)
-                return eng.start_stealing(self, cid, hops, scn, core, i, t), ms
+                return eng.start_stealing(self, cid, chops, scn, core, i, t), ms
 
             return eng.cond(empty, steal, pop_local, s)
 
         return eng.cond(finished, _finish, _continue, (core, ms))
 
-    def on_request(self, arrays, cid, hops, scn, core, ms: AdaptiveState, i, t):
+    def on_request(self, arrays, cid, chops, scn, core, ms: AdaptiveState, i, t):
         v = eng.read(core.victim, i)
-        d_vi = eng.dist(cid, hops, scn, v, i)
+        d_vi = eng.dist(cid, chops, scn, v, i)
         free = eng.chan_free(self, core, v, t)
 
         qlen = eng.read(ms.tail, v) - eng.read(ms.head, v)
@@ -265,7 +265,7 @@ class AdaptiveModel(eng.TaskModel):
                        jnp.where(ok, EV_REQ_OK, EV_REQ_FAIL), v)
         return core, ms
 
-    def on_answer(self, arrays, cid, hops, scn, core, ms: AdaptiveState, i, t):
+    def on_answer(self, arrays, cid, chops, scn, core, ms: AdaptiveState, i, t):
         task = eng.read(core.stolen, i)
         ok = task >= 0
 
@@ -279,7 +279,7 @@ class AdaptiveModel(eng.TaskModel):
 
         def retry(s):
             core, ms = s
-            core = eng.start_stealing(self, cid, hops, scn, core, i, t)
+            core = eng.start_stealing(self, cid, chops, scn, core, i, t)
             return eng.log(self, core, t, i, EV_ANS_FAIL,
                            eng.read(core.victim, i)), ms
 

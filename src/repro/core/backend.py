@@ -456,6 +456,16 @@ class PallasBackend(ExecutionBackend):
         return ws_sim_pallas(model, scn, interpret=self._interpret,
                              grid_chunk=self.grid_chunk)
 
+    def _fetch(self, model, rows, chunks, outs) -> "sw.GridResult":
+        """The base fetch, then each device chunk's block fill counted from
+        its rows' events, now on the host (``ws_sim.block_*_events``)."""
+        from repro.kernels.ws_sim import count_blocks
+        grid = super()._fetch(model, rows, chunks, outs)
+        for lo, hi, _ in chunks:
+            count_blocks(model, grid.extras["n_events"][lo:hi],
+                         self.grid_chunk)
+        return grid
+
 
 class PallasInterpretBackend(PallasBackend):
     """The Pallas kernel in interpret mode: runs anywhere, CI-checkable."""

@@ -147,7 +147,7 @@ class DagModel(eng.TaskModel):
         return eng.fori_loop(eng.read(cptr, c), eng.read(cptr, c + 1), body,
                              (core, ms), self.cfg.dag.max_children)
 
-    def on_idle(self, arrays, cid, hops, scn, core, ms: DagState, i, t):
+    def on_idle(self, arrays, cid, chops, scn, core, ms: DagState, i, t):
         dur, cptr, cidx, _ = arrays
         c = eng.read(ms.cur_task, i)
         has_task = c >= 0
@@ -195,16 +195,16 @@ class DagModel(eng.TaskModel):
                 core, ms = s
                 core = eng.enter_idle(core, i, t)
                 core = eng.log(self, core, t, i, EV_IDLE, 0)
-                return eng.start_stealing(self, cid, hops, scn, core, i, t), ms
+                return eng.start_stealing(self, cid, chops, scn, core, i, t), ms
 
             return eng.cond(empty, steal, pop_local, s)
 
         return eng.cond(finished, _finish, _continue, (core, ms))
 
-    def on_request(self, arrays, cid, hops, scn, core, ms: DagState, i, t):
+    def on_request(self, arrays, cid, chops, scn, core, ms: DagState, i, t):
         v = eng.read(core.victim, i)
         qlen = eng.read(ms.tail, v) - eng.read(ms.head, v)
-        d_vi = eng.dist(cid, hops, scn, v, i)
+        d_vi = eng.dist(cid, chops, scn, v, i)
         free = eng.chan_free(self, core, v, t)
         ok = (qlen > scn.theta_static) & free
         slot = lax.rem(eng.read(ms.head, v), self.cfg.cap)
@@ -215,7 +215,7 @@ class DagModel(eng.TaskModel):
                        jnp.where(ok, EV_REQ_OK, EV_REQ_FAIL), v)
         return core, ms
 
-    def on_answer(self, arrays, cid, hops, scn, core, ms: DagState, i, t):
+    def on_answer(self, arrays, cid, chops, scn, core, ms: DagState, i, t):
         dur = arrays[0]
         task = eng.read(core.stolen, i)
         ok = task >= 0
@@ -230,7 +230,7 @@ class DagModel(eng.TaskModel):
 
         def retry(s):
             core, ms = s
-            core = eng.start_stealing(self, cid, hops, scn, core, i, t)
+            core = eng.start_stealing(self, cid, chops, scn, core, i, t)
             return eng.log(self, core, t, i, EV_ANS_FAIL,
                            eng.read(core.victim, i)), ms
 

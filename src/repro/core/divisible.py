@@ -71,7 +71,7 @@ class DivisibleModel(eng.TaskModel):
         rem_flight = jnp.sum(jnp.where(state2 == ANS_FLIGHT, core.stolen, 0))
         return (rem_active + rem_flight) == 0
 
-    def on_idle(self, arrays, cid, hops, scn, core, ms, i, t):
+    def on_idle(self, arrays, cid, chops, scn, core, ms, i, t):
         """idle event: processor i's running work is exhausted (paper idle())."""
         state2 = eng.write(core.state, i, REQ_FLIGHT)  # tentatively not-active
         finished = self.is_done(arrays, core, ms, i, t)
@@ -85,17 +85,17 @@ class DivisibleModel(eng.TaskModel):
             return eng.finish(self, c, t, idle_now)
 
         def _steal(c: eng.CoreState) -> eng.CoreState:
-            return eng.start_stealing(self, cid, hops, scn, c, i, t)
+            return eng.start_stealing(self, cid, chops, scn, c, i, t)
 
         return eng.cond(finished, _finish, _steal, core), ms
 
-    def on_request(self, arrays, cid, hops, scn, core, ms, i, t):
+    def on_request(self, arrays, cid, chops, scn, core, ms, i, t):
         """steal-request event: thief i's request reaches victim v
         (paper answer_steal_request() + get_part_of_work_if_exist())."""
         v = eng.read(core.victim, i)
         w_v = jnp.where(eng.read(core.state, v) == ACTIVE,
                         eng.read(core.idle_at, v) - t, 0)
-        d_vi = eng.dist(cid, hops, scn, v, i)
+        d_vi = eng.dist(cid, chops, scn, v, i)
         thr = eng.steal_threshold(scn, d_vi)
         free = eng.chan_free(self, core, v, t)
         amt = w_v // 2
@@ -116,7 +116,7 @@ class DivisibleModel(eng.TaskModel):
         return eng.log(self, core, t, i,
                        jnp.where(ok, EV_REQ_OK, EV_REQ_FAIL), v), ms
 
-    def on_answer(self, arrays, cid, hops, scn, core, ms, i, t):
+    def on_answer(self, arrays, cid, chops, scn, core, ms, i, t):
         """steal-answer event: the (possibly empty) answer reaches thief i
         (paper steal_answer())."""
         amt = eng.read(core.stolen, i)
@@ -127,7 +127,7 @@ class DivisibleModel(eng.TaskModel):
             return eng.log(self, c, t, i, EV_ANS_OK, amt)
 
         def _retry(c: eng.CoreState) -> eng.CoreState:
-            c = eng.start_stealing(self, cid, hops, scn, c, i, t)
+            c = eng.start_stealing(self, cid, chops, scn, c, i, t)
             return eng.log(self, c, t, i, EV_ANS_FAIL,
                            eng.read(c.victim, i))
 

@@ -26,8 +26,9 @@ hashable (frozen-dataclass) object implementing:
 ``init(arrays, scn, core) -> (core, ms)``
     patch the freshly built :class:`CoreState` and build the model-state
     pytree ``ms`` (deques, task pools, predecessor counts, ...);
-``on_idle / on_request / on_answer (arrays, cid, hops, scn, core, ms, i, t)``
-    the three event handlers, each returning ``(core, ms)``;
+``on_idle / on_request / on_answer (arrays, cid, chops, scn, core, ms, i, t)``
+    the three event handlers, each returning ``(core, ms)``; ``chops`` is
+    the topology's k×k cluster hop table flattened to int32[k*k];
 ``is_done(arrays, core, ms, i, t)``
     the termination predicate, used by the model's ``on_idle``;
 ``results(core, ms)``
@@ -38,7 +39,8 @@ The concrete models are ``divisible.DivisibleModel``, ``dag.DagModel`` and
 in ``repro.core.oracle``. Because handlers are plain traced JAX, the same
 ``_simulate_impl`` body runs as ordinary jit/vmap code, sharded SPMD over a
 mesh (``sweep.simulate_sharded``), or inside the Pallas kernel
-(``kernels.ws_sim``) with all state VMEM-resident.
+(``kernels.ws_sim``) with all state VMEM-resident, one row or a block of
+rows (:func:`simulate_block`) a grid step.
 """
 from __future__ import annotations
 
@@ -46,6 +48,7 @@ import contextlib
 import contextvars
 import dataclasses
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -190,6 +193,11 @@ class TaskModel:
     documented in the module docstring.
     """
 
+    def __post_init__(self):
+        # the event core reads distances from the cluster hop table: refuse
+        # a topology that has none here, where the model is built
+        self.topology.cluster_hops
+
     @property
     def topology(self) -> Topology:
         return self.cfg.topology
@@ -223,20 +231,26 @@ class TaskModel:
 # ---------------------------------------------------------------------------
 
 _SELECT_FORMS = contextvars.ContextVar("select_forms", default=False)
+_EVERY_BRANCH = contextvars.ContextVar("every_branch", default=False)
 
 
 @contextlib.contextmanager
-def select_forms():
+def select_forms(every_branch: bool = False):
     """Trace the event core with one-hot select forms (the Pallas kernel
     body, ``kernels.ws_sim``). Mosaic has no lowering for ``scatter``,
     ``scatter-add``, ``dynamic_slice`` or an int32 ``argmin``; the helpers
     below emit those forms under XLA and masks, selects and reductions here.
-    All operands are int32/uint32/bool, so both forms are exact."""
-    token = _SELECT_FORMS.set(True)
+    All operands are int32/uint32/bool, so both forms are exact.
+
+    ``every_branch`` (a block of rows, :func:`simulate_block`): a
+    :func:`switch` runs every branch and selects, as each row of the block
+    takes its own branch."""
+    tokens = (_SELECT_FORMS.set(True), _EVERY_BRANCH.set(every_branch))
     try:
         yield
     finally:
-        _SELECT_FORMS.reset(token)
+        _EVERY_BRANCH.reset(tokens[1])
+        _SELECT_FORMS.reset(tokens[0])
 
 
 def _hit(x, idx):
@@ -338,6 +352,13 @@ def switch(index, branches, *operands):
     as int32: Mosaic cannot legalize an ``scf.if`` that yields them."""
     if not _SELECT_FORMS.get():
         return lax.switch(index, branches, *operands)
+    if _EVERY_BRANCH.get():
+        outs = [f(*operands) for f in branches]
+        out = outs[-1]
+        for k in range(len(outs) - 2, -1, -1):
+            out = jax.tree.map(functools.partial(_select, index == k),
+                               outs[k], out)
+        return out
     out_like = jax.eval_shape(branches[0], *operands)
     out = lax.switch(index, [_via_i32(f, operands) for f in branches],
                      *_to_i32(operands))
@@ -377,14 +398,38 @@ def first_true(mask):
     return jnp.where(k == n, 0, k)
 
 
-def dist(cid, hops, scn: Scenario, i, j):
+def cluster_hop_table(topology: Topology) -> np.ndarray:
+    """The event core's distance structure: ``topology.cluster_hops``
+    flattened to int32[k*k]; a pass over k² lanes where ``hops`` is p×p."""
+    return topology.cluster_hops.reshape(-1)
+
+
+def _n_clusters(chops) -> int:
+    return math.isqrt(chops.shape[0])
+
+
+def dist(cid, chops, scn: Scenario, i, j):
     """Scalar distance d(i, j) under the scenario's latency scalars."""
-    same = read(cid, i) == read(cid, j)
-    d = jnp.where(same, scn.lam_local, scn.lam_remote * read(hops, i, j))
+    ci, cj = read(cid, i), read(cid, j)
+    hops = read(chops, ci * _n_clusters(chops) + cj)
+    d = jnp.where(ci == cj, scn.lam_local, scn.lam_remote * hops)
     return jnp.where(i == j, jnp.int32(0), d).astype(jnp.int32)
 
 
-def select_victim(strategy: int, p: int, cid, hops, scn: Scenario,
+def hops_from(cid, chops, ci):
+    """int32[p] hop counts from cluster ``ci`` to each processor's cluster,
+    ``chops[ci * k + cid]``; in the kernel body one select over the p lanes
+    per cluster."""
+    k = _n_clusters(chops)
+    if not _SELECT_FORMS.get():
+        return chops[ci * k + cid]
+    out = jnp.zeros(cid.shape, chops.dtype)
+    for c in range(k):
+        out = jnp.where(cid == c, read(chops, ci * k + c), out)
+    return out
+
+
+def select_victim(strategy: int, p: int, cid, chops, scn: Scenario,
                   rng_i, rr_i, i):
     """Victim selection (topology engine §3.3); returns (victim, rng', rr')."""
     if strategy == topo_mod.UNIFORM:
@@ -409,9 +454,10 @@ def select_victim(strategy: int, p: int, cid, hops, scn: Scenario,
         return v, rng_i, rr_i
     if strategy == topo_mod.INV_DISTANCE:
         idx = jnp.arange(p, dtype=jnp.int32)
-        same = cid == read(cid, i)
-        d = jnp.where(same, scn.lam_local,
-                      scn.lam_remote * read(hops, i)).astype(jnp.float32)
+        ci = read(cid, i)
+        d = jnp.where(cid == ci, scn.lam_local,
+                      scn.lam_remote * hops_from(cid, chops, ci)
+                      ).astype(jnp.float32)
         w = jnp.where(idx == i, 0.0, 1.0 / jnp.maximum(d, 1.0))
         c = jnp.cumsum(w)
         rng_i = topo_mod.xorshift32(rng_i)
@@ -426,13 +472,13 @@ def select_victim(strategy: int, p: int, cid, hops, scn: Scenario,
     raise ValueError(f"unknown strategy {strategy}")
 
 
-def start_stealing(model: TaskModel, cid, hops, scn: Scenario,
+def start_stealing(model: TaskModel, cid, chops, scn: Scenario,
                    core: CoreState, i, t) -> CoreState:
     """processor engine start_stealing(): pick victim, emit request event."""
-    v, rng_i, rr_i = select_victim(model.topology.strategy, model.p, cid, hops,
-                                   scn, read(core.rng, i),
+    v, rng_i, rr_i = select_victim(model.topology.strategy, model.p, cid,
+                                   chops, scn, read(core.rng, i),
                                    read(core.rr_aux, i), i)
-    d = dist(cid, hops, scn, i, v)
+    d = dist(cid, chops, scn, i, v)
     return core._replace(
         state=write(core.state, i, REQ_FLIGHT),
         victim=write(core.victim, i, v),
@@ -559,40 +605,76 @@ def init_core(model: TaskModel, scn: Scenario) -> CoreState:
     )
 
 
-def _simulate_impl(model: TaskModel, cid, hops, arrays, scn: Scenario):
-    """Event loop with every array input passed explicitly (Pallas-friendly:
-    the kernel feeds cid/hops/model arrays as refs, not closure constants)."""
-    core, ms = model.init(arrays, scn, init_core(model, scn))
+def _budget(model: TaskModel, scn: Scenario):
+    """Per-row event budget: the static model cap bounds the compiled loop,
+    the (traced) scenario budget truncates it per row — a row dispatched
+    under a relaxed static cap is bit-identical to a run whose static cap
+    equals its budget, because the loop freezes each row at its own cond."""
+    return jnp.minimum(jnp.int32(model.max_events),
+                       jnp.asarray(scn.max_events, jnp.int32))
 
-    handlers = [functools.partial(h, arrays, cid, hops, scn)
+
+def _live(core: CoreState, budget):
+    return (~core.done) & (core.n_events < budget) & (~core.halt)
+
+
+def _event(model: TaskModel, cid, chops, arrays, scn: Scenario, c, m):
+    """One event of one row: the handler of the processor whose pending
+    event is earliest."""
+    handlers = [functools.partial(h, arrays, cid, chops, scn)
                 for h in (model.on_idle, model.on_request, model.on_answer)]
+    i = argmin(c.ev_time)
+    t = read(c.ev_time, i)
+    c = c._replace(t=t, n_events=c.n_events + 1)
+    return switch(read(c.state, i), handlers, c, m, i, t)
 
-    # Per-row event budget: the static model cap bounds the compiled loop,
-    # the (traced) scenario budget truncates it per row — a row dispatched
-    # under a relaxed static cap is bit-identical to a run whose static cap
-    # equals its budget, because lax.while_loop freezes each vmap lane at
-    # its own cond.
-    budget = jnp.minimum(jnp.int32(model.max_events),
-                         jnp.asarray(scn.max_events, jnp.int32))
 
-    def cond(s):
-        c = s[0]
-        return (~c.done) & (c.n_events < budget) & (~c.halt)
+def _simulate_impl(model: TaskModel, cid, chops, arrays, scn: Scenario):
+    """Event loop with every array input passed explicitly (Pallas-friendly:
+    the kernel feeds cid/chops/model arrays as refs, not closure constants)."""
+    core, ms = model.init(arrays, scn, init_core(model, scn))
+    budget = _budget(model, scn)
 
     def body(s):
-        c, m = s
-        i = argmin(c.ev_time)
-        t = read(c.ev_time, i)
-        c = c._replace(t=t, n_events=c.n_events + 1)
-        return switch(read(c.state, i), handlers, c, m, i, t)
+        return _event(model, cid, chops, arrays, scn, *s)
+
+    core, ms = while_loop(lambda s: _live(s[0], budget), body, (core, ms))
+    return model.results(core, ms)
+
+
+def simulate_block(model: TaskModel, cid, chops, arrays, scn: Scenario):
+    """:func:`_simulate_impl` over a block of rows (every leaf of ``scn``
+    has a leading block axis), written out for the kernel, where a vmapped
+    ``while_loop`` does not lower: the loop runs while any row is live, the
+    body is the vmapped one-row event, and each row's carry takes a select
+    on its own live mask. So every row runs exactly its own event
+    sequence, counters and budget. Traced under
+    ``select_forms(every_branch=True)``, the event's switch is selects."""
+    def one(s):
+        return model.init(arrays, s, init_core(model, s))
+
+    core, ms = jax.vmap(one)(scn)
+    budget = _budget(model, scn)
+
+    def cond(s):
+        return jnp.max(_live(s[0], budget).astype(jnp.int32)) > 0
+
+    def body(s):
+        live = _live(s[0], budget)
+        new = jax.vmap(functools.partial(_event, model, cid, chops, arrays))(
+            scn, *s)
+        return jax.tree.map(
+            lambda a, b: _select(
+                live.reshape(live.shape + (1,) * (a.ndim - 1)), a, b),
+            new, s)
 
     core, ms = while_loop(cond, body, (core, ms))
-    return model.results(core, ms)
+    return jax.vmap(model.results)(core, ms)
 
 
 def _simulate(model: TaskModel, scn: Scenario):
     return _simulate_impl(model, jnp.asarray(model.topology.cluster_id),
-                          jnp.asarray(model.topology.hops),
+                          jnp.asarray(cluster_hop_table(model.topology)),
                           model.static_arrays(), scn)
 
 
@@ -630,27 +712,21 @@ def default_segment_len(max_events: int, ev_budget=None) -> int:
     return int(max(32, min(128, _pow2ceil(base))))
 
 
-def _segment_impl(model: TaskModel, cid, hops, arrays, scn: Scenario,
+def _segment_impl(model: TaskModel, cid, chops, arrays, scn: Scenario,
                   core: CoreState, ms, seg_len: int):
     """Run up to ``seg_len`` further events of one lane. The loop body and
     termination condition are identical to :func:`_simulate_impl`; the only
     extra clause is the per-segment event counter, so chaining segments
     reproduces the monolithic loop exactly."""
-    handlers = [functools.partial(h, arrays, cid, hops, scn)
-                for h in (model.on_idle, model.on_request, model.on_answer)]
-    budget = jnp.minimum(jnp.int32(model.max_events),
-                         jnp.asarray(scn.max_events, jnp.int32))
+    budget = _budget(model, scn)
 
     def cond(s):
         c, _, k = s
-        return (~c.done) & (c.n_events < budget) & (~c.halt) & (k < seg_len)
+        return _live(c, budget) & (k < seg_len)
 
     def body(s):
         c, m, k = s
-        i = argmin(c.ev_time)
-        t = read(c.ev_time, i)
-        c = c._replace(t=t, n_events=c.n_events + 1)
-        c, m = switch(read(c.state, i), handlers, c, m, i, t)
+        c, m = _event(model, cid, chops, arrays, scn, c, m)
         return (c, m, k + jnp.int32(1))
 
     core, ms, k = lax.while_loop(cond, body, (core, ms, jnp.int32(0)))
@@ -676,12 +752,13 @@ def _segment_step(model: TaskModel, seg_len: int):
     the useful events executed -- the driver's wasted-lane telemetry.
     """
     cid = jnp.asarray(model.topology.cluster_id)
-    hops = jnp.asarray(model.topology.hops)
+    chops = jnp.asarray(cluster_hop_table(model.topology))
     arrays = model.static_arrays()
 
     def one(scn, state):
         core, ms = state
-        return _segment_impl(model, cid, hops, arrays, scn, core, ms, seg_len)
+        return _segment_impl(model, cid, chops, arrays, scn, core, ms,
+                             seg_len)
 
     def step(scn, state):
         core, ms, fin, k = jax.vmap(one)(scn, state)

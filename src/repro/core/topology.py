@@ -10,6 +10,8 @@ scenario:
 
 * ``cluster_id`` -- int32[p]    cluster membership (structure, static),
 * ``hops``       -- int32[p, p] inter-cluster hop counts (structure, static),
+                    a function of the two processors' clusters, so the
+                    event core reads the k×k ``cluster_hops`` table instead,
 * ``lam_local``  -- intra-cluster delay (scalar, sweepable),
 * ``lam_remote`` -- per-hop inter-cluster delay (scalar, sweepable).
 
@@ -36,6 +38,7 @@ Pallas kernel and the numpy oracle produce bit-identical traces.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax.numpy as jnp
@@ -143,6 +146,28 @@ class Topology:
     @property
     def n_clusters(self) -> int:
         return int(self.cluster_id.max()) + 1
+
+    @functools.cached_property
+    def cluster_hops(self) -> np.ndarray:
+        """int32[k, k] inter-cluster hop counts, k = ``n_clusters``:
+        ``hops[i, j] == cluster_hops[cluster_id[i], cluster_id[j]]`` for
+        every i and j in different clusters (0 on the diagonal, which the
+        distance never reads). The event core reads this table, not the
+        p×p ``hops``. Raises ValueError where two processors of one pair of
+        clusters are a different number of hops apart."""
+        cid = np.asarray(self.cluster_id)
+        hops = np.asarray(self.hops)
+        present, first = np.unique(cid, return_index=True)
+        table = np.zeros((self.n_clusters,) * 2, np.int32)
+        table[np.ix_(present, present)] = hops[np.ix_(first, first)]
+        np.fill_diagonal(table, 0)
+        cross = cid[:, None] != cid[None, :]
+        if not (hops[cross] == table[cid[:, None], cid[None, :]][cross]).all():
+            raise ValueError(
+                f"topology {self.name!r}: hops between processors of two "
+                f"clusters differ, so they are not a function of the "
+                f"clusters (the event core reads a k×k cluster hop table)")
+        return table
 
     def with_strategy(self, strategy: int, remote_prob: Optional[float] = None) -> "Topology":
         return dataclasses.replace(

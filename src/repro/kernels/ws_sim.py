@@ -1,5 +1,5 @@
-"""Pallas kernel: batched Work-Stealing simulations, one scenario per grid
-cell — the paper-representative hot spot (DESIGN.md §2, §4).
+"""Pallas kernel: batched Work-Stealing simulations, a block of scenarios
+per grid step — the paper-representative hot spot (DESIGN.md §2, §4).
 
 The unified event core keeps O(p) int32 state (event times, processor
 states, PRNG lanes) plus the task model's pytree (deques, task pools).
@@ -7,14 +7,23 @@ Running a Monte-Carlo sweep as ordinary JAX re-reads that state from HBM on
 every event; here the *entire* per-scenario state lives in VMEM/registers
 for the whole event loop, so HBM is touched exactly twice: scenario
 parameters in, results out. The event loop body is the same traced code as
-the library engine (``repro.core.engine._simulate_impl``), so the kernel is
-bit-identical to the oracle-validated engine by construction — for EVERY
-task model (divisible, DAG, adaptive), not just the divisible hot path.
+the library engine (``repro.core.engine._simulate_impl``, or its block form
+``simulate_block``), so the kernel is bit-identical to the oracle-validated
+engine by construction — for EVERY task model (divisible, DAG, adaptive),
+not just the divisible hot path.
 
-Grid: ``(G,)`` scenarios. The scenario parameters are whole ``(G,)``
-columns in SMEM read at the grid index; each result leaf has a block whose
-last two dims are the array's own (the TPU's block rule), reshaped back in
-the wrapper. The wrapper is fully generic: it derives the output pytree via
+Grid: ``(G / B,)`` steps of B scenarios (:func:`block_rows`). A divisible
+scenario carries only scalars and int32[p] vectors, so B = 8 rows run one
+per sublane: every state vector fills the vregs it would occupy alone, the
+three event handlers run for every row as selects, and the loop runs while
+any row is live, each row's carry frozen by its own live mask
+(``engine.simulate_block``). A model that reads shared arrays or carries a
+table a row (DAG, adaptive) runs B = 1, the one-row loop. The scenario
+parameters are whole ``(G,)`` columns in SMEM read at the step's rows; each
+result leaf has a ``(B,) + tile`` block whose last two dims are the array's
+own (the TPU's block rule), reshaped back in the wrapper. Distances come
+from the topology's k×k cluster hop table, not its p×p ``hops``. The
+wrapper is fully generic: it derives the output pytree via
 ``jax.eval_shape`` on the model's result type and threads the model's static
 arrays (DAG durations/edges) as kernel inputs rather than closure constants.
 It builds that ``pallas_call`` once per model and shape (:func:`kernel_call`),
@@ -50,6 +59,37 @@ def _fresh(x):
     return jnp.where(lax.broadcasted_iota(jnp.int32, x.shape, 0) >= 0, x, 0)
 
 
+#: Scenarios one grid step runs where each carries only scalars and
+#: int32[p] vectors and reads no shared array: one per sublane of a 32-bit
+#: vreg (8 × 128 lanes), so every state vector fills, eight rows deep, the
+#: vregs it occupies alone. A shared array read per row would become a
+#: (B, n) pass, and a table per row a third dimension, so those models run
+#: one scenario a step.
+BLOCK = 8
+
+
+@functools.lru_cache(maxsize=64)
+def block_rows(model) -> int:
+    """Scenarios a grid step of ``model``'s kernel runs: :data:`BLOCK`
+    where the state one scenario carries (its ``CoreState`` and the
+    model's own) is scalars and int32[p] vectors and the model has no
+    static arrays; 1 otherwise (DAG, adaptive, a logged trace)."""
+    if model.static_arrays() or model.log_trace:
+        return 1
+    core, ms = jax.eval_shape(
+        lambda s: model.init((), s, eng.init_core(model, s)),
+        eng.make_scenario(0, 0))
+    leaves = jax.tree.leaves((core._replace(trace=None), ms))
+    ok = all(l.shape in ((), (model.p,)) for l in leaves)
+    return BLOCK if ok else 1
+
+
+def _write_out(res, out_refs, bool_mask):
+    for leaf, ref, is_bool in zip(jax.tree.leaves(res), out_refs, bool_mask):
+        val = leaf.astype(jnp.int32) if is_bool else leaf
+        ref[...] = val.reshape(ref.shape)
+
+
 def _kernel(*refs, model, n_const, n_scn, scn_def, bool_mask):
     consts = [_fresh(refs[k][...]) for k in range(n_const)]
     row = pl.program_id(0)
@@ -58,10 +98,26 @@ def _kernel(*refs, model, n_const, n_scn, scn_def, bool_mask):
     with eng.select_forms():
         res = eng._simulate_impl(model, consts[0], consts[1],
                                  tuple(consts[2:]), scn)
-    out_refs = refs[n_const + n_scn:]
-    for leaf, ref, is_bool in zip(jax.tree.leaves(res), out_refs, bool_mask):
-        val = leaf.astype(jnp.int32) if is_bool else leaf
-        ref[...] = val.reshape(ref.shape)
+    _write_out(res, refs[n_const + n_scn:], bool_mask)
+
+
+def _block_kernel(*refs, model, n_const, n_scn, scn_def, bool_mask, B):
+    """``B`` scenarios a grid step, one per sublane: rows ``B * step + b``
+    of the SMEM columns, assembled into (B,) vectors."""
+    consts = [_fresh(refs[k][...]) for k in range(n_const)]
+    base = pl.program_id(0) * B
+    rows = lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+    cols = []
+    for ref in refs[n_const:n_const + n_scn]:
+        col = jnp.zeros((B, 1), ref.dtype)
+        for b in range(B):
+            col = jnp.where(rows == b, ref[base + b], col)
+        cols.append(col.reshape(B))
+    with eng.select_forms(every_branch=True):
+        res = eng.simulate_block(model, consts[0], consts[1],
+                                 tuple(consts[2:]),
+                                 jax.tree.unflatten(scn_def, cols))
+    _write_out(res, refs[n_const + n_scn:], bool_mask)
 
 
 def _tile_shape(shape) -> tuple:
@@ -100,12 +156,13 @@ def _pad_chunk(scn: eng.Scenario, c: int) -> eng.Scenario:
 
 @functools.lru_cache(maxsize=64)
 def _host_consts(model) -> tuple:
-    """The kernel's constant inputs (topology, then the model's static
-    arrays) as host arrays, fetched once per model. Each dispatch copies
-    them from the host to its scenarios' device, so a row chunk on one
-    chip never reads another chip's copy."""
+    """The kernel's constant inputs (the cluster of each processor and the
+    flattened cluster hop table, then the model's static arrays) as host
+    arrays, fetched once per model. Each dispatch copies them from the host
+    to its scenarios' device, so a row chunk on one chip never reads
+    another chip's copy."""
     return ((np.asarray(model.topology.cluster_id),
-             np.asarray(model.topology.hops))
+             eng.cluster_hop_table(model.topology))
             + tuple(jax.device_get(model.static_arrays())))
 
 
@@ -135,8 +192,13 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
     dispatches traces and lowers once per device and input shape; a fresh
     kernel closure per dispatch would miss JAX's cache every time. The
     key holds avals, not values, so tracers build it too. A build sets the
-    gauge ``ws_sim.state_bytes{task_model}``, the bytes of one scenario's
-    carried state, so no dispatch pays for it."""
+    gauges ``ws_sim.state_bytes{task_model}``, the bytes of one scenario's
+    carried state, and ``ws_sim.block_rows{task_model}``, the scenarios a
+    grid step runs (:func:`block_rows`, which divides ``G``), so no
+    dispatch pays for them."""
+    B = block_rows(model)
+    if G % B:
+        raise ValueError(f"G={G} is not a multiple of the block of {B} rows")
     consts = _host_consts(model)
     scn1 = jax.tree.unflatten(
         scn_def, [jax.ShapeDtypeStruct((), d) for d in scn_dtypes])
@@ -145,9 +207,10 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
         consts, scn1)
     res_leaves, res_def = jax.tree.flatten(res_struct)
     bool_mask = tuple(l.dtype == jnp.bool_ for l in res_leaves)
-    obs.REGISTRY.gauge("ws_sim.state_bytes",
-                       {"task_model": _task_model(model)}).set(
+    labels = {"task_model": _task_model(model)}
+    obs.REGISTRY.gauge("ws_sim.state_bytes", labels).set(
         _state_bytes(model, consts, scn1))
+    obs.REGISTRY.gauge("ws_sim.block_rows", labels).set(B)
 
     def _const_spec(x):
         rank = x.ndim
@@ -155,11 +218,11 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
 
     def _out_spec(shape):
         rank = len(shape)
-        return pl.BlockSpec((1,) + shape,
+        return pl.BlockSpec((B,) + shape,
                             lambda i, rank=rank: (i,) + (0,) * rank)
 
     # Scenario scalars: the whole (G,) column in SMEM, read at the grid
-    # index (a (1,) VMEM block is below the TPU's 128-lane tiling).
+    # step's rows (a (B,) VMEM block is below the TPU's 128-lane tiling).
     in_specs = ([_const_spec(c) for c in consts]
                 + [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scn_dtypes))
     tiles = [_tile_shape(l.shape) for l in res_leaves]
@@ -173,11 +236,12 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
     # rename the op itself, which the trace reduction finds as
     # ``%tpu_custom_call``; so would an outer ``jax.jit``, so the backend
     # calls this object eagerly.
+    body = functools.partial(_block_kernel, B=B) if B > 1 else _kernel
     call = pl.pallas_call(
-        functools.partial(_kernel, model=model, n_const=len(consts),
+        functools.partial(body, model=model, n_const=len(consts),
                           n_scn=len(scn_dtypes), scn_def=scn_def,
                           bool_mask=bool_mask),
-        grid=(G,),
+        grid=(G // B,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -207,7 +271,9 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
     chunk is padded up to the chunk size with copies of its first row whose
     event budget is zero — the padded lanes exit the loop before executing
     a single event, and their rows are dropped from the output.
-    Bit-exactness is untouched: grid cells are independent. Each chunk is
+    Bit-exactness is untouched: grid cells are independent. A grid that
+    the model's block of rows (:func:`block_rows`) does not divide is
+    padded the same way. Each chunk is
     one ``ws_sim.chunk`` span (a DAG's with its ``deque_cap``): its slice
     and pad, and the eager call of the cached ``pallas_call``
     (:func:`kernel_call`), which traces, lowers and compiles or loads only
@@ -235,6 +301,12 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
         res = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *outs)
         return jax.tree.map(lambda x: x[:G], res) if G % c else res
 
+    B = block_rows(model)
+    if G % B:
+        res = ws_sim_pallas(model, _pad_chunk(scn, G + B - G % B),
+                            interpret=interpret)
+        return jax.tree.map(lambda x: x[:G], res)
+
     scn_leaves, scn_def = jax.tree.flatten(scn)
     misses = kernel_call.cache_info().misses
     kc = kernel_call(model, G, interpret, scn_def,
@@ -248,6 +320,25 @@ def ws_sim_pallas(model, scn: eng.Scenario, interpret: Optional[bool] = None,
     outs = [o.astype(jnp.bool_) if b else o
             for o, b in zip(outs, kc.bool_mask)]
     return jax.tree.unflatten(kc.res_def, outs)
+
+
+def count_blocks(model, n_events, grid_chunk: Optional[int] = None) -> None:
+    """Count how one :func:`ws_sim_pallas` dispatch of rows with these
+    event counts (host ints, in dispatch order) filled its blocks of
+    :func:`block_rows`: ``ws_sim.block_row_events``, the rows' events, and
+    ``ws_sim.block_slot_events``, B times each block's largest count (a
+    block steps until its last row ends). Host arithmetic only."""
+    B = block_rows(model)
+    n = np.asarray(n_events, np.int64)
+    c = max(int(grid_chunk or len(n)), 1)
+    slots = 0
+    for lo in range(0, len(n), c):
+        ck = n[lo:lo + c]
+        ck = np.pad(ck, (0, -len(ck) % B))
+        slots += B * int(ck.reshape(-1, B).max(axis=1).sum())
+    labels = {"task_model": _task_model(model)}
+    obs.REGISTRY.counter("ws_sim.block_row_events", labels).inc(int(n.sum()))
+    obs.REGISTRY.counter("ws_sim.block_slot_events", labels).inc(slots)
 
 
 def grid_shape_hazards(grid_chunk: Optional[int],

@@ -3,7 +3,8 @@
 The TPU's compiler is installed with JAX, so the programs the chip would run
 are compiled here at their real sizes: the ``ws_sim`` Pallas kernel for each
 task model at the sizes ``chip_smoke.py`` runs and at the benchmark's BOTS
-sort DAG, and the ``jax`` backend's segment step at the paper cell. This
+sort DAG, the divisible kernel's blocks of eight scenarios on one, two and
+four clusters, and the ``jax`` backend's segment step at the paper cell. This
 catches what interpret mode cannot: a block shape, an op or a layout the
 chip's kernel compiler refuses.
 
@@ -26,8 +27,9 @@ from repro.core import divisible as dv
 from repro.core import engine as eng
 from repro.core import sweep as sw
 from repro.core.backend import PallasBackend
-from repro.core.topology import one_cluster
-from repro.kernels.ws_sim import _host_consts, kernel_call, ws_sim_pallas
+from repro.core.topology import multi_cluster, one_cluster, two_clusters
+from repro.kernels.ws_sim import (_host_consts, block_rows, kernel_call,
+                                  ws_sim_pallas)
 
 GRID_CHUNK = PallasBackend.grid_chunk
 
@@ -97,6 +99,23 @@ def test_ws_sim_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in lowered.as_text()
     compiled = lowered.compile()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("topology", [
+    one_cluster(256, 1), two_clusters(256, 40),
+    multi_cluster(4, 64, 10, inter="ring")], ids=lambda t: t.name)
+def test_blocked_divisible_kernel_compiles_for_v5e(one_chip, topology):
+    """The paper cell's divisible kernel runs eight scenarios a grid step
+    (16 steps for 128 rows), reading distances from the cluster hop table:
+    1×1, 2×2 and 4×4 here."""
+    model = sw.resolve_model(topology, "divisible", W_list=[10**7],
+                             lam_list=[2, 62, 262, 482],
+                             pow2_max_events=True)
+    assert block_rows(model) == 8
+    fn = jax.jit(functools.partial(ws_sim_pallas, model, interpret=False,
+                                   grid_chunk=GRID_CHUNK))
+    compiled = fn.lower(_scenario_spec(GRID_CHUNK, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_cached_kernel_call_keeps_the_custom_call_name(one_chip):
