@@ -445,7 +445,7 @@ def backend_matrix(reps: int):
     rows = grid_rows([W], lams, n_reps)
     model = resolve_model(topo, "divisible", W_list=[W], lam_list=lams,
                           pow2_max_events=True)
-    ref = run_rows(model, rows, backend="jax", reroute=False)
+    ref = run_rows(model, rows, backend="jax")
     ev = np.asarray(ref.extras["n_events"], np.float64)
     convoy = 1.0 - ev.sum() / (len(rows) * ev.max())
     interp_n = min(8, len(rows))
@@ -460,7 +460,7 @@ def backend_matrix(reps: int):
             else rows
         nb = len(rows_b)
         def run():
-            return run_rows(model, rows_b, backend=name, reroute=False)
+            return run_rows(model, rows_b, backend=name)
         run()                                # compile + warm
         t0 = time.time()
         g = run()
@@ -612,7 +612,7 @@ def sanitizer_overhead(reps: int):
     def timed() -> float:
         t0 = time.time()
         for g in grids:
-            run_rows(model, g, backend="jax", reroute=False)
+            run_rows(model, g, backend="jax")
         return time.time() - t0
 
     timed()                                  # compile + warm (both widths)

@@ -21,8 +21,9 @@ so the daemon runs first, while this process has not opened JAX):
 
 With ``--chips 4`` only the main-path rows run: sharded over the four
 devices on ``jax`` and on ``pallas`` (through the service), and through
-``run_rows(..., mesh=...)``; each must be byte-equal to a run on device 0,
-every device must have run rows, and no device reads another's copy.
+``sweep.run_rows`` on the default backend, one row chunk a device; each must
+be byte-equal to a run on device 0, every device must have run rows, and no
+device reads another's copy.
 
 Every service phase asserts that no fallback hid the device: no resilience
 fallback or degraded dispatch, no dispatch on the oracle, and the kernel
@@ -325,9 +326,9 @@ def phase_models(work: Path):
 
 def phase_four_chips(work: Path):
     """The main-path rows sharded over four devices (service on jax and
-    pallas, and the mesh path), each byte-equal to a run on device 0."""
+    pallas, and ``run_rows`` on the default backend), each byte-equal to a
+    run on device 0."""
     import jax
-    from jax.sharding import Mesh
     devs = jax.devices()
     topo = one_cluster(MAIN_P, 1)
     probe = SimulationService(root=work / "probe")
@@ -365,13 +366,14 @@ def phase_four_chips(work: Path):
                  events_per_s=events_per_s(r.grid, wall))
         before = counters()
         t0 = time.perf_counter()
-        g = sw.run_rows(model, rows, mesh=Mesh(np.array(devs), ("data",)))
+        g = sw.run_rows(model, rows)
         wall = time.perf_counter() - t0
-        rows_by_dev = per_device(before, counters(), "jax")
-        check(grids_equal(g, ref), "the mesh path differs from device 0")
+        rows_by_dev = per_device(before, counters(), KERNEL)
+        check(grids_equal(g, ref),
+              "run_rows on the default backend differs from device 0")
         check(all(v > 0 for v in rows_by_dev.values()),
-              f"mesh: a device ran no rows: {rows_by_dev}")
-        note("four_chips", run="run_rows(mesh=...)", wall_s=wall,
+              f"run_rows: a device ran no rows: {rows_by_dev}")
+        note("four_chips", run=f"run_rows on {KERNEL}", wall_s=wall,
              rows_per_device=rows_by_dev, byte_equal=True)
 
 

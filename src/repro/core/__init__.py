@@ -19,8 +19,7 @@ from repro.core.engine import (  # noqa: F401
     SegmentStats, SegmentedRun, default_segment_len, simulate_segmented,
 )
 from repro.core.sweep import (  # noqa: F401
-    run_grid, run_rows, quick_sim, GridResult, simulate_sharded, make_model,
-    as_model,
+    run_grid, run_rows, quick_sim, GridResult, make_model, as_model,
 )
 from repro.core.backend import (  # noqa: F401
     BackendCapabilities, ExecutionBackend, available_backends, backend_names,

@@ -21,9 +21,12 @@ Every backend is **bit-identical** on the same rows (the parity tests in
 result store needs no backend key component: a cache fill from any backend
 serves every other.
 
-Auto-detection: ``default_backend_name()`` honours the ``REPRO_WS_BACKEND``
-environment variable, then picks ``pallas`` iff a TPU is attached, else
-``jax``.
+Where each dispatch decision lives: the backend is the query's explicit
+one or ``default_backend_name()`` (the ``REPRO_WS_BACKEND`` environment
+variable, then ``pallas`` iff a TPU is attached, else ``jax``), and it runs
+every batch it is given; rows map to devices only in
+:meth:`ExecutionBackend._device_chunks`; a failed dispatch is demoted only
+along ``repro.service.resilience.fallback_chain``.
 """
 from __future__ import annotations
 
@@ -126,7 +129,6 @@ class BackendCapabilities:
     max_events_pow2: bool     # dispatcher should round static caps to pow2
     note: str = ""
     n_devices: int = 1        # local devices run_rows shards rows across
-    crossover_rows: int = 0   # below this batch size, cheaper to reroute
     segment_len: Optional[int] = None  # preferred event-segment length
 
 
@@ -393,7 +395,6 @@ class JaxBackend(ExecutionBackend):
             devices=_device_platforms(), max_p=1 << 14,
             max_events_pow2=False,
             n_devices=max(len(self.local_devices()), 1),
-            crossover_rows=8,
             segment_len=eng.default_segment_len(1 << 20))
 
     def _segment_len(self, model, ev_budget, n: int) -> Optional[int]:
@@ -441,8 +442,7 @@ class PallasBackend(ExecutionBackend):
             # Pow2 static caps bound the set of programs Mosaic compiles.
             max_events_pow2=True,
             note="" if _on_tpu() else "needs a TPU; use 'pallas_interpret'",
-            n_devices=max(len(self.local_devices()), 1),
-            crossover_rows=16)
+            n_devices=max(len(self.local_devices()), 1))
 
     def local_devices(self) -> tuple:
         try:
@@ -533,42 +533,6 @@ def get_backend(
     except KeyError:
         raise ValueError(f"unknown backend {backend!r}; registered: "
                          f"{backend_names()}") from None
-
-
-def cheapest_backend() -> ExecutionBackend:
-    """The lowest-fixed-overhead available backend: the serial oracle when
-    usable (no compile, no device dispatch), else the auto-detected one."""
-    b = _REGISTRY.get("oracle")
-    if b is not None and b.capabilities().available:
-        return b
-    return get_backend(None)
-
-
-def reroute_small_batch(be: ExecutionBackend, model,
-                        n_rows: int) -> ExecutionBackend:
-    """Small-batch crossover (DESIGN.md §8): when a batch is below the
-    backend's ``crossover_rows``, its fixed XLA dispatch/compile overhead
-    exceeds the whole batch's simulation cost, so run the rows on
-    :func:`cheapest_backend` instead — safe because all backends are
-    bit-identical on the same rows. Only configs the oracle models exactly
-    are rerouted: the divisible task model without trace logging (the
-    oracle has no capacity-halt or trace modelling), within the oracle's
-    ``max_p``. Callers opt in (``sweep.run_rows`` does so only when the
-    backend was auto-selected, so an explicitly requested backend always
-    runs)."""
-    caps = be.capabilities()
-    if caps.crossover_rows <= 0 or n_rows >= caps.crossover_rows:
-        return be
-    cheap = cheapest_backend()
-    if cheap.name == be.name:
-        return be
-    model = sw.as_model(model)
-    if model.log_trace or not isinstance(model, dv.DivisibleModel):
-        return be
-    ccaps = cheap.capabilities()
-    if not ccaps.available or model.p > ccaps.max_p:
-        return be
-    return cheap
 
 
 def default_jit_cache_dir() -> Path:

@@ -37,10 +37,10 @@ hashable (frozen-dataclass) object implementing:
 The concrete models are ``divisible.DivisibleModel``, ``dag.DagModel`` and
 ``adaptive.AdaptiveModel``; each is bit-exact against its serial numpy twin
 in ``repro.core.oracle``. Because handlers are plain traced JAX, the same
-``_simulate_impl`` body runs as ordinary jit/vmap code, sharded SPMD over a
-mesh (``sweep.simulate_sharded``), or inside the Pallas kernel
-(``kernels.ws_sim``) with all state VMEM-resident, one row or a block of
-rows (:func:`simulate_block`) a grid step.
+``_simulate_impl`` body runs as ordinary jit/vmap code (one row chunk a
+device, ``backend.ExecutionBackend._device_chunks``), or inside the Pallas
+kernel (``kernels.ws_sim``) with all state VMEM-resident, one row or a block
+of rows (:func:`simulate_block`) a grid step.
 """
 from __future__ import annotations
 

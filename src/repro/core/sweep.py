@@ -4,8 +4,8 @@ The paper's simulator engine runs "several scenarios and simulation in the
 same time". Here that is: build one batched Scenario per processor count
 (shapes are static in p), ``vmap`` the unified event core over the whole
 (W, λ, θ, rep) cross product for ANY task model (divisible, DAG, adaptive),
-and optionally shard the batch axis over a JAX mesh — on a 512-chip fleet a
-full paper sweep runs as a single SPMD program (DESIGN.md §4).
+and let the execution backend deal contiguous row chunks across every local
+device (``backend.ExecutionBackend._device_chunks``, DESIGN.md §4).
 """
 from __future__ import annotations
 
@@ -14,11 +14,8 @@ import itertools
 from typing import Callable, Dict, NamedTuple, Optional, Sequence, Union
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import obs
 from repro.core import adaptive as ad
 from repro.core import divisible
 from repro.core import dag as dg
@@ -213,19 +210,12 @@ def scenario_from_rows(rows: GridRows, remote_prob: float = 0.25,
     ``ev_budget`` (scalar or per-row array) fills the per-row event-budget
     column; None defers every row to the model's static ``max_events`` cap.
     The columns are built on the host and placed straight onto ``device``
-    (a device or a sharding; None: the default device), so a chunk bound
-    for one device is never staged on another.
+    (None: the default device), so a chunk bound for one device is never
+    staged on another.
     """
-    return jax.device_put(_host_scenario(rows, remote_prob, ev_budget),
-                          device)
-
-
-def _host_scenario(rows: GridRows, remote_prob: float,
-                   ev_budget) -> Scenario:
-    """:func:`scenario_from_rows`'s columns as host (numpy) arrays."""
     n = len(rows)
     budget = eng.INF32 if ev_budget is None else np.asarray(ev_budget)
-    return Scenario(
+    return jax.device_put(Scenario(
         W=np.asarray(rows.W, np.int32),
         seed=np.asarray(rows.seed, np.uint32),
         lam_local=np.asarray(rows.lam_local, np.int32),
@@ -236,7 +226,7 @@ def _host_scenario(rows: GridRows, remote_prob: float,
                             np.uint32(remote_prob_u32(float(remote_prob)))),
         max_events=np.broadcast_to(
             np.asarray(budget).astype(np.int32), (n,)),
-    )
+    ), device)
 
 
 def build_batch(
@@ -359,48 +349,21 @@ def resolve_model(
 
 
 def run_rows(model: eng.TaskModel, rows: GridRows, remote_prob: float = 0.25,
-             mesh: Optional[Mesh] = None,
-             shard_axes: Sequence[str] = ("data",),
-             backend=None, ev_budget=None, devices=None,
-             reroute: Optional[bool] = None) -> GridResult:
+             backend=None, ev_budget=None, devices=None) -> GridResult:
     """Run one batched simulation over canonical rows -> GridResult.
 
     ``backend`` selects the execution substrate (name, backend object, or
-    None for auto-detection — see ``repro.core.backend``); all backends are
-    bit-identical on the same rows. ``mesh`` shards the batch axis over a
-    JAX mesh and therefore requires the ``jax`` backend; without a mesh the
-    backend itself shards contiguous row chunks across every local device
-    (``devices=`` narrows the set). ``ev_budget`` is a per-row (or scalar)
-    event budget truncating the loop below the model's static cap (exact —
-    see ``engine.Scenario.max_events``).
-
-    ``reroute`` controls the small-batch crossover
-    (``backend.reroute_small_batch``): batches below the selected backend's
-    ``crossover_rows`` run on the cheapest available backend instead of
-    paying fixed XLA dispatch overhead. Default: on exactly when the
-    backend was auto-selected (``backend is None``), so naming a backend
-    always runs that backend.
+    None for ``backend.default_backend_name()``); all backends are
+    bit-identical on the same rows, and the selected one runs every batch
+    it is given. The backend shards contiguous row chunks across every
+    local device (``devices=`` narrows the set). ``ev_budget`` is a per-row
+    (or scalar) event budget truncating the loop below the model's static
+    cap (exact — see ``engine.Scenario.max_events``).
     """
     from repro.core import backend as bk
-    if mesh is not None:
-        be = bk.get_backend("jax" if backend is None else backend)
-        if be.name != "jax":
-            raise ValueError(
-                f"mesh-sharded sweeps require the 'jax' backend, got "
-                f"{be.name!r}")
-        model = as_model(model)
-        # host columns: simulate_sharded puts each shard on its device
-        res = simulate_sharded(
-            model, _host_scenario(rows, remote_prob, ev_budget), mesh,
-            shard_axes)
-        return grid_from_result(model.p, rows, res)
-    be = bk.get_backend(backend)
-    if reroute is None:
-        reroute = backend is None
-    if reroute:
-        be = bk.reroute_small_batch(be, model, len(rows))
-    return be.run_rows(model, rows, remote_prob=remote_prob,
-                       ev_budget=ev_budget, devices=devices)
+    return bk.get_backend(backend).run_rows(
+        model, rows, remote_prob=remote_prob, ev_budget=ev_budget,
+        devices=devices)
 
 
 def run_grid(
@@ -411,8 +374,6 @@ def run_grid(
     theta: Sequence[tuple] = ((0, 0),),
     mwt: bool = False,
     max_events: Optional[int] = None,
-    mesh: Optional[Mesh] = None,
-    shard_axes: Sequence[str] = ("data",),
     seed0: int = 1,
     task_model: Union[str, eng.TaskModel] = "divisible",
     chunk_size: Optional[int] = None,
@@ -479,67 +440,11 @@ def run_grid(
                     "not match the chunk's rows (stale store entry?)")
             parts.append(g)
             continue
-        g = run_rows(model, rws, mesh=mesh, shard_axes=shard_axes,
-                     backend=backend)
+        g = run_rows(model, rws, backend=backend)
         if on_chunk is not None:
             on_chunk(ci, g)
         parts.append(g)
     return concat_grids(parts)
-
-
-def simulate_sharded(model, scn: Scenario, mesh: Mesh,
-                     shard_axes: Sequence[str] = ("data",)):
-    """Shard the scenario batch axis over ``mesh`` axes and run SPMD.
-
-    Works for any task model (``model`` may also be a bare engine config).
-    Pads the batch to a multiple of the shard extent; padded rows simulate
-    W=1 (divisible/adaptive terminate immediately; DAG pad rows rerun the
-    static DAG under a dummy seed) and are dropped. This is how the
-    Monte-Carlo workload of the paper maps to a multi-pod fleet.
-    """
-    model = as_model(model)
-    extent = int(np.prod([mesh.shape[a] for a in shard_axes]))
-    n = int(scn.W.shape[0])
-    pad = (-n) % extent
-
-    def pad_leaf(x):
-        if pad == 0:
-            return x
-        filler = np.ones((pad,), x.dtype)  # W=1 dummy scenarios terminate fast
-        cat = np.concatenate if isinstance(x, np.ndarray) else jnp.concatenate
-        return cat([x, filler], axis=0)
-
-    scn_p = jax.tree.map(pad_leaf, scn)
-    sharding = NamedSharding(mesh, P(tuple(shard_axes)))
-    scn_p = jax.tree.map(lambda x: jax.device_put(x, sharding), scn_p)
-    for shard in scn_p.W.addressable_shards:
-        obs.REGISTRY.counter("backend.device_rows", {
-            "backend": "jax", "device": str(shard.device.id)}).inc(
-                int(shard.data.shape[0]))
-    out = eng.simulate_batch(model, scn_p)
-    if pad:
-        # a static slice: no index array crosses devices
-        out = jax.tree.map(lambda x: jax.lax.slice_in_dim(x, 0, n), out)
-    return out
-
-
-def lower_sharded_sweep(model, batch: int, mesh: Mesh,
-                        shard_axes: Sequence[str] = ("data",)):
-    """Lower (no execution) the sharded sweep for dry-run/roofline analysis."""
-    model = as_model(model)
-    sharding = NamedSharding(mesh, P(tuple(shard_axes)))
-
-    def specs(dtype):
-        return jax.ShapeDtypeStruct((batch,), dtype, sharding=sharding)
-
-    scn = Scenario(
-        W=specs(jnp.int32), seed=specs(jnp.uint32),
-        lam_local=specs(jnp.int32), lam_remote=specs(jnp.int32),
-        theta_static=specs(jnp.int32), theta_comm=specs(jnp.int32),
-        remote_prob=specs(jnp.uint32), max_events=specs(jnp.int32),
-    )
-    fn = jax.jit(jax.vmap(lambda s: eng._simulate(model, s)))
-    return fn.lower(scn)
 
 
 def quick_sim(p: int, W: int, lam: int, seed: int = 1, mwt: bool = False,

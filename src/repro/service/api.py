@@ -39,11 +39,8 @@ class SimulationService:
 
     def __init__(self, store: Optional[ResultStore] = None,
                  root: Optional[os.PathLike] = None,
-                 mesh=None, shard_axes: Sequence[str] = ("data",),
-                 confidence: float = 0.95, pad_pow2: bool = True,
-                 relax_max_events: bool = True,
+                 confidence: float = 0.95,
                  lock_wait_s: Optional[float] = 60.0,
-                 straggler_sort: bool = True,
                  compile_cache: bool = False,
                  dispatch_log_max: Optional[int] = 1024,
                  metrics: Optional[obs.MetricsRegistry] = None,
@@ -54,12 +51,8 @@ class SimulationService:
             root=root, metrics=self.metrics)
         if metrics is not None and store is not None:
             store.metrics = metrics     # one registry across the service
-        self.broker = QueryBroker(store=self.store, mesh=mesh,
-                                  shard_axes=shard_axes,
-                                  confidence=confidence, pad_pow2=pad_pow2,
-                                  relax_max_events=relax_max_events,
+        self.broker = QueryBroker(store=self.store, confidence=confidence,
                                   lock_wait_s=lock_wait_s,
-                                  straggler_sort=straggler_sort,
                                   dispatch_log_max=dispatch_log_max,
                                   metrics=self.metrics,
                                   resilience=resilience)

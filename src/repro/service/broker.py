@@ -17,18 +17,23 @@ wastes the batched core. The broker instead:
    program; everything else (W, λ, θ, seed) is a traced per-row scenario
    field. Buckets are keyed by the *canonical model form*, not object
    identity, so structurally identical models built by different callers
-   coalesce too. Under ``relax_max_events`` (the default) ``max_events`` is
-   dropped from the bucket key: members' static caps are *relaxed* to the
-   bucket's shared pow2 upper bound at dispatch, while each member's rows
-   carry their original cap as a per-row event budget
-   (``Scenario.max_events``) that truncates the loop in-engine — so every
-   row, overflow columns included, is bit-identical to its unrelaxed run
-   and stored results/keys stay byte-identical to the unrelaxed path;
-4. concatenates every bucket's pending rows into ONE batched sweep, padded
-   to the next power of two (padding rows are W=1 scenarios, which
+   coalesce too. ``max_events`` is not part of the bucket key: members'
+   static caps are *relaxed* to the bucket's shared pow2 upper bound at
+   dispatch, while each member's rows carry their original cap as a per-row
+   event budget (``Scenario.max_events``) that truncates the loop in-engine
+   — so every row, overflow columns included, is bit-identical to a run of
+   that query alone at its own cap, and stored results/keys are the same
+   bytes;
+4. concatenates every bucket's pending rows into ONE batched sweep, sorted
+   by expected event count (the permutation is inverted before fan-back),
+   padded to the next power of two (padding rows are W=1 scenarios, which
    terminate immediately; pow-2 padding bounds the number of distinct batch
-   shapes XLA ever compiles), and dispatches it through ``core/sweep`` on
-   the bucket's backend (``repro.core.backend``);
+   shapes XLA ever compiles), and dispatches it through
+   ``core.sweep.run_rows`` on the bucket's backend — the query's explicit
+   ``backend``, else ``backend.default_backend_name()``. The backend runs
+   every batch it is given, and maps rows to devices itself
+   (``ExecutionBackend._device_chunks``); only a failure demotes a batch,
+   along ``resilience.fallback_chain``;
 5. fans the per-row results back to each query, rounds the adaptive
    estimator, and persists each finished answer in the store. All backends
    are bit-identical, so store keys carry no backend component: a fill
@@ -541,7 +546,7 @@ class EventHistory:
 
 class _Bucket:
     """One coalesced dispatch group: every member shares the same canonical
-    static config (modulo ``max_events`` under relaxation), ``remote_prob``
+    static config (``max_events`` aside: it is relaxed), ``remote_prob``
     and execution backend — and therefore the same compiled program."""
 
     def __init__(self, model: eng.TaskModel, canon: dict, rp: float,
@@ -550,7 +555,6 @@ class _Bucket:
         self.canon = canon       # bucket-key canonical form
         self.rp = rp
         self.backend = backend
-        self.explicit = False    # any member explicitly named the backend
         # (query idx, tag, rows, member's own static max_events cap)
         self.members: List[Tuple[int, str, GridRows, int]] = []
 
@@ -559,8 +563,10 @@ class QueryBroker:
     """Accepts concurrent SimQuerys/PairedQuerys, coalesces, dispatches,
     fans back.
 
-    ``relax_max_events`` enables cross-bucket coalescing over the static
-    ``max_events`` cap (exact per-row budgets — see the module docstring);
+    Every bucket is dispatched one way (see the module docstring): static
+    caps relaxed to a shared pow2 bound with exact per-row budgets, rows
+    sorted by expected event count, padded to a pow2, and handed to
+    ``run_rows`` — this module's name, looked up at each dispatch.
     ``lock_wait_s`` bounds how long a flush polls the store for a key whose
     advisory lock another process holds (None disables locking entirely,
     0 takes locks but never waits); ``dispatch_log_max`` bounds the
@@ -570,21 +576,15 @@ class QueryBroker:
     it)."""
 
     def __init__(self, store: Optional[ResultStore] = None,
-                 dispatch=None, pad_pow2: bool = True,
-                 confidence: float = 0.95, mesh=None,
-                 shard_axes: Sequence[str] = ("data",),
-                 relax_max_events: bool = True,
+                 confidence: float = 0.95,
                  lock_wait_s: Optional[float] = 60.0,
                  lock_poll_s: float = 0.05,
                  lock_poll_cap_s: float = 0.5,
-                 straggler_sort: bool = True,
                  dispatch_log_max: Optional[int] = 1024,
                  metrics: Optional[obs.MetricsRegistry] = None,
                  resilience: Optional[rz.ResilienceConfig] = None):
         self.store = store if store is not None else ResultStore()
-        self.pad_pow2 = pad_pow2
         self.confidence = float(confidence)
-        self.relax_max_events = bool(relax_max_events)
         self.lock_wait_s = lock_wait_s if lock_wait_s is None \
             else float(lock_wait_s)
         # Lock polling backs off with decorrelated jitter from lock_poll_s
@@ -598,21 +598,9 @@ class QueryBroker:
         self.resilience = resilience if resilience is not None \
             else rz.ResilienceConfig()
         self._breaker = self.resilience.make_breaker(metrics)
-        # Straggler-aware dispatch: order a bucket's rows by expected event
-        # count before running (results are un-permuted before fan-back, so
-        # answers and stored artifacts are byte-identical either way).
-        self.straggler_sort = bool(straggler_sort)
+        # Expected event counts that order each bucket's rows before it
+        # runs (straggler-aware dispatch).
         self.history = EventHistory()
-        # Mesh-sharded dispatch only exists on the jax backend, so a mesh
-        # pins the *default* (auto-detected) backend to jax; queries that
-        # explicitly name another backend still fail fast in run_rows.
-        self._mesh = mesh
-        self._dispatch = dispatch or (
-            lambda model, rows, rp, backend=None, ev_budget=None,
-            reroute=None: run_rows(
-                model, rows, remote_prob=rp, mesh=mesh,
-                shard_axes=shard_axes, backend=backend, ev_budget=ev_budget,
-                reroute=reroute))
         self._queue: List[Union[SimQuery, PairedQuery]] = []
         # Telemetry for the service_throughput bench / coalescing tests.
         # Legacy integer attributes stay (stats()/tests read them); every
@@ -666,8 +654,7 @@ class QueryBroker:
     def _history_sig(self, canon: dict, rp: float) -> str:
         """Event-history key: the bucket identity minus the static cap (so
         history survives cap relaxation) plus the remote-steal probability."""
-        if self.relax_max_events:
-            canon = {k: v for k, v in canon.items() if k != "max_events"}
+        canon = {k: v for k, v in canon.items() if k != "max_events"}
         return (json.dumps(canon, sort_keys=True, separators=(",", ":"))
                 + f":{remote_prob_u32(float(rp))}")
 
@@ -809,17 +796,12 @@ class QueryBroker:
                     owned.discard(keys[i])
                 continue
             for tag, model, canon, rp, backend, rows in wants:
-                bname = backend or (
-                    "jax" if self._mesh is not None
-                    else bk.default_backend_name())
-                if self.relax_max_events:
-                    # Drop the static cap from the bucket identity:
-                    # members coalesce across max_events and the
-                    # dispatch cap is relaxed to a shared pow2 bound.
-                    canon_b = {k: v for k, v in canon.items()
-                               if k != "max_events"}
-                else:
-                    canon_b = canon
+                bname = backend or bk.default_backend_name()
+                # No static cap in the bucket identity: members coalesce
+                # across max_events and the dispatch cap is relaxed to a
+                # shared pow2 bound.
+                canon_b = {k: v for k, v in canon.items()
+                           if k != "max_events"}
                 bkey = (json.dumps(canon_b, sort_keys=True,
                                    separators=(",", ":")),
                         remote_prob_u32(float(rp)), bname)
@@ -831,7 +813,6 @@ class QueryBroker:
                     assert bucket.canon == canon_b, (
                         "bucket members' canonical model configs "
                         "disagree despite equal bucket keys")
-                bucket.explicit |= backend is not None
                 bucket.members.append((i, tag, rows,
                                        int(model.max_events)))
         return buckets
@@ -859,63 +840,48 @@ class QueryBroker:
         n = len(rows)
         caps = [c for _, _, _, c in bucket.members]
         model = bucket.model
-        if self.relax_max_events:
-            # Relax the static cap to the bucket's shared pow2 upper bound;
-            # every member's rows keep their own cap as an in-engine per-row
-            # event budget, so results (overflow columns included) are
-            # bit-identical to the member's unrelaxed dispatch. Clamped to
-            # INT32_MAX: a pow2-ceil of a near-limit cap must not wrap the
-            # engine's int32 event counter.
-            cap = min(_next_pow2(max(caps)), int(eng.INF32))
-            if cap != model.max_events:
-                model = dataclasses.replace(
-                    model, cfg=dataclasses.replace(model.cfg,
-                                                   max_events=cap))
-            budgets = np.concatenate(
-                [np.full(len(r), c, np.int32)
-                 for _, _, r, c in bucket.members])
-        else:
-            cap = int(model.max_events)
-            budgets = None
+        # Relax the static cap to the bucket's shared pow2 upper bound;
+        # every member's rows keep their own cap as an in-engine per-row
+        # event budget, so results (overflow columns included) are
+        # bit-identical to the member's own dispatch at its cap. Clamped to
+        # INT32_MAX: a pow2-ceil of a near-limit cap must not wrap the
+        # engine's int32 event counter.
+        cap = min(_next_pow2(max(caps)), int(eng.INF32))
+        if cap != model.max_events:
+            model = dataclasses.replace(
+                model, cfg=dataclasses.replace(model.cfg, max_events=cap))
+        budgets = np.concatenate(
+            [np.full(len(r), c, np.int32) for _, _, r, c in bucket.members])
         # Straggler-aware ordering: dispatch the batch sorted by expected
         # event count (history EMA, else λ heuristic), so contiguous device
-        # chunks have tight intra-chunk spread and segmented compaction
-        # retires whole width levels at once. The permutation is inverted
-        # before fan-back: answers and stored artifacts stay byte-identical
-        # to an unsorted dispatch.
+        # chunks and kernel blocks have a tight spread. The permutation is
+        # inverted before fan-back: answers and stored artifacts stay
+        # byte-identical to the rows in member order.
         sig = self._history_sig(bucket.canon, bucket.rp)
         cols = _rows_cols(rows)
         order = None
-        if self.straggler_sort and n > 1:
+        if n > 1:
             srt = np.argsort(
                 self.history.predict(sig, model.p, cols), kind="stable")
             if not np.array_equal(srt, np.arange(n)):
                 order = srt
                 rows = rows.take(order)
-                if budgets is not None:
-                    budgets = budgets[order]
-        padded = _pad_rows(rows, _next_pow2(n)) if self.pad_pow2 else rows
-        if budgets is not None and len(padded) > n:
-            budgets = np.concatenate(
-                [budgets, np.full(len(padded) - n, eng.INF32, np.int32)])
+                budgets = budgets[order]
+        padded = _pad_rows(rows, _next_pow2(n))
+        budgets = np.concatenate(
+            [budgets, np.full(len(padded) - n, eng.INF32, np.int32)])
         entry = dict(
             n_queries=len(bucket.members), n_rows=n, n_padded=len(padded),
             backend=bucket.backend, max_events=cap,
-            relaxed=bool(self.relax_max_events and len(set(caps)) > 1),
-            sorted=order is not None)
+            relaxed=len(set(caps)) > 1, sorted=order is not None)
         cfg = self.resilience
-        if cfg.enabled and cfg.fallback and self._mesh is None:
-            chain = rz.fallback_chain(bucket.backend, model)
-        else:
-            # Mesh-sharded dispatch pins the backend (row sharding needs
-            # jax); no cross-backend demotion in that mode.
-            chain = [bucket.backend]
+        chain = (rz.fallback_chain(bucket.backend, model)
+                 if cfg.enabled and cfg.fallback else [bucket.backend])
 
-        def call(rws, buds, bname, top):
+        def call(rws, buds, bname):
             rz.fault_point("broker.dispatch", backend=bname, n_rows=len(rws))
-            return self._dispatch(model, rws, bucket.rp, backend=bname,
-                                  ev_budget=buds,
-                                  reroute=(not bucket.explicit) and top)
+            return run_rows(model, rws, remote_prob=bucket.rp, backend=bname,
+                            ev_budget=buds)
 
         member_keys = dict.fromkeys(keys[i] for i, *_ in bucket.members)
         with obs.span("broker.dispatch", keys=key_prefixes(member_keys),
@@ -926,8 +892,7 @@ class QueryBroker:
                     breaker=self._breaker, metrics=self.metrics,
                     salvage=cfg.salvage)
             else:
-                grid, degraded = call(padded, budgets, bucket.backend,
-                                      True), False
+                grid, degraded = call(padded, budgets, bucket.backend), False
         entry["degraded"] = degraded
         self._count("n_dispatches", "broker.dispatches")
         self.metrics.counter("broker.coalesced_queries").inc(

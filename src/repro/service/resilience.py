@@ -482,10 +482,9 @@ FALLBACK_ORDER = ("pallas", "jax", "oracle")
 
 
 def backend_compatible(be, model) -> bool:
-    """Can ``be`` produce bit-identical results for ``model``? Mirrors the
-    constraints ``reroute_small_batch`` honours: the oracle twins model
-    neither trace logging nor capacity halt, so only the divisible model
-    without ``log_trace`` may demote onto it."""
+    """Can ``be`` produce bit-identical results for ``model``? The oracle
+    twins model neither trace logging nor capacity halt, so only the
+    divisible model without ``log_trace`` may demote onto it."""
     from repro.core import divisible as dv
     from repro.core import sweep as sw
     caps = be.capabilities()
@@ -517,7 +516,7 @@ def fallback_chain(primary: str, model) -> List[str]:
 # -- salvage dispatch ---------------------------------------------------------
 
 #: Exception classes a dispatch failure must NOT recover from: these are
-#: caller/config errors (bad backend for a mesh, oversized p, type errors),
+#: caller/config errors (unknown backend, oversized p, type errors),
 #: where retrying or demoting would only mask the bug.
 NON_RECOVERABLE = (ValueError, TypeError, NotImplementedError, KeyError,
                    KeyboardInterrupt, SystemExit)
@@ -552,7 +551,7 @@ def dispatch_resilient(call: Callable, rows, budgets, chain: Sequence[str],
                        *, retry: RetryPolicy, breaker: CircuitBreaker,
                        metrics: obs.MetricsRegistry,
                        salvage: bool = True) -> Tuple[object, bool]:
-    """Run ``call(rows, budgets, backend_name, primary: bool)`` with retry,
+    """Run ``call(rows, budgets, backend_name)`` with retry,
     bisection salvage and fallback-chain demotion.
 
     Returns ``(GridResult, degraded)`` where ``degraded`` is True iff any
@@ -569,16 +568,14 @@ def dispatch_resilient(call: Callable, rows, budgets, chain: Sequence[str],
     """
     from repro.core import sweep as sw
 
-    def attempt(rows, budgets, ci: int, top: bool) -> Tuple[object, bool]:
-        """(grid, clean) for chain[ci]; clean = no failure in this subtree.
-        ``top`` marks the initial whole-batch attempt — the only call that
-        keeps the caller's original routing semantics (e.g. small-batch
-        reroute); every salvage/fallback sub-dispatch pins its backend."""
+    def attempt(rows, budgets, ci: int) -> Tuple[object, bool]:
+        """(grid, clean) for chain[ci]; clean = no failure in this
+        subtree. Every attempt runs on the backend it names."""
         name = chain[ci]
         last = ci == len(chain) - 1
         if not last and not breaker.allow(name):
             metrics.counter("resilience.fallbacks").inc()
-            grid, _ = attempt(rows, budgets, ci + 1, False)
+            grid, _ = attempt(rows, budgets, ci + 1)
             return grid, False
         err = None
         deadline = time.monotonic() + retry.deadline_s
@@ -589,7 +586,7 @@ def dispatch_resilient(call: Callable, rows, budgets, chain: Sequence[str],
                                 {"op": "dispatch"}).inc()
                 time.sleep(retry.sleep_s(k - 1))
             try:
-                grid = call(rows, budgets, name, top)
+                grid = call(rows, budgets, name)
             except NON_RECOVERABLE:
                 raise
             except Exception as e:          # noqa: BLE001 — recovery layer
@@ -614,8 +611,8 @@ def dispatch_resilient(call: Callable, rows, budgets, chain: Sequence[str],
             if budgets is not None:
                 bl, br = budgets[:mid], budgets[mid:]
             with obs.span("resilience.salvage", backend=name, n_rows=n):
-                gl, cl = attempt(rows.slice(0, mid), bl, ci, False)
-                gr, cr = attempt(rows.slice(mid, n), br, ci, False)
+                gl, cl = attempt(rows.slice(0, mid), bl, ci)
+                gr, cr = attempt(rows.slice(mid, n), br, ci)
             salvaged = (mid if cl else 0) + (n - mid if cr else 0)
             if salvaged:
                 metrics.counter("resilience.salvaged_rows").inc(salvaged)
@@ -624,11 +621,11 @@ def dispatch_resilient(call: Callable, rows, budgets, chain: Sequence[str],
             metrics.counter("resilience.fallbacks").inc()
             with obs.span("resilience.fallback", n_rows=n,
                           src=name, dst=chain[ci + 1]):
-                grid, _ = attempt(rows, budgets, ci + 1, False)
+                grid, _ = attempt(rows, budgets, ci + 1)
             return grid, False
         raise err
 
-    grid, clean = attempt(rows, budgets, 0, True)
+    grid, clean = attempt(rows, budgets, 0)
     return grid, not clean
 
 

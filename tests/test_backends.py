@@ -129,28 +129,6 @@ def test_run_grid_backend_param():
     assert_grids_equal(a, b)
 
 
-def test_mesh_requires_jax_backend():
-    from repro.launch.mesh import make_test_mesh
-    topo = T.one_cluster(4, 1)
-    rows = grid_rows([200], [1], 1)
-    model = resolve_model(topo, "divisible", W_list=[200], lam_list=[1])
-    mesh = make_test_mesh((1,), ("data",))
-    with pytest.raises(ValueError):
-        run_rows(model, rows, mesh=mesh, backend="oracle")
-
-
-def test_mesh_service_pins_default_backend_to_jax(tmp_path, monkeypatch):
-    """A mesh-sharded service must keep working when the auto-detected
-    default backend is not 'jax' (TPU host, or env override here)."""
-    from repro.launch.mesh import make_test_mesh
-    monkeypatch.setenv(bk.BACKEND_ENV, "pallas_interpret")
-    svc = SimulationService(root=tmp_path, mesh=make_test_mesh((1,),
-                                                               ("data",)))
-    r = svc.query(T.one_cluster(4, 1), W_list=[600], lam_list=[2], reps=2)
-    assert not r.grid.overflow.any()
-    assert svc.broker.dispatch_log[0]["backend"] == "jax"
-
-
 def test_oracle_rejects_trace_models():
     topo = T.one_cluster(4, 1)
     model = resolve_model(topo, "divisible", W_list=[500], lam_list=[1],
@@ -191,10 +169,10 @@ def test_ev_budget_truncates_exactly_incl_overflow():
 
 
 def test_broker_relaxation_coalesces_and_matches_unrelaxed(tmp_path):
-    """Acceptance: a 2-query workload whose λ buckets used to need 2
-    dispatches (different max_events caps) coalesces to 1 under relaxation,
-    with per-query results and stored artifacts byte-identical to the
-    unrelaxed path — including a query whose cap overflows."""
+    """Acceptance: a 2-query workload whose λ buckets have different
+    max_events caps coalesces to 1 relaxed dispatch, with per-query results
+    and stored artifacts byte-identical to each query answered alone at its
+    own cap — including a query whose cap overflows."""
     kw = dict(W_list=[30_000], reps=3)
     mk = lambda svc: [
         svc.make_query(T.one_cluster(8, 1), lam_list=[2],
@@ -209,16 +187,19 @@ def test_broker_relaxation_coalesces_and_matches_unrelaxed(tmp_path):
     assert svc_r.broker.dispatch_log[0]["n_queries"] == 2
     assert res_r[0].grid.overflow.any()
 
-    svc_u = SimulationService(root=tmp_path / "unrelaxed",
-                              relax_max_events=False)
-    res_u = svc_u.query_many(mk(svc_u))
-    assert svc_u.n_dispatches == 2
-
-    for r, u in zip(res_r, res_u):
+    for k, r in enumerate(res_r):
+        # The unrelaxed reference: the query alone, at its own static cap.
+        root_u = tmp_path / f"alone{k}"
+        svc_u = SimulationService(root=root_u)
+        q = mk(svc_u)[k]
+        u = svc_u.query_many([q])[0]
+        d = svc_u.broker.dispatch_log[0]
+        assert svc_u.n_dispatches == 1 and not d["relaxed"]
+        assert d["max_events"] == q.model.max_events
         assert r.key == u.key          # store keys unchanged by relaxation
         assert_grids_equal(r.grid, u.grid)
         art_r = (tmp_path / "relaxed" / f"{r.key}.npz").read_bytes()
-        art_u = (tmp_path / "unrelaxed" / f"{u.key}.npz").read_bytes()
+        art_u = (root_u / f"{u.key}.npz").read_bytes()
         assert art_r == art_u          # byte-identical artifacts
 
 

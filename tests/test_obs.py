@@ -339,7 +339,7 @@ def test_last_stats_reset_every_run():
     model = resolve_model(topo, "divisible", W_list=[900], lam_list=[2])
     run_rows(model, grid_rows([900], [2], 48), backend="jax")
     assert be.last_stats is not None        # 48 rows: segmented path
-    run_rows(model, grid_rows([900], [2], 4), backend="jax", reroute=False)
+    run_rows(model, grid_rows([900], [2], 4), backend="jax")
     assert be.last_stats is None            # 4 rows: monolithic path
 
 

@@ -313,7 +313,7 @@ def _resilient_run(n_rows, poisoned_seeds, **cfg_kw):
     oracle = bk.get_backend("oracle")
     calls = []
 
-    def call(rws, buds, name, top):
+    def call(rws, buds, name):
         calls.append((name, len(rws)))
         if name == "jax" and set(np.asarray(rws.seed)) & poisoned_seeds:
             raise rz.InjectedFault("poisoned row")
@@ -368,7 +368,7 @@ def test_dispatch_resilient_nonrecoverable_propagates():
     m = obs.MetricsRegistry()
     rows = grid_rows([2000], [2], 4)
 
-    def call(rws, buds, name, top):
+    def call(rws, buds, name):
         raise ValueError("config bug")
 
     cfg = rz.ResilienceConfig()
@@ -382,7 +382,7 @@ def test_dispatch_resilient_exhausted_chain_reraises():
     m = obs.MetricsRegistry()
     rows = grid_rows([2000], [2], 1)    # single row: no bisection possible
 
-    def call(rws, buds, name, top):
+    def call(rws, buds, name):
         raise rz.InjectedFault(f"{name} down")
 
     cfg = rz.ResilienceConfig(

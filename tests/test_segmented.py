@@ -1,9 +1,9 @@
 """Segmented execution layer (DESIGN.md §8): bit-exactness of the
 segmented driver vs the monolithic while_loop across task models /
 victim-selection strategies / SWT-MWT, per-row budget overflow, active-lane
-compaction telemetry, multi-device row sharding, the small-batch crossover
-reroute, straggler-aware dispatch ordering, and the persistent compile
-cache."""
+compaction telemetry, multi-device row sharding, small batches on the
+selected backend, straggler-aware dispatch ordering, and the persistent
+compile cache."""
 import dataclasses
 import os
 import subprocess
@@ -23,6 +23,7 @@ from repro.core.sweep import (grid_rows, resolve_model, run_rows,
                               scenario_from_rows)
 from repro.service import SimulationService
 from repro.service.broker import EventHistory, _rows_cols
+from repro.service.store import ResultStore, canonical_model
 
 
 def assert_trees_equal(a, b, msg=""):
@@ -63,12 +64,10 @@ def test_default_segment_len_bounds():
 def test_capability_fields():
     jb = bk.get_backend("jax").capabilities()
     assert jb.n_devices >= 1
-    assert jb.crossover_rows == 8
     assert jb.segment_len == 128
     ob = bk.get_backend("oracle").capabilities()
-    assert ob.crossover_rows == 0 and ob.n_devices == 1
+    assert ob.n_devices == 1
     assert bk.get_backend("oracle").local_devices() == ()
-    assert bk.get_backend("pallas").capabilities().crossover_rows == 16
     assert bk.get_backend("pallas_interpret").grid_chunk is None
 
 
@@ -208,11 +207,13 @@ def test_pallas_grid_chunk_parity():
 
 MULTIDEV_SCRIPT = """
 import dataclasses
+import sys
 import numpy as np
 import jax
 
 assert jax.device_count() == 4, jax.devices()
 from repro.core import backend as bk
+from repro.core import dag_gen as gen
 from repro.core import topology as T
 from repro.core.sweep import grid_rows, resolve_model, run_rows
 
@@ -223,8 +224,14 @@ assert [c[:2] for c in chunks] == [(0, 8), (8, 16), (16, 24), (24, 32)]
 assert len({c[2] for c in chunks}) == 4
 
 topo = T.one_cluster(4, 2)
-model = resolve_model(topo, "divisible", W_list=[800], lam_list=[2])
-rows = grid_rows([800], [2], 32)
+task_model = sys.argv[1]
+if task_model == "dag":
+    model = resolve_model(topo, "dag", dag=gen.merge_sort(200, 32),
+                          lam_list=[2])
+    rows = grid_rows([0], [2], 32)
+else:
+    model = resolve_model(topo, task_model, W_list=[800], lam_list=[2])
+    rows = grid_rows([800], [2], 32)
 ref = run_rows(model, rows, backend="jax", devices=[jax.local_devices()[0]])
 got = run_rows(model, rows, backend="jax")   # every device by default
 for f in dataclasses.fields(ref):
@@ -236,12 +243,14 @@ for f in dataclasses.fields(ref):
     else:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f.name)
+assert not ref.overflow.any()
 assert be.last_stats is not None and be.last_stats.n_segments >= 4
 print("MULTIDEV_OK")
 """
 
 
-def test_run_rows_shards_across_forced_host_devices(tmp_path):
+@pytest.mark.parametrize("task_model", ["divisible", "dag", "adaptive"])
+def test_run_rows_shards_across_forced_host_devices(tmp_path, task_model):
     import repro
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
@@ -251,7 +260,7 @@ def test_run_rows_shards_across_forced_host_devices(tmp_path):
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     script = tmp_path / "multidev.py"
     script.write_text(MULTIDEV_SCRIPT)
-    proc = subprocess.run([sys.executable, str(script)], env=env,
+    proc = subprocess.run([sys.executable, str(script), task_model], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-4000:]
     assert "MULTIDEV_OK" in proc.stdout
@@ -259,7 +268,6 @@ def test_run_rows_shards_across_forced_host_devices(tmp_path):
 
 NO_CROSSING_SCRIPT = r"""
 import jax, numpy as np
-from jax.sharding import Mesh
 from repro import obs
 from repro.core import backend as bk
 from repro.core import topology as T
@@ -290,12 +298,13 @@ def device_rows(backend):
 # or copied from, another device.
 with jax.transfer_guard_device_to_device("disallow_explicit"):
     assert same(run_rows(model, rows, backend="jax"))
-    assert same(run_rows(model, rows, mesh=Mesh(np.array(devs), ("data",))))
     scn = scenario_from_rows(rows, device=devs[2])
     out = ws_sim_pallas(model, scn, interpret=True, grid_chunk=8)
     assert out.makespan.devices() == {devs[2]}
     assert same(grid_from_result(model.p, rows, jax.device_get(out)))
-assert all(n > 0 for n in device_rows("jax")), device_rows("jax")
+# The reference's 30 rows on device 0, then three 10-row chunks (at least
+# eight rows a device) on devices 0-2.
+assert device_rows("jax") == [40, 10, 10, 0], device_rows("jax")
 print("NO_CROSSING_OK")
 """
 
@@ -317,30 +326,30 @@ def test_sharded_chunks_never_cross_devices(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Small-batch crossover reroute.
+# Small batches run on the selected backend.
 # ---------------------------------------------------------------------------
 
-def test_small_batch_reroute_to_oracle(monkeypatch):
+def test_small_batch_runs_on_selected_backend(monkeypatch):
     monkeypatch.setenv(bk.BACKEND_ENV, "jax")  # deterministic auto-detect
     topo = T.one_cluster(4, 2)
     model = resolve_model(topo, "divisible", W_list=[500], lam_list=[2])
-    rows = grid_rows([500], [2], 2)            # 2 < crossover_rows (8)
     orc_be, jax_be = bk.get_backend("oracle"), bk.get_backend("jax")
-    o0, j0 = orc_be.n_run_rows, jax_be.n_run_rows
-    got = run_rows(model, rows)                # auto backend -> rerouted
-    assert (orc_be.n_run_rows, jax_be.n_run_rows) == (o0 + 1, j0)
-    ref = run_rows(model, rows, backend="jax")  # explicit -> honoured
-    assert jax_be.n_run_rows == j0 + 1
-    assert_grids_equal(ref, got, msg="reroute parity")
-    run_rows(model, rows, reroute=False)       # auto, reroute opted out
-    assert (orc_be.n_run_rows, jax_be.n_run_rows) == (o0 + 1, j0 + 2)
-    run_rows(model, grid_rows([500], [2], 8))  # at crossover: no reroute
-    assert (orc_be.n_run_rows, jax_be.n_run_rows) == (o0 + 1, j0 + 3)
-    # Configs the oracle cannot model exactly are never rerouted.
+    for n in (2, 8):
+        rows = grid_rows([500], [2], n)
+        o0, j0 = orc_be.n_run_rows, jax_be.n_run_rows
+        got = run_rows(model, rows)            # auto-selected backend
+        assert (orc_be.n_run_rows, jax_be.n_run_rows) == (o0, j0 + 1)
+        ref = run_rows(model, rows, backend="oracle")
+        assert_grids_equal(ref, got, msg=f"{n} rows")
+    # A trace-logging model, which the oracle cannot run, runs there too.
     trace = resolve_model(topo, "divisible", W_list=[500], lam_list=[2],
                           log_trace=True, max_trace=64)
-    run_rows(trace, rows)
-    assert (orc_be.n_run_rows, jax_be.n_run_rows) == (o0 + 1, j0 + 4)
+    rows = grid_rows([500], [2], 2)
+    o0, j0 = orc_be.n_run_rows, jax_be.n_run_rows
+    got = run_rows(trace, rows)
+    assert (orc_be.n_run_rows, jax_be.n_run_rows) == (o0, j0 + 1)
+    assert_grids_equal(run_rows(trace, rows, backend="jax"), got,
+                       msg="log_trace")
 
 
 # ---------------------------------------------------------------------------
@@ -370,18 +379,22 @@ def test_straggler_sort_orders_dispatch_bitexact(tmp_path):
     kw = dict(W_list=[40_000, 500], lam_list=[2], reps=2,
               max_events=1 << 15)
     svc = SimulationService(root=tmp_path / "sorted")
-    r = svc.query(T.one_cluster(6, 1), **kw)
+    q = svc.make_query(T.one_cluster(6, 1), **kw)
+    r = svc.query_many([q])[0]
     d = svc.broker.dispatch_log[0]
     assert d["sorted"] is True
     assert len(svc.broker.history) > 0         # fed back after dispatch
 
-    svc_u = SimulationService(root=tmp_path / "plain", straggler_sort=False)
-    r_u = svc_u.query(T.one_cluster(6, 1), **kw)
-    assert svc_u.broker.dispatch_log[0]["sorted"] is False
-    assert r.key == r_u.key
-    assert_grids_equal(r.grid, r_u.grid, msg="sorted vs unsorted")
+    # The unsorted reference: the query's rows in grid order, run directly.
+    ref = run_rows(q.model, grid_rows(q.W_list, q.lam_list, q.reps,
+                                      q.theta, seed0=q.seed0),
+                   remote_prob=q.remote_prob)
+    assert_grids_equal(ref, r.grid, msg="sorted vs grid order")
+    ResultStore(root=tmp_path / "plain").put(
+        r.key, ref, meta={"grid": q.grid_dict(),
+                          "model": canonical_model(q.model)})
     art_a = (tmp_path / "sorted" / f"{r.key}.npz").read_bytes()
-    art_b = (tmp_path / "plain" / f"{r_u.key}.npz").read_bytes()
+    art_b = (tmp_path / "plain" / f"{r.key}.npz").read_bytes()
     assert art_a == art_b
 
     # A cache hit still teaches the history (no dispatch needed).
@@ -420,7 +433,7 @@ def test_compile_cache_opt_in(tmp_path, monkeypatch):
         assert svc.compile_cache_dir == env_dir and env_dir.is_dir()
         assert jax.config.jax_compilation_cache_dir == str(env_dir)
         r = svc.query(T.one_cluster(4, 1), W_list=[500], lam_list=[2],
-                      reps=16)                 # above the oracle reroute
+                      reps=16)
         assert not r.grid.overflow.any()
         assert any(env_dir.iterdir())
         st = svc.stats()
