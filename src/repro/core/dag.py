@@ -54,7 +54,7 @@ class DagSimResult(NamedTuple):
 class DagState(NamedTuple):
     """Per-model state pytree: the task engine's deques + activation front."""
     cur_task: jnp.ndarray      # int32[p]; -1 = no running task
-    pred: jnp.ndarray          # int32[n] remaining predecessor counts
+    pred: jnp.ndarray          # int32[R, 128] predecessor counts, flat_tiles
     buf: jnp.ndarray           # int32[p, cap] deques, slot = index % cap
     head: jnp.ndarray          # int32[p] tasks ever taken from the head
     tail: jnp.ndarray          # int32[p] head + the deque's length
@@ -92,19 +92,18 @@ class DagModel(eng.TaskModel):
     cfg: DagEngineConfig
 
     def static_arrays(self):
+        """``dur``, ``child_ptr``, ``child_idx`` and ``pred_count`` as
+        :func:`engine.flat_tiles`, read through ``engine.read_flat``."""
         dag = self.cfg.dag
-        cidx = jnp.asarray(dag.child_idx)
-        if cidx.shape[0] == 0:        # keep Pallas inputs non-empty
-            cidx = jnp.zeros((1,), jnp.int32)
-        return (jnp.asarray(dag.dur), jnp.asarray(dag.child_ptr), cidx,
-                jnp.asarray(dag.pred_count))
+        return tuple(jnp.asarray(eng.flat_tiles(a)) for a in (
+            dag.dur, dag.child_ptr, dag.child_idx, dag.pred_count))
 
     def init(self, arrays, scn: Scenario, core: eng.CoreState):
         dur, _, _, pred0 = arrays
         p = self.p
         src = int(self.cfg.dag.sources[0])
         core = core._replace(
-            ev_time=eng.write(core.ev_time, 0, eng.read(dur, src)),
+            ev_time=eng.write(core.ev_time, 0, eng.read_flat(dur, src)),
             stolen=jnp.full((p,), -1, jnp.int32),
         )
         ms = DagState(
@@ -128,14 +127,14 @@ class DagModel(eng.TaskModel):
 
         def body(k, s):
             core, ms = s
-            child = eng.read(cidx, k)
-            pc = eng.read(ms.pred, child) - 1
+            child = eng.read_flat(cidx, k)
+            pc = eng.read_flat(ms.pred, child) - 1
             ready = pc == 0
             tl = eng.read(ms.tail, i)
             ok = tl - eng.read(ms.head, i) < cap
             pos = lax.rem(tl, cap)
             ms = ms._replace(
-                pred=eng.write(ms.pred, child, pc),
+                pred=eng.write_flat(ms.pred, child, pc),
                 buf=eng.write(ms.buf, (i, pos),
                               jnp.where(ready & ok, child,
                                         eng.read(ms.buf, i, pos))),
@@ -144,8 +143,9 @@ class DagModel(eng.TaskModel):
             core = core._replace(halt=core.halt | (ready & ~ok))
             return core, ms
 
-        return eng.fori_loop(eng.read(cptr, c), eng.read(cptr, c + 1), body,
-                             (core, ms), self.cfg.dag.max_children)
+        return eng.fori_loop(eng.read_flat(cptr, c),
+                             eng.read_flat(cptr, c + 1), body, (core, ms),
+                             self.cfg.dag.max_children)
 
     def on_idle(self, arrays, cid, chops, scn, core, ms: DagState, i, t):
         dur, cptr, cidx, _ = arrays
@@ -157,7 +157,7 @@ class DagModel(eng.TaskModel):
             ms = ms._replace(n_completed=ms.n_completed + 1,
                              tasks_run=eng.add(ms.tasks_run, i, 1))
             core = core._replace(
-                executed=eng.add(core.executed, i, eng.read(dur, c)))
+                executed=eng.add(core.executed, i, eng.read_flat(dur, c)))
             return self._activate_children(cptr, cidx, core, ms, i, c)
 
         core, ms = eng.cond(has_task, complete, lambda s: s, (core, ms))
@@ -188,7 +188,7 @@ class DagModel(eng.TaskModel):
                 ms = ms._replace(cur_task=eng.write(ms.cur_task, i, task))
                 core = core._replace(
                     ev_time=eng.write(core.ev_time, i,
-                                      t + eng.read(dur, task)))
+                                      t + eng.read_flat(dur, task)))
                 return core, ms
 
             def steal(s):
@@ -223,7 +223,7 @@ class DagModel(eng.TaskModel):
         def got(s):
             core, ms = s
             core = eng.acquire_work(self, core, i, t,
-                                    t + eng.read(dur, task),
+                                    t + eng.read_flat(dur, task),
                                     jnp.int32(0), jnp.int32(-1))
             ms = ms._replace(cur_task=eng.write(ms.cur_task, i, task))
             return eng.log(self, core, t, i, EV_ANS_OK, task), ms

@@ -300,6 +300,53 @@ def add(x, idx, v):
     return jnp.where(_hit(x, idx), x + jnp.asarray(v, x.dtype), x)
 
 
+#: Lanes of a 32-bit vreg: the minor dim of a task-indexed tile.
+LANES = 128
+
+
+def flat_tiles(a) -> np.ndarray:
+    """1-D ``a`` as the int32 ``(R, 128)`` tile that :func:`read_flat` and
+    :func:`write_flat` index: element k at row ``k >> 7``, lane
+    ``k & 127``; R rounded up to whole (8, 128) vregs, zero-padded. Mosaic
+    lays a 1-D vector out one sublane a vreg, so a one-hot pass over a tile
+    of n elements touches n/1024 vregs where the vector touched n/128.
+    Built in numpy, so it runs under ``jit``."""
+    a = np.asarray(a, np.int32).reshape(-1)
+    rows = max(-(-a.size // (8 * LANES)), 1) * 8
+    out = np.zeros(rows * LANES, np.int32)
+    out[:a.size] = a
+    return out.reshape(rows, LANES)
+
+
+def _tile_index(x, k):
+    """(row, lane) of flat index ``k`` in tile ``x``; a negative ``k``
+    gets row R, past the end, where :func:`read_flat` reads 0 and
+    :func:`write_flat` writes nothing, as in the select forms."""
+    return jnp.where(k < 0, x.shape[0], k >> 7), k & (LANES - 1)
+
+
+def _flat_iota(shape):
+    return (lax.broadcasted_iota(jnp.int32, shape, 0) * shape[1]
+            + lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def read_flat(x, k):
+    """Element ``k`` of an int32 :func:`flat_tiles` tile; 0 where ``k``
+    lies outside it (``-1``, no task). In the kernel body one compare of
+    the flat iota with ``k`` a vreg, a select and a sum."""
+    if not _SELECT_FORMS.get():
+        return x.at[_tile_index(x, k)].get(mode="fill", fill_value=0)
+    return jnp.sum(jnp.where(_flat_iota(x.shape) == k, x, 0))
+
+
+def write_flat(x, k, v):
+    """:func:`flat_tiles` tile ``x`` with element ``k`` set to ``v``;
+    unchanged where ``k`` lies outside it."""
+    if not _SELECT_FORMS.get():
+        return x.at[_tile_index(x, k)].set(v, mode="drop")
+    return jnp.where(_flat_iota(x.shape) == k, jnp.asarray(v, x.dtype), x)
+
+
 def argmin(x):
     """First index of the minimum of a 1-D vector, as int32."""
     if not _SELECT_FORMS.get():

@@ -36,6 +36,7 @@ in interpret mode on the CPU and compiles via Mosaic on a TPU.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -166,13 +167,25 @@ def _host_consts(model) -> tuple:
             + tuple(jax.device_get(model.static_arrays())))
 
 
-def _state_bytes(model, consts, scn1) -> int:
-    """Bytes of the state that one scenario carries through the event
+def _state_leaves(model, consts, scn1) -> list:
+    """Shapes of the state that one scenario carries through the event
     loop: the event core's ``CoreState`` and the task model's own."""
-    state = jax.eval_shape(
+    return jax.tree.leaves(jax.eval_shape(
         lambda c, s: model.init(c[2:], s, eng.init_core(model, s)),
-        consts, scn1)
-    return sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(state))
+        consts, scn1))
+
+
+def _vreg_tiles(shape) -> int:
+    """(8, 128) 32-bit tiles an array of ``shape`` takes in Mosaic's
+    layout: a scalar one; a vector ``(m,)`` one sublane a vreg,
+    ceil(m/128); a higher rank its leading dims times ceil(r/8) ×
+    ceil(c/128) for its last two ``(r, c)``."""
+    if not shape:
+        return 1
+    if len(shape) == 1:
+        return -(-shape[0] // 128)
+    *lead, r, c = shape
+    return math.prod(lead) * -(-r // 8) * -(-c // 128)
 
 
 class KernelCall(NamedTuple):
@@ -192,10 +205,12 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
     dispatches traces and lowers once per device and input shape; a fresh
     kernel closure per dispatch would miss JAX's cache every time. The
     key holds avals, not values, so tracers build it too. A build sets the
-    gauges ``ws_sim.state_bytes{task_model}``, the bytes of one scenario's
-    carried state, and ``ws_sim.block_rows{task_model}``, the scenarios a
-    grid step runs (:func:`block_rows`, which divides ``G``), so no
-    dispatch pays for them."""
+    gauges ``ws_sim.state_bytes{task_model}`` and
+    ``ws_sim.state_tiles{task_model}``, the bytes and the vreg tiles
+    (:func:`_vreg_tiles`) of one scenario's carried state, and
+    ``ws_sim.block_rows{task_model}``, the scenarios a grid step runs
+    (:func:`block_rows`, which divides ``G``), so no dispatch pays for
+    them."""
     B = block_rows(model)
     if G % B:
         raise ValueError(f"G={G} is not a multiple of the block of {B} rows")
@@ -208,8 +223,11 @@ def kernel_call(model, G: int, interpret, scn_def, scn_dtypes: tuple
     res_leaves, res_def = jax.tree.flatten(res_struct)
     bool_mask = tuple(l.dtype == jnp.bool_ for l in res_leaves)
     labels = {"task_model": _task_model(model)}
+    state = _state_leaves(model, consts, scn1)
     obs.REGISTRY.gauge("ws_sim.state_bytes", labels).set(
-        _state_bytes(model, consts, scn1))
+        sum(l.size * l.dtype.itemsize for l in state))
+    obs.REGISTRY.gauge("ws_sim.state_tiles", labels).set(
+        sum(_vreg_tiles(l.shape) for l in state))
     obs.REGISTRY.gauge("ws_sim.block_rows", labels).set(B)
 
     def _const_spec(x):

@@ -101,6 +101,23 @@ def test_ws_sim_kernel_compiles_for_v5e(one_chip, name):
     assert compiled.memory_analysis() is not None
 
 
+def test_bots_sort_state_fits_in_few_vreg_tiles(monkeypatch):
+    """On the CPU, no chip: the ``bots_sort`` kernel's carried state, in
+    (8, 128) vreg tiles (gauge ``ws_sim.state_tiles``), stays at most 80.
+    Its task-indexed arrays are (R, 128) tiles; as 1-D vectors, one
+    sublane a vreg, the predecessor counts alone would take 216."""
+    from repro import obs
+    registry = obs.MetricsRegistry()
+    monkeypatch.setattr(obs, "REGISTRY", registry)
+    kernel_call.cache_clear()     # a cached build sets no gauge
+    leaves, scn_def = jax.tree.flatten(_scenario_spec(GRID_CHUNK, None))
+    kernel_call(_models()["bots_sort"], GRID_CHUNK, True, scn_def,
+                tuple(l.dtype for l in leaves))
+    (labels, gauge), = registry.find("gauge", "ws_sim.state_tiles")
+    assert labels == {"task_model": "dag"}
+    assert gauge.value <= 80
+
+
 @pytest.mark.parametrize("topology", [
     one_cluster(256, 1), two_clusters(256, 40),
     multi_cluster(4, 64, 10, inter="ring")], ids=lambda t: t.name)
